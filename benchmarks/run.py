@@ -47,12 +47,17 @@ case/whitespace-normalized and unknown names are an error.  ``--json F``
 additionally writes the rows to F for the bench-regression CI gate
 (benchmarks/check_regression.py compares the ``derived`` column of the
 analytic tables against benchmarks/baselines/).
+
+Compiled programs go to JAX's persistent cache: ``JAX_COMPILATION_CACHE_DIR``
+where it is set, else ``.jax_cache/`` at the repo root
+(``repro.compile_cache``).
 """
 from __future__ import annotations
 
 import argparse
 import importlib
 import json
+import os
 import sys
 import traceback
 
@@ -104,6 +109,9 @@ def main(argv=None) -> None:
     ap.add_argument("--json", default="", metavar="FILE",
                     help="also write rows as JSON (for check_regression.py)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     mods = MODULES
     if args.only.strip():
         selected = parse_only(args.only)

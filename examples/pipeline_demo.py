@@ -15,8 +15,12 @@ paper's continuous-flow math at stage level:
      device s, micro-batches overlap under the GPipe schedule, and the
      report shows measured frames/sec against the sequential baseline.
 
+This is a CPU virtual-device demo: it re-executes itself as a child
+pinned to ``JAX_PLATFORMS=cpu`` with XLA_FLAGS for 4 virtual CPU
+devices, and the parent never imports JAX (so on a machine with an
+accelerator the child cannot collide with a process holding the chip).
+
 Run: PYTHONPATH=src python examples/pipeline_demo.py
-(re-executes itself with XLA_FLAGS for 4 virtual devices)
 """
 import os
 import subprocess
@@ -44,7 +48,8 @@ def _main() -> None:
           f"-> {naive / plan.bottleneck:.2f}x more throughput")
 
     print("\n=== 2. pipeline ring on 4 devices ===")
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = jax.make_mesh(
+        (4,), ("stage",), axis_types=(jax.sharding.AxisType.Auto,))
     L, mb, d, M = 8, 4, 32, 12
     w = jax.random.normal(jax.random.key(0), (L, d, d)) * 0.1
 
@@ -72,7 +77,7 @@ def _main() -> None:
     print(f"  16 chips over stages {plan.stage_cost} -> {chips} "
           f"(min service rate {min(rates):.3f}/s vs even-split "
           f"{min(service_rates(list(plan.stage_cost), [4] * 4, 1.0)):.3f}/s)")
-    print("\n=== 4. wall-clock staged CNN on the 4-device mesh ===")
+    print("\n=== 4. wall-clock staged CNN on the 4 virtual CPU devices ===")
     from fractions import Fraction as F
 
     import numpy as np
@@ -105,6 +110,7 @@ def _main() -> None:
 if __name__ == "__main__":
     if os.environ.get("_PIPE_DEMO_CHILD") != "1":
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         env["_PIPE_DEMO_CHILD"] = "1"
         raise SystemExit(subprocess.call([sys.executable, __file__], env=env))

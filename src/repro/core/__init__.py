@@ -20,7 +20,8 @@ Public surface:
                   ways behind a round-robin splitter / order-preserving
                   merger (plan_graph(replicate=...))
   hlo_analysis  — roofline term extraction from compiled HLO
-  hw_specs      — hardware constants (TPU v5e + xcvu37p)
+  hw_specs      — hardware constants (TPU specs keyed by device_kind +
+                  xcvu37p)
 """
 
 from .rate import (  # noqa: F401
@@ -80,7 +81,15 @@ from .replicate import (  # noqa: F401
     select_bottleneck,
 )
 from .tpu_tiles import TileChoice, select_tile, select_tile_for_impl  # noqa: F401
-from .hw_specs import TPU_V5E, XCVU37P, FPGASpec, TPUSpec  # noqa: F401
+from .hw_specs import (  # noqa: F401
+    TPU_SPECS,
+    TPU_V5E,
+    XCVU37P,
+    FPGASpec,
+    TPUSpec,
+    target_spec,
+    tpu_spec,
+)
 from .resource_model import (  # noqa: F401
     ResourceEstimate,
     estimate_graph,

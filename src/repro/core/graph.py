@@ -61,7 +61,7 @@ from fractions import Fraction
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from .dse import NON_ARITH_KINDS, LayerImpl, select_impl
-from .hw_specs import TPU_V5E, TPUSpec
+from .hw_specs import TPUSpec, target_spec
 from .rate import LayerSpec, RatePoint
 from .stage_partition import (
     DEFAULT_LINK_CYCLES,
@@ -75,7 +75,7 @@ from .stage_partition import (
     stage_stream_bits,
     stream_buffers,
 )
-from .tpu_tiles import TileChoice, select_tile_for_impl
+from .tpu_tiles import VMEM_FRACTION, TileChoice, select_tile_for_impl
 
 JOIN_KINDS = ("add", "concat")
 
@@ -668,8 +668,8 @@ class GraphPlan:
         self,
         *,
         dtype_bytes: int = 4,
-        tpu: TPUSpec = TPU_V5E,
-        vmem_fraction: float = 0.5,
+        tpu: Optional[TPUSpec] = None,
+        vmem_fraction: float = VMEM_FRACTION,
         batch: Optional[int] = None,
     ) -> "OrderedDict[str, ImplPlan]":
         """Lower this hardware plan to the executor's per-node contract.
@@ -687,7 +687,12 @@ class GraphPlan:
         it, and the executor asserts bm too (``ImplPlan.batch`` records
         the pin).  Without ``batch`` bm only bounds the runtime re-fit,
         exactly as before.
+
+        ``tpu`` defaults to ``core.hw_specs.target_spec()``: the attached
+        chip's spec (an unknown TPU kind raises), or TPU v5e — the
+        explicit planning target — when JAX runs on another backend.
         """
+        tpu = target_spec() if tpu is None else tpu
         plans: "OrderedDict[str, ImplPlan]" = OrderedDict()
         for name, impl in self.impls.items():
             spec = self.graph.spec(name)

@@ -1,8 +1,12 @@
 """Hardware constants for the roofline model and both cost models.
 
 Two targets coexist:
-  * ``TPUSpec``  — the runtime target of the framework (TPU v5e class, the
-    numbers mandated for the roofline analysis).
+  * ``TPUSpec``  — the runtime target of the framework, one entry per
+    chip in ``TPU_SPECS`` keyed by the ``device_kind`` JAX reports.
+    ``target_spec()`` is the one lookup: the attached chip's entry (an
+    unknown kind raises), or ``TPU_V5E`` — the explicit planning target —
+    when JAX runs on another backend, so CPU planning stays
+    deterministic.
   * ``FPGASpec`` — the paper's target (AMD/Xilinx Virtex UltraScale+
     xcvu37p-fsvh2892-3-e), used only by the analytical resource model that
     reproduces the paper's Tables I/II.
@@ -11,20 +15,32 @@ Two targets coexist:
 from __future__ import annotations
 
 import dataclasses
+from typing import Dict
 
 
 @dataclasses.dataclass(frozen=True)
 class TPUSpec:
-    """Per-chip numbers for the roofline terms (v5e class)."""
+    """Per-chip numbers for the roofline terms and the kernel tiling.
+
+    Defaults are TPU v5e.  Peaks and HBM: Google Cloud documentation,
+    "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s,
+    1,600 Gbit/s ICI = 4 links x 50 GB/s).  ``vmem_bytes`` is what the
+    XLA:TPU compiler lets one Pallas kernel use on v5e: 128 MiB compiles,
+    130 MiB is refused ("Used 130.00M of 128.00M vmem").  It is the one
+    VMEM number: every ``pallas_call`` asks for it as
+    ``vmem_limit_bytes`` (the default scoped limit is 16 MiB), and the
+    tile rule (core.tpu_tiles) budgets working sets against a fraction
+    of it.
+    """
 
     name: str = "tpu-v5e"
     peak_bf16_flops: float = 197e12     # FLOP/s per chip
-    peak_int8_ops: float = 394e12       # int8 OPS (2x bf16) — used by cost model
+    peak_int8_ops: float = 393e12       # int8 OP/s per chip
     hbm_bytes: int = 16 * 1024**3       # 16 GiB HBM per chip
     hbm_bw: float = 819e9               # bytes/s per chip
     ici_bw_per_link: float = 50e9       # bytes/s per ICI link (one direction)
     ici_links: int = 4                  # 2D torus: +/-x, +/-y
-    vmem_bytes: int = 128 * 1024**2     # ~128 MiB VMEM (v5e: 128MB)
+    vmem_bytes: int = 128 * 1024**2     # compiler-allowed VMEM per kernel
     mxu_dim: int = 128                  # systolic array edge
     sublanes: int = 8
     lanes: int = 128
@@ -72,3 +88,34 @@ class FPGASpec:
 
 TPU_V5E = TPUSpec()
 XCVU37P = FPGASpec()
+
+# device_kind (as ``jax.devices()[0].device_kind`` reports it) -> spec.
+TPU_SPECS: Dict[str, TPUSpec] = {"TPU v5 lite": TPU_V5E}
+
+
+def tpu_spec(device_kind: str) -> TPUSpec:
+    """The spec of one TPU kind; a kind without an entry is an error,
+    never a default."""
+    try:
+        return TPU_SPECS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no TPUSpec for device kind {device_kind!r} "
+            f"(known: {sorted(TPU_SPECS)}) — add its entry to "
+            f"core.hw_specs.TPU_SPECS"
+        ) from None
+
+
+def target_spec() -> TPUSpec:
+    """The chip kernels are planned and compiled for.
+
+    On a TPU backend, the attached chip's entry (unknown kinds raise).
+    Elsewhere (CPU planning, interpret-mode kernels, compiles for a
+    described but unattached chip) ``TPU_V5E``, the explicit target.
+    """
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    return tpu_spec(dev.device_kind)

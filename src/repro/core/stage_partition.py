@@ -356,8 +356,8 @@ class GraphStagePlan:
     runs on — device indices, not device objects, so the core stays
     JAX-free; the executor (``models.cnn.stage_functions(placement=...)``
     and ``distributed.device_pipeline``) resolves ordinals against the
-    live device list, folding modulo the live count when the host has
-    fewer devices than the plan assumed.
+    live device list and raises when an ordinal names a device the host
+    does not have.
     """
 
     order: Tuple[str, ...]
@@ -399,9 +399,10 @@ def round_robin_placement(n_stages: int, n_devices: int) -> Tuple[int, ...]:
 
     The canonical multi-device layout: with at least as many devices as
     stages every stage gets its own device (true pipeline overlap);
-    with fewer, stages fold round-robin and co-resident stages simply
-    serialize on their shared device — the schedule stays correct, only
-    the overlap shrinks.
+    with fewer (asked for by name, e.g. ``plan_graph(n_devices=)``),
+    stages fold round-robin and co-resident stages serialize on their
+    shared device — the schedule stays correct, only the overlap
+    shrinks.
     """
     if n_stages < 1:
         raise ValueError(f"n_stages must be >= 1, got {n_stages}")

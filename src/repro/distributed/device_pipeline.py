@@ -4,9 +4,10 @@ The tick-level serving engine (``serving/cnn_stream.py``) and the
 discrete-event validator *model* pipeline overlap; this module is where
 the repo finally *measures* it.  A ``DevicePipeline`` takes the compiled
 per-stage functions of a stage partition (``models.cnn.stage_functions``
-with ``placement=``), places stage ``s`` on ``jax.devices()[s % n]``
-(round-robin when stages outnumber devices — the smaller-host fallback
-degrades to co-resident stages, never to an error), and drives them with
+with ``placement=``), places each stage on a device
+(``models.cnn.resolve_stage_devices``: one device per stage by default;
+asking for more distinct devices than exist is an error, co-resident
+stages only by request), and drives them with
 the same software GPipe schedule ``distributed.pipeline_parallel``
 builds inside ``shard_map``:
 
@@ -94,8 +95,8 @@ class DevicePipeline:
     ``pipeline`` should come from ``models.cnn.stage_functions(...,
     placement=...)`` (or ``DevicePipeline.build``).  An unplaced
     pipeline is placed in-place via ``placement`` (default ``True``:
-    the partition's recorded ordinals, else round-robin over every
-    local device) — pass a pipeline you own, not one served from a
+    the partition's recorded ordinals, else stage ``s`` on
+    ``jax.devices()[s]``) — pass a pipeline you own, not one served from a
     shared memo cache, or build with ``placement=`` up front.
 
     ``run(x, microbatch=m)`` splits ``x`` into M = ceil(N/m)

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.stage_partition import StagePlan, partition_blocks
-from repro import compat
 
 
 def microbatch_utilization(n_micro: int, n_stages: int) -> float:
@@ -68,10 +67,10 @@ def pipeline_forward(
         mb_shape = x_all.shape[1:]
         # carries are stage-varying (each stage holds different values):
         # annotate for shard_map's vma type system.
-        buf = compat.pcast(
+        buf = jax.lax.pcast(
             jnp.zeros(mb_shape, x_all.dtype), (stage_axis,), to="varying"
         )
-        outs = compat.pcast(
+        outs = jax.lax.pcast(
             jnp.zeros((m,) + mb_shape, x_all.dtype), (stage_axis,), to="varying"
         )
 
@@ -99,7 +98,7 @@ def pipeline_forward(
         outs = jnp.where(stage_id == n_stages - 1, outs, 0)
         return jax.lax.psum(outs, stage_axis)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),

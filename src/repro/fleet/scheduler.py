@@ -24,7 +24,7 @@ takes a fleet-wide config (execution knobs shared by every engine) and
 tenant arrival scenarios (``serving.scenarios``) and overload policies
 (``serving.overload``), so one tenant can shed under an SLA while its
 neighbor plan-switches.  The pre-ServeConfig keyword arguments
-(``execute``/``interpret``/``check``/``jit`` on the scheduler,
+(``execute``/``check``/``jit`` on the scheduler,
 ``arrival_rate``/``microbatch``/``flush_after_ticks`` on the workload)
 keep working as a deprecated shim.
 
@@ -166,7 +166,7 @@ class FleetReport:
 
 _UNSET = object()
 
-_LEGACY_SCHED = ("execute", "interpret", "check", "jit")
+_LEGACY_SCHED = ("execute", "check", "jit")
 
 
 class FleetScheduler:
@@ -188,19 +188,18 @@ class FleetScheduler:
         params: Optional[Mapping[str, object]] = None,
         config: Optional[ServeConfig] = None,
         execute=_UNSET,
-        interpret=_UNSET,
         check=_UNSET,
         jit=_UNSET,
     ) -> None:
         legacy = {
             k: v
-            for k, v in zip(_LEGACY_SCHED, (execute, interpret, check, jit))
+            for k, v in zip(_LEGACY_SCHED, (execute, check, jit))
             if v is not _UNSET
         }
         if config is None:
             if legacy:
                 warnings.warn(
-                    "FleetScheduler(..., execute=/interpret=/check=/jit=) is "
+                    "FleetScheduler(..., execute=/check=/jit=) is "
                     "deprecated — pass a serving.ServeConfig",
                     DeprecationWarning,
                     stacklevel=2,

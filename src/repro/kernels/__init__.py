@@ -1,3 +1,7 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the paper's compute units (KPU, depthwise KPU, FCU)
+plus the language-model kernels (flash attention, SSD chunk scan).
+
+Every kernel is built through ``kernels.common.pallas_call``: compiled by
+Mosaic when the computation is lowered for a TPU, run by the Pallas
+interpreter on any other backend.  There is no ``interpret`` option.
+"""

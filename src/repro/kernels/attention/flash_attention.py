@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import pallas_call
+
 _NEG_INF = -1e30
 
 
@@ -95,7 +97,6 @@ def flash_attention_p(
     block_q: int = 128,
     block_k: int = 128,
     scale: float | None = None,
-    interpret: bool = True,
 ) -> jax.Array:
     bh, sq, d = q.shape
     _, sk, _ = k.shape
@@ -104,7 +105,7 @@ def flash_attention_p(
     assert sq % block_q == 0 and sk % block_k == 0, (sq, sk, block_q, block_k)
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     grid = (bh, sq // block_q, sk // block_k)
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(
             _flash_kernel,
             scale=scale,
@@ -126,5 +127,4 @@ def flash_attention_p(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
     )(q, k, v)

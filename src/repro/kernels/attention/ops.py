@@ -10,9 +10,7 @@ from repro.core.tpu_tiles import TileChoice
 from .flash_attention import flash_attention_p
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
 def flash_attention(
     q: jax.Array,   # [B, H, Sq, d]
     k: jax.Array,   # [B, H, Sk, d]
@@ -21,14 +19,13 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     b, h, sq, d = q.shape
     sk = k.shape[2]
     out = flash_attention_p(
         q.reshape(b * h, sq, d), k.reshape(b * h, sk, d),
         v.reshape(b * h, sk, d),
-        causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
+        causal=causal, block_q=block_q, block_k=block_k,
     )
     return out.reshape(b, h, sq, d)
 
@@ -36,7 +33,6 @@ def flash_attention(
 def attention_impl(
     *,
     causal: bool = True,
-    interpret: bool = True,
     tile: Optional[TileChoice] = None,
     record: Optional[Callable[..., None]] = None,
 ):
@@ -59,7 +55,6 @@ def attention_impl(
             causal=causal,
             block_q=block_q,
             block_k=block_k,
-            interpret=interpret,
         )
         if record is not None:
             record(block_q=block_q, block_k=block_k, seq=q.shape[2])

@@ -9,7 +9,8 @@ multiply-accumulate over the K*K taps.
 Per §II-B: "the channel multiplier replaces d_out"; here cm=1 (MobileNet)
 and h=1, so the layer is just j-channel-parallel tap accumulation; the
 channel BlockSpec tile is the paper's j (j | d_in).  Stride pruning is the
-same strided gather as kpu_conv.
+same phase split as kpu_conv: each tap reads one contiguous window of one
+stride phase.
 """
 from __future__ import annotations
 
@@ -19,48 +20,41 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import pallas_call
 
-def _dw_kernel(x_ref, w_ref, o_ref, *, kh: int, kw: int, stride: int):
-    """Grid: (n, c_blocks). x: [1, Hp, Wp, bc], w: [kh, kw, bc],
-    o: [1, Ho, Wo, bc]."""
+
+def _dw_kernel(x_ref, w_ref, o_ref, *, taps: tuple):
+    """Grid: (n, c_blocks). x: [1, P, Hq, Wq, bc] (padded frame split
+    into P stride phases), w: [kh, kw, bc], o: [1, Ho, Wo, bc]."""
     _, ho, wo, bc = o_ref.shape
-    x = x_ref[0]
     acc = jnp.zeros((ho, wo, bc), jnp.float32)
-    for dy in range(kh):
-        for dx in range(kw):
-            win = jax.lax.slice(
-                x,
-                (dy, dx, 0),
-                (dy + (ho - 1) * stride + 1, dx + (wo - 1) * stride + 1, bc),
-                (stride, stride, 1),
-            )
-            acc += win.astype(jnp.float32) * w_ref[dy, dx].astype(jnp.float32)
+    for (dy, dx), (p, oy, ox) in taps:
+        win = x_ref[0, p, oy : oy + ho, ox : ox + wo, :]
+        acc += win.astype(jnp.float32) * w_ref[dy, dx].astype(jnp.float32)
     o_ref[0] = acc.astype(o_ref.dtype)
 
 
 def dw_conv_p(
-    x_padded: jax.Array,   # [N, Hp, Wp, C]
+    x_phases: jax.Array,   # [N, P, Hq, Wq, C]  (padded, phase-split)
     w: jax.Array,          # [kh, kw, C]
     *,
+    taps: tuple,
     out_hw: tuple,
-    stride: int = 1,
     bc: int,
-    interpret: bool = True,
     out_dtype=None,
 ) -> jax.Array:
-    n, hp, wp, c = x_padded.shape
+    n, n_ph, hq, wq, c = x_phases.shape
     kh, kw, c2 = w.shape
-    assert c == c2 and c % bc == 0, (x_padded.shape, w.shape, bc)
+    assert c == c2 and c % bc == 0, (x_phases.shape, w.shape, bc)
     ho, wo = out_hw
-    out_dtype = out_dtype or x_padded.dtype
-    return pl.pallas_call(
-        functools.partial(_dw_kernel, kh=kh, kw=kw, stride=stride),
+    out_dtype = out_dtype or x_phases.dtype
+    return pallas_call(
+        functools.partial(_dw_kernel, taps=taps),
         grid=(n, c // bc),
         in_specs=[
-            pl.BlockSpec((1, hp, wp, bc), lambda nn, cc: (nn, 0, 0, cc)),
+            pl.BlockSpec((1, n_ph, hq, wq, bc), lambda nn, cc: (nn, 0, 0, 0, cc)),
             pl.BlockSpec((kh, kw, bc), lambda nn, cc: (0, 0, cc)),
         ],
         out_specs=pl.BlockSpec((1, ho, wo, bc), lambda nn, cc: (nn, 0, 0, cc)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, c), out_dtype),
-        interpret=interpret,
-    )(x_padded, w)
+    )(x_phases, w)

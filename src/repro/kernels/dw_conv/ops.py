@@ -1,4 +1,5 @@
-"""jit'd wrapper for the depthwise kernel: SAME padding + j-tile choice.
+"""jit'd wrapper for the depthwise kernel: SAME padding, stride phases +
+j-tile choice.
 
 The channel tile (the paper's j, with h = 1 — §II-B: the channel
 multiplier replaces d_out for depthwise) is chosen either uniformly
@@ -15,52 +16,43 @@ from fractions import Fraction
 from typing import Callable, Optional
 
 import jax
-import jax.numpy as jnp
 
-from repro.core.rate import divisors
-from repro.core.tpu_tiles import TileChoice
+from repro.core.tpu_tiles import TileChoice, conv_geometry, plan_dim_tile
+from repro.kernels.common import conv_taps, phase_split
 from .dw_conv import dw_conv_p
 
 
-def _same_pads(size: int, k: int, s: int):
-    out = -(-size // s)
-    total = max(0, (out - 1) * s + k - size)
-    return out, (total // 2, total - total // 2)
-
-
 def _pick_bc(c: int, rate: Optional[Fraction]) -> int:
-    """The paper's j for depthwise (h=1, cm=1): smallest divisor tile
-    covering the stream rate; default = lane-width-ish tile."""
+    """The paper's j for depthwise (h=1, cm=1): smallest legal channel
+    tile covering the stream rate; default = one lane width."""
     want = 128 if rate is None else max(1, int(rate))
-    cands = [d for d in divisors(c) if d >= want]
-    return min(cands) if cands else c
+    return plan_dim_tile(c, min(want, c), 128)
 
 
-@functools.partial(jax.jit, static_argnames=("stride", "rate", "interpret", "bc"))
+@functools.partial(jax.jit, static_argnames=("stride", "rate", "bc"))
 def dw_conv(
     x: jax.Array,          # [N, H, W, C]
     w: jax.Array,          # [kh, kw, C]
     *,
     stride: int = 1,
     rate: Optional[Fraction] = None,
-    interpret: bool = True,
     bc: Optional[int] = None,
 ) -> jax.Array:
     n, h, wdt, c = x.shape
     kh, kw, _ = w.shape
-    ho, (pt, pb) = _same_pads(h, kh, stride)
-    wo, (pl_, pr) = _same_pads(wdt, kw, stride)
-    xp = jnp.pad(x, ((0, 0), (pt, pb), (pl_, pr), (0, 0)))
-    bc = bc or _pick_bc(c, rate)
+    geo = conv_geometry((h, wdt), (kh, kw), stride)
     return dw_conv_p(
-        xp, w, out_hw=(ho, wo), stride=stride, bc=bc, interpret=interpret
+        phase_split(x, geo),
+        w,
+        taps=conv_taps(geo, (kh, kw)),
+        out_hw=geo.out_hw,
+        bc=bc or _pick_bc(c, rate),
     )
 
 
 def dw_conv_impl(
     *,
     rate: Optional[Fraction] = None,
-    interpret: bool = True,
     tile: Optional[TileChoice] = None,
     record: Optional[Callable[..., None]] = None,
 ):
@@ -80,9 +72,7 @@ def dw_conv_impl(
                 f"{x.shape[-1]} channels); use the lax dwconv impl"
             )
         bc = tile.bk if tile is not None else None
-        y = dw_conv(
-            x, w[:, :, 0, :], stride=stride, rate=rate, interpret=interpret, bc=bc
-        )
+        y = dw_conv(x, w[:, :, 0, :], stride=stride, rate=rate, bc=bc)
         if record is not None:
             record(bk=bc, bn=1, d_in=x.shape[-1], d_out=x.shape[-1])
         return y

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import pallas_call
+
 
 def _fcu_kernel(x_ref, w_ref, o_ref, acc_ref, *, grid_k: int):
     """One (bm x bk) @ (bk x bn) MXU pass; accumulate over the k grid."""
@@ -49,7 +51,6 @@ def fcu_matmul_p(
     bm: int,
     bk: int,
     bn: int,
-    interpret: bool = True,
     out_dtype=None,
 ) -> jax.Array:
     """[m, d_in] @ [d_in, d_out] with explicit (bm, bk, bn) VMEM tiling.
@@ -65,7 +66,7 @@ def fcu_matmul_p(
     )
     grid = (m // bm, d_out // bn, d_in // bk)
     out_dtype = out_dtype or x.dtype
-    return pl.pallas_call(
+    return pallas_call(
         functools.partial(_fcu_kernel, grid_k=grid[2]),
         grid=grid,
         in_specs=[
@@ -75,5 +76,4 @@ def fcu_matmul_p(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, d_out), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
     )(x, w)

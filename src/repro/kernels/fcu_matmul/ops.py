@@ -9,7 +9,7 @@ from the paper's HJ exploration, two ways:
   * rate-matched — ``pointwise_impl(tile=...)`` / ``dense_impl(tile=...)``
     receive one node's plan-derived ``TileChoice``
     (``GraphPlan.kernel_plan``) and execute exactly that (bk, bn); the
-    pixel tile bm re-fits the runtime m (batch and spatial dims are
+    pixel tile bm re-fits the runtime m via ``fit_bm`` (batch and spatial dims are
     flattened together, so m varies with batch while bk/bn do not) —
     unless the plan was pinned to a serving batch
     (``kernel_plan(batch=B)``), in which case the planned bm *divides*
@@ -27,24 +27,16 @@ from typing import Callable, Optional
 
 import jax
 
-from repro.core.tpu_tiles import TileChoice, select_tile
+from repro.core.tpu_tiles import TileChoice, fit_bm, select_tile
 from .fcu_matmul import fcu_matmul_p
 
 
-def _pick_bm(m: int, want: int) -> int:
-    bm = min(want, m)
-    while m % bm:
-        bm -= 1
-    return max(1, bm)
-
-
-@functools.partial(jax.jit, static_argnames=("rate", "interpret", "bm", "bk", "bn"))
+@functools.partial(jax.jit, static_argnames=("rate", "bm", "bk", "bn"))
 def fcu_matmul(
     x: jax.Array,
     w: jax.Array,
     *,
     rate: Optional[Fraction] = None,
-    interpret: bool = True,
     bm: Optional[int] = None,
     bk: Optional[int] = None,
     bn: Optional[int] = None,
@@ -61,27 +53,26 @@ def fcu_matmul(
         t = select_tile(m, d_in, d_out, rate=rate, dtype_bytes=x.dtype.itemsize)
         bk = bk or t.bk
         bn = bn or t.bn
-        bm = bm or _pick_bm(m, t.bm)
+        bm = bm or fit_bm(m, t.bm)
     else:
-        bm = _pick_bm(m, bm)
-    out = fcu_matmul_p(xm, w, bm=bm, bk=bk, bn=bn, interpret=interpret)
+        bm = fit_bm(m, bm)
+    out = fcu_matmul_p(xm, w, bm=bm, bk=bk, bn=bn)
     return out.reshape(*lead, d_out)
 
 
 def _fcu_impl(
     rate: Optional[Fraction],
-    interpret: bool,
     tile: Optional[TileChoice],
     record: Optional[Callable[..., None]],
 ):
     def impl(x, w):
         if tile is None:
-            return fcu_matmul(x, w, rate=rate, interpret=interpret)
+            return fcu_matmul(x, w, rate=rate)
         m = 1
         for s in x.shape[:-1]:
             m *= s
-        bm = _pick_bm(m, tile.bm)
-        y = fcu_matmul(x, w, interpret=interpret, bm=bm, bk=tile.bk, bn=tile.bn)
+        bm = fit_bm(m, tile.bm)
+        y = fcu_matmul(x, w, bm=bm, bk=tile.bk, bn=tile.bn)
         if record is not None:
             record(
                 bk=tile.bk,
@@ -99,21 +90,19 @@ def _fcu_impl(
 def pointwise_impl(
     *,
     rate: Optional[Fraction] = None,
-    interpret: bool = True,
     tile: Optional[TileChoice] = None,
     record: Optional[Callable[..., None]] = None,
 ):
     """Adapter to the CNN executor's 'pointwise' signature (models/cnn.py):
     a 1x1 conv is exactly the FCU matmul over the pixel axis."""
-    return _fcu_impl(rate, interpret, tile, record)
+    return _fcu_impl(rate, tile, record)
 
 
 def dense_impl(
     *,
     rate: Optional[Fraction] = None,
-    interpret: bool = True,
     tile: Optional[TileChoice] = None,
     record: Optional[Callable[..., None]] = None,
 ):
     """Adapter to the CNN executor's 'dense' signature (models/cnn.py)."""
-    return _fcu_impl(rate, interpret, tile, record)
+    return _fcu_impl(rate, tile, record)

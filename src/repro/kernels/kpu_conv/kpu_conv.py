@@ -16,14 +16,16 @@ is the schedule:
     MXU's systolic reduction + scratch accumulation;
   * multi-pixel P: every output position of the block is computed per
     pass (the lane dimension), i.e. P = Wo;
-  * stride pruning (§II-E): the strided slice  x[:, dy::s, dx::s, :]
-    gathers only surviving-phase windows — skipped windows are never
-    materialized, the moral equivalent of deleting pruned KPUs;
+  * stride pruning (§II-E): the padded frame arrives split into its
+    stride phases (``kernels.common.phase_split``), so every tap reads
+    one contiguous window of one phase — only surviving-phase windows
+    exist, the moral equivalent of deleting pruned KPUs, and the kernel
+    never slices with a stride;
   * j -> bci input-channel tile (j | d_in), h -> d_out/bco output tile
     trips (h | d_out), C -> the (ci, tap) accumulation trip count.
 
-Input must be pre-padded (ops.py does 'SAME' padding); the pad-select
-signals of the FPGA become plain zero padding here.
+Input must be pre-padded and phase-split (ops.py does 'SAME' padding);
+the pad-select signals of the FPGA become plain zero padding here.
 """
 from __future__ import annotations
 
@@ -34,13 +36,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import pallas_call
 
-def _kpu_kernel(
-    x_ref, w_ref, o_ref, acc_ref, *, kh: int, kw: int, stride: int, grid_ci: int
-):
+
+def _kpu_kernel(x_ref, w_ref, o_ref, acc_ref, *, taps: tuple, grid_ci: int):
     """Grid: (n, co_blocks, ci_blocks).  Blocks:
-    x: [1, Hp, Wp, bci] (padded spatial), w: [kh, kw, bci, bco],
-    o/acc: [1, Ho, Wo, bco]."""
+    x: [1, P, Hq, Wq, bci] (padded frame split into P stride phases),
+    w: [kh, kw, bci, bco], o/acc: [1, Ho, Wo, bco].  ``taps`` gives per
+    (dy, dx) the (phase, row, col) its window starts at."""
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -48,28 +51,15 @@ def _kpu_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     _, ho, wo, _ = acc_ref.shape
-    x = x_ref[0]                      # [Hp, Wp, bci]
     # weight-stationary tap loop (static unroll = the C configurations)
-    for dy in range(kh):
-        for dx in range(kw):
-            # §II-E stride pruning: gather only surviving windows
-            win = jax.lax.slice(
-                x,
-                (dy, dx, 0),
-                (
-                    dy + (ho - 1) * stride + 1,
-                    dx + (wo - 1) * stride + 1,
-                    x.shape[-1],
-                ),
-                (stride, stride, 1),
-            )  # [Ho, Wo, bci]
-            w_tap = w_ref[dy, dx]  # [bci, bco]
-            acc_ref[0] += jax.lax.dot_general(
-                win,
-                w_tap,
-                dimension_numbers=(((2,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+    for (dy, dx), (p, oy, ox) in taps:
+        win = x_ref[0, p, oy : oy + ho, ox : ox + wo, :]  # [Ho, Wo, bci]
+        acc_ref[0] += jax.lax.dot_general(
+            win,
+            w_ref[dy, dx],  # [bci, bco]
+            dimension_numbers=(((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(ci == grid_ci - 1)
     def _flush():
@@ -77,17 +67,16 @@ def _kpu_kernel(
 
 
 def kpu_conv_p(
-    x_padded: jax.Array,     # [N, Hp, Wp, d_in]  (pre-padded)
+    x_phases: jax.Array,     # [N, P, Hq, Wq, d_in]  (padded, phase-split)
     w: jax.Array,            # [kh, kw, d_in, d_out]
     *,
+    taps: tuple,
     out_hw: tuple,
-    stride: int = 1,
     bci: int,
     bco: int,
-    interpret: bool = True,
     out_dtype=None,
 ) -> jax.Array:
-    n, hp, wp, d_in = x_padded.shape
+    n, n_ph, hq, wq, d_in = x_phases.shape
     kh, kw, d_in2, d_out = w.shape
     assert d_in == d_in2
     assert d_in % bci == 0 and d_out % bco == 0, (
@@ -95,18 +84,17 @@ def kpu_conv_p(
     )
     ho, wo = out_hw
     grid = (n, d_out // bco, d_in // bci)
-    out_dtype = out_dtype or x_padded.dtype
-    return pl.pallas_call(
-        functools.partial(
-            _kpu_kernel, kh=kh, kw=kw, stride=stride, grid_ci=grid[2]
-        ),
+    out_dtype = out_dtype or x_phases.dtype
+    return pallas_call(
+        functools.partial(_kpu_kernel, taps=taps, grid_ci=grid[2]),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, hp, wp, bci), lambda nn, co, ci: (nn, 0, 0, ci)),
+            pl.BlockSpec(
+                (1, n_ph, hq, wq, bci), lambda nn, co, ci: (nn, 0, 0, 0, ci)
+            ),
             pl.BlockSpec((kh, kw, bci, bco), lambda nn, co, ci: (0, 0, ci, co)),
         ],
         out_specs=pl.BlockSpec((1, ho, wo, bco), lambda nn, co, ci: (nn, 0, 0, co)),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, d_out), out_dtype),
         scratch_shapes=[pltpu.VMEM((1, ho, wo, bco), jnp.float32)],
-        interpret=interpret,
-    )(x_padded, w)
+    )(x_phases, w)

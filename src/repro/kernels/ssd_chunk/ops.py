@@ -10,7 +10,7 @@ from repro.core.rate import divisors
 from .ssd_chunk import ssd_chunk_p
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "head_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "head_block"))
 def ssd_chunk(
     x,
     dt,
@@ -20,7 +20,6 @@ def ssd_chunk(
     *,
     chunk: int = 128,
     head_block: int | None = None,
-    interpret: bool = True,
 ):
     """x: [B,L,H,P]; dt: [B,L,H]; a: [H]; b,c: [B,L,G,N] (G | H)."""
     h = x.shape[2]
@@ -30,6 +29,4 @@ def ssd_chunk(
         c = jnp.repeat(c, h // g, axis=2)
     if head_block is None:
         head_block = max(d for d in divisors(h) if d <= 8)
-    return ssd_chunk_p(
-        x, dt, a, b, c, chunk=chunk, head_block=head_block, interpret=interpret
-    )
+    return ssd_chunk_p(x, dt, a, b, c, chunk=chunk, head_block=head_block)

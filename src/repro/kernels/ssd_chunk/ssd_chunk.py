@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import pallas_call
+
 
 def _ssd_kernel(
     x_ref,
@@ -88,7 +90,6 @@ def ssd_chunk_p(
     *,
     chunk: int,
     head_block: int = 8,
-    interpret: bool = True,
 ):
     bsz, l, h, p = x.shape
     n = b.shape[-1]
@@ -100,7 +101,7 @@ def ssd_chunk_p(
     bc = b.reshape(bsz, nc, chunk, h, n)
     cc = c.reshape(bsz, nc, chunk, h, n)
 
-    y, s = pl.pallas_call(
+    y, s = pallas_call(
         functools.partial(_ssd_kernel, n_chunks=nc, q=chunk),
         grid=grid,
         in_specs=[
@@ -129,6 +130,5 @@ def ssd_chunk_p(
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((head_block, p, n), jnp.float32)],
-        interpret=interpret,
     )(xc, dtc, a, bc, cc)
     return y.reshape(bsz, l, h, p), s

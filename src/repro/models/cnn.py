@@ -127,7 +127,6 @@ def default_impls() -> Dict[str, Impl]:
 
 def kernel_impls(
     *,
-    interpret: bool = True,
     rate=None,
     plan: Optional[Mapping[str, ImplPlan]] = None,
     executed: Optional[Dict[str, Dict[str, int]]] = None,
@@ -135,8 +134,9 @@ def kernel_impls(
     """Pallas-kernel-backed implementations (KPU / DW / FCU).
 
     Imported lazily so graph-only callers never pay for (or break on)
-    the Pallas stack; ``interpret=True`` runs the kernels in interpreter
-    mode on CPU.
+    the Pallas stack.  Where the kernels run is the backend's choice
+    (``kernels.common.pallas_call``): Mosaic-compiled on a TPU, the
+    Pallas interpreter elsewhere.
 
     Without ``plan`` this is the **uniform** path: four kind-level impls
     whose tiles come from ``select_tile`` under one global ``rate``
@@ -162,8 +162,7 @@ def kernel_impls(
         "dense": dense_impl,
     }
     table: Dict[str, Impl] = {
-        kind: make(rate=rate, interpret=interpret)
-        for kind, make in factories.items()
+        kind: make(rate=rate) for kind, make in factories.items()
     }
     if plan is None:
         return table
@@ -177,9 +176,7 @@ def kernel_impls(
         record = None
         if executed is not None:
             record = _tile_recorder(executed, name)
-        table[name] = factories[node_plan.kind](
-            interpret=interpret, tile=node_plan.tile, record=record
-        )
+        table[name] = factories[node_plan.kind](tile=node_plan.tile, record=record)
     return table
 
 
@@ -363,7 +360,8 @@ def _check_planned_tile(
     paper's j and d_out/h images — must match the plan exactly and
     divide the live array dims.  When the plan was pinned to a serving
     batch (``kernel_plan(batch=B)`` — ``ImplPlan.batch`` set), the fcu
-    kinds additionally must execute the planned bm on the planned m:
+    kinds (and convs planned on the im2col route, which run the FCU)
+    additionally must execute the planned bm on the planned m:
     the micro-batcher promised that shape, so a mismatch is a serving
     bug, not a legal re-fit.
     """
@@ -392,7 +390,9 @@ def _check_planned_tile(
             f"{spec.name}: planned tile (bk={t.bk}, bn={t.bn}) does not "
             f"divide live dims ({d_in}, {d_out})"
         )
-    if node_plan.batch is not None and spec.kind in ("pointwise", "dense"):
+    if node_plan.batch is not None and (
+        spec.kind in ("pointwise", "dense") or t.im2col
+    ):
         want_m = node_plan.batch * spec.out_hw[0] * spec.out_hw[1]
         if got.get("m") != want_m:
             raise GraphExecutionError(
@@ -425,7 +425,6 @@ def _build_table(
     plan: Optional[Mapping[str, ImplPlan]],
     overrides: Optional[Mapping[str, Impl]],
     graph: LayerGraph,
-    interpret: bool,
     executed: Dict[str, Dict[str, int]],
 ) -> Dict[str, Impl]:
     """Assemble the dispatch table: kind-level defaults, then plan-derived
@@ -434,7 +433,7 @@ def _build_table(
     graph so a typoed node name fails loudly)."""
     table = default_impls()
     if plan is not None:
-        table.update(kernel_impls(interpret=interpret, plan=plan, executed=executed))
+        table.update(kernel_impls(plan=plan, executed=executed))
     if impls:
         table.update(impls)
     if overrides:
@@ -604,7 +603,6 @@ def apply_graph(
     impls: Optional[Dict[str, Impl]] = None,
     plan: Optional[Mapping[str, ImplPlan]] = None,
     overrides: Optional[Mapping[str, Impl]] = None,
-    interpret: bool = True,
     executed: Optional[Dict[str, Dict[str, int]]] = None,
     dtype=jnp.float32,
     check: bool = True,
@@ -619,8 +617,8 @@ def apply_graph(
 
     ``plan`` switches to rate-matched execution: a per-node ``ImplPlan``
     table (``core.graph.GraphPlan.kernel_plan()``) from which one Pallas
-    impl per arithmetic node is built (``kernel_impls(plan=...)``,
-    honouring ``interpret``), each dispatching its node's own
+    impl per arithmetic node is built (``kernel_impls(plan=...)``),
+    each dispatching its node's own
     (j, h)-derived tile.  After each planned node executes, the tile the
     kernel reported is asserted equal to the plan's (see
     ``_check_planned_tile``) — the executable network provably follows
@@ -653,7 +651,6 @@ def apply_graph(
         plan=plan,
         overrides=overrides,
         graph=graph,
-        interpret=interpret,
         executed=executed,
     )
     values: Dict[str, jax.Array] = {}
@@ -686,36 +683,44 @@ def resolve_stage_devices(placement, n_stages: int, partition=None):
 
     * ``True`` — the partition's recorded ``placement`` ordinals
       (``GraphStagePlan.placement``, e.g. from ``plan_graph(...,
-      n_devices=)``) when present, else round-robin over every local
-      device: stage ``s`` on ``jax.devices()[s % n_devices]``.
-    * an ``int`` n — round-robin over the first ``min(n, available)``
-      local devices.
+      n_devices=)``) when present, else one device per stage: stage
+      ``s`` on ``jax.devices()[s]``.
+    * an ``int`` n — round-robin over the first n local devices.
     * a sequence of device *ordinals* — indices into ``jax.devices()``,
-      folded modulo the live device count (the fewer-devices-than-
-      stages / smaller-host fallback: placement degrades to co-resident
-      stages, never to an error).
+      cycled when shorter than the stage count (``(0,)`` puts every
+      stage on device 0).
     * a sequence of ``jax.Device`` objects — used round-robin.
+
+    Asking for more distinct devices than exist raises
+    ``GraphExecutionError``: stages never fold silently onto fewer
+    devices.  Co-resident stages are placed only when asked for by
+    name (repeated ordinals, ``n_devices=`` at planning, or an int).
     """
     if placement is None or placement is False:
         return None
     devs = jax.devices()
     if placement is True:
         recorded = getattr(partition, "placement", None)
-        placement = recorded if recorded is not None else len(devs)
+        placement = tuple(recorded or range(n_stages))
     if isinstance(placement, int):
-        if placement < 1:
+        if not 1 <= placement <= len(devs):
             raise GraphExecutionError(
-                f"placement needs >= 1 device, got {placement}"
+                f"placement over {placement} devices, but "
+                f"{len(devs)} exist"
             )
-        pool = devs[: min(placement, len(devs))]
-        return tuple(pool[s % len(pool)] for s in range(n_stages))
+        placement = tuple(range(placement))
     seq = list(placement)
     if not seq:
         raise GraphExecutionError("placement sequence is empty")
     if all(isinstance(p, int) for p in seq):
-        return tuple(
-            devs[seq[s % len(seq)] % len(devs)] for s in range(n_stages)
-        )
+        bad = [p for p in seq if not 0 <= p < len(devs)]
+        if bad:
+            raise GraphExecutionError(
+                f"placement asks for device ordinals {bad}, but only "
+                f"{len(devs)} device(s) exist (stages {n_stages}; pass "
+                f"repeated ordinals to co-locate stages)"
+            )
+        seq = [devs[p] for p in seq]
     return tuple(seq[s % len(seq)] for s in range(n_stages))
 
 
@@ -766,7 +771,6 @@ def stage_functions(
     impls: Optional[Dict[str, Impl]] = None,
     plan: Optional[Mapping[str, ImplPlan]] = None,
     overrides: Optional[Mapping[str, Impl]] = None,
-    interpret: bool = True,
     executed: Optional[Dict[str, Dict[str, int]]] = None,
     check: bool = True,
     jit: bool = True,
@@ -812,7 +816,7 @@ def stage_functions(
 
     ``cache`` (a plain dict the caller owns) memoizes the compiled
     pipeline on the identity of (graph, partition, plan, impls,
-    overrides, link_quant, placement) plus the interpret/check/jit
+    overrides, link_quant, placement) plus the check/jit
     knobs, so repeated one-shot calls (``apply_staged`` via
     ``registry.CNNApi``) hit the per-stage jit cache instead of
     retracing every stage per call.  Skipped when ``executed`` is given
@@ -823,7 +827,7 @@ def stage_functions(
     if cache is not None and executed is None:
         cache_refs = (graph, partition, plan, impls, overrides, link_quant, placement)
         cache_key, hit = _pipeline_cache_get(
-            cache, cache_refs, (interpret, check, jit)
+            cache, cache_refs, (check, jit)
         )
         if hit is not None:
             return hit
@@ -854,7 +858,6 @@ def stage_functions(
         plan=plan,
         overrides=overrides,
         graph=graph,
-        interpret=interpret,
         executed=executed,
     )
     overridden = frozenset(overrides or ())
@@ -1063,7 +1066,6 @@ def staged_forward(
     impls: Optional[Dict[str, Impl]] = None,
     plan: Optional[Mapping[str, ImplPlan]] = None,
     overrides: Optional[Mapping[str, Impl]] = None,
-    interpret: bool = True,
     executed: Optional[Dict[str, Dict[str, int]]] = None,
     dtype=jnp.float32,
     check: bool = True,
@@ -1095,7 +1097,6 @@ def staged_forward(
         impls=impls,
         plan=plan,
         overrides=overrides,
-        interpret=interpret,
         executed=executed,
         check=check,
         jit=jit,
@@ -1123,7 +1124,6 @@ def apply_staged(
     impls: Optional[Dict[str, Impl]] = None,
     plan: Optional[Mapping[str, ImplPlan]] = None,
     overrides: Optional[Mapping[str, Impl]] = None,
-    interpret: bool = True,
     executed: Optional[Dict[str, Dict[str, int]]] = None,
     dtype=jnp.float32,
     check: bool = True,
@@ -1172,7 +1172,6 @@ def apply_staged(
         impls=impls,
         plan=plan,
         overrides=overrides,
-        interpret=interpret,
         executed=executed if user_executed else None,
         dtype=dtype,
         check=check,
@@ -1189,7 +1188,6 @@ def apply_staged(
             plan=plan,
             overrides=overrides,
             graph=graph,
-            interpret=interpret,
             executed=executed,
         )
         qmap = _resolve_link_quant(link_quant, graph, partition)
@@ -1258,7 +1256,6 @@ def apply_int8(
     plan: Optional[Mapping[str, ImplPlan]] = None,
     overrides: Optional[Mapping[str, Impl]] = None,
     partition=None,
-    interpret: bool = True,
     dtype=jnp.float32,
     check: bool = True,
     jit: bool = True,
@@ -1280,7 +1277,6 @@ def apply_int8(
             impls=impls,
             plan=plan,
             overrides=overrides,
-            interpret=interpret,
             dtype=dtype,
             check=check,
             jit=jit,
@@ -1292,7 +1288,6 @@ def apply_int8(
         impls=impls,
         plan=plan,
         overrides=overrides,
-        interpret=interpret,
         dtype=dtype,
         check=check,
     )

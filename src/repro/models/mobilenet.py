@@ -239,7 +239,6 @@ def apply(
     conv_impls: Optional[Dict[str, cnn.Impl]] = None,
     plan=None,
     overrides=None,
-    interpret: bool = True,
     check: bool = True,
 ) -> jax.Array:
     """Forward pass.  ``x``: [N, H, W, 3].  Returns logits [N, classes].
@@ -258,7 +257,6 @@ def apply(
         impls=conv_impls,
         plan=plan,
         overrides=overrides,
-        interpret=interpret,
         dtype=cfg.dtype,
         check=check,
     )
@@ -273,7 +271,6 @@ def apply_staged(
     conv_impls: Optional[Dict[str, cnn.Impl]] = None,
     plan=None,
     overrides=None,
-    interpret: bool = True,
     check: bool = True,
     jit: bool = True,
     check_monolithic: bool = False,
@@ -297,7 +294,6 @@ def apply_staged(
         impls=conv_impls,
         plan=plan,
         overrides=overrides,
-        interpret=interpret,
         dtype=cfg.dtype,
         check=check,
         jit=jit,
@@ -321,7 +317,6 @@ def apply_int8(
     plan=None,
     overrides=None,
     partition=None,
-    interpret: bool = True,
     jit: bool = True,
 ) -> jax.Array:
     """Inference with int8 weights dequantized on the fly (sim of the
@@ -335,7 +330,6 @@ def apply_int8(
         plan=plan,
         overrides=overrides,
         partition=partition,
-        interpret=interpret,
         dtype=cfg.dtype,
         jit=jit,
     )

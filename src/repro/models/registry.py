@@ -164,7 +164,7 @@ class CNNApi:
     per-stage pipeline with BestRate admission control and bounded
     inter-stage queues.  Returns ``(outputs, ServeReport)``.
     ``serve(..., execute="devices")`` places each stage on its own
-    device (round-robin over ``jax.devices()``) so the engine pumps
+    device (stage ``s`` on ``jax.devices()[s]``) so the engine pumps
     genuinely overlapped stages — wall-clock, not only ticks.
 
     Every ``CNNApi`` owns a set of memo ``caches`` (graphs per config,

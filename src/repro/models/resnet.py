@@ -140,7 +140,6 @@ def apply(
     conv_impls: Optional[Dict[str, cnn.Impl]] = None,
     plan=None,
     overrides=None,
-    interpret: bool = True,
     check: bool = True,
 ) -> jax.Array:
     """Forward pass.  ``x``: [N, H, W, 3].  Returns logits [N, classes].
@@ -158,7 +157,6 @@ def apply(
         impls=conv_impls,
         plan=plan,
         overrides=overrides,
-        interpret=interpret,
         dtype=cfg.dtype,
         check=check,
     )
@@ -173,7 +171,6 @@ def apply_staged(
     conv_impls: Optional[Dict[str, cnn.Impl]] = None,
     plan=None,
     overrides=None,
-    interpret: bool = True,
     check: bool = True,
     jit: bool = True,
     check_monolithic: bool = False,
@@ -196,7 +193,6 @@ def apply_staged(
         impls=conv_impls,
         plan=plan,
         overrides=overrides,
-        interpret=interpret,
         dtype=cfg.dtype,
         check=check,
         jit=jit,
@@ -219,7 +215,6 @@ def apply_int8(
     plan=None,
     overrides=None,
     partition=None,
-    interpret: bool = True,
     jit: bool = True,
 ) -> jax.Array:
     return cnn.apply_int8(
@@ -230,7 +225,6 @@ def apply_int8(
         plan=plan,
         overrides=overrides,
         partition=partition,
-        interpret=interpret,
         dtype=cfg.dtype,
         jit=jit,
     )

@@ -24,8 +24,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from .layers import _dense_init
 
 
@@ -191,7 +189,7 @@ def ssd_seq_parallel(x, dt, a, b, c, *, chunk: int, mesh, axis: str = "model"):
                             c_h.astype(jnp.float32), jnp.exp(acum), s_in)
         return y, s_fin
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, axis, None, None), P(bspec, axis, None), P(None),
                   P(bspec, axis, None, None), P(bspec, axis, None, None)),

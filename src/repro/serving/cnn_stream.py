@@ -579,12 +579,12 @@ class _Rung:
                 impls=config.impls,
                 plan=kernel_plan,
                 overrides=config.overrides,
-                interpret=config.interpret,
                 check=config.check,
                 jit=config.jit,
                 link_quant=config.link_quant,
-                # "devices": stage s committed to jax.devices()[s % n] so
-                # the engine's interleaved stage pumping overlaps on real
+                # "devices": one device per stage (the plan's recorded
+                # placement, else stage s on jax.devices()[s]) so the
+                # engine's interleaved stage pumping overlaps on real
                 # silicon (async dispatch per device queue).
                 placement=(True if config.execute == "devices" else None),
                 cache=config.pipeline_cache,
@@ -605,7 +605,6 @@ _LEGACY_INIT = (
     "kernel_plan",
     "impls",
     "overrides",
-    "interpret",
     "dtype",
     "check",
     "jit",
@@ -649,7 +648,6 @@ class CNNStreamEngine:
         kernel_plan=_UNSET,
         impls=_UNSET,
         overrides=_UNSET,
-        interpret=_UNSET,
         dtype=_UNSET,
         check=_UNSET,
         jit=_UNSET,
@@ -664,7 +662,6 @@ class CNNStreamEngine:
                     kernel_plan,
                     impls,
                     overrides,
-                    interpret,
                     dtype,
                     check,
                     jit,
@@ -1511,7 +1508,6 @@ def serve_frames(
     arrival_rate=None,
     microbatch: Optional[int] = None,
     rate_matched: bool = False,
-    interpret: Optional[bool] = None,
     dtype=None,
     check: Optional[bool] = None,
     jit: Optional[bool] = None,
@@ -1538,9 +1534,10 @@ def serve_frames(
     buffer-aware cuts); pair them with ``config.link_quant`` to make
     the executed boundaries match the priced wire format.
 
-    ``execute="devices"`` places each stage on its own device
-    (round-robin over ``jax.devices()``).  ``plan_cache`` memoizes the
-    DSE result per (graph identity, rate, stages, kwargs) so repeated
+    ``execute="devices"`` places each stage on its own device (pass
+    ``n_devices=`` to record a placement that co-locates stages).
+    ``plan_cache`` memoizes the DSE result per (graph identity, rate,
+    stages, kwargs) so repeated
     calls — e.g. through ``CNNApi.serve`` — skip re-planning; pair with
     ``config.pipeline_cache`` to also skip re-jitting the stages.
     """
@@ -1549,7 +1546,6 @@ def serve_frames(
     cfg = config if config is not None else ServeConfig()
     overrides = {
         "microbatch": microbatch,
-        "interpret": interpret,
         "dtype": dtype,
         "check": check,
         "jit": jit,

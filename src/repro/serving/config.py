@@ -8,7 +8,9 @@ groups:
 
 * **execution knobs** — how admitted micro-batches are computed
   (``microbatch``, ``kernel_plan``, ``impls``, ``overrides``,
-  ``interpret``, ``dtype``, ``check``, ``jit``, ``execute``);
+  ``dtype``, ``check``, ``jit``, ``execute``); where Pallas kernels
+  run (compiled on a TPU, interpreted elsewhere) is the backend's
+  choice, not a knob;
 * **arrival source** — what traffic the run sees: a bare rate
   (frames/tick, the legacy constant process) or any
   ``serving.scenarios.ArrivalProcess`` (``arrival``), plus the run
@@ -51,13 +53,13 @@ class ServeConfig:
     kernel_plan: Optional[Mapping[str, Any]] = None
     impls: Optional[Mapping[str, Any]] = None
     overrides: Optional[Mapping[str, Any]] = None
-    interpret: bool = True
     dtype: Any = None
     check: bool = True
     jit: bool = True
     # False = plan/validate only; True = run stages (host placement);
-    # "devices" = place stage s on jax.devices()[s % n] so the engine's
-    # interleaved stage pumping overlaps on real silicon (see
+    # "devices" = one device per stage (the plan's recorded placement,
+    # else stage s on jax.devices()[s]; too few devices raise) so the
+    # engine's interleaved stage pumping overlaps on real silicon (see
     # distributed.device_pipeline for the wall-clock harness).
     execute: Any = True
     # Quantized cut crossings (models.cnn.stage_functions link_quant):

@@ -125,3 +125,73 @@ def test_roofline_terms_math():
     assert t.dominant in ("compute", "memory")
     assert t.roofline_fraction == pytest.approx(0.5, rel=0.01)
     assert t.useful_flops_ratio == pytest.approx(0.5, rel=0.01)
+
+
+# ---------------------------------------------------------------------------
+# VMEM accounting and conv routes (what the v5e compiler holds kernels to)
+# ---------------------------------------------------------------------------
+
+
+def test_padded_bytes_counts_lane_and_sublane_tiles():
+    from repro.core.hw_specs import TPU_V5E
+    from repro.core.tpu_tiles import padded_bytes
+
+    # the stem's padded frame: 3 channels occupy 128 lanes, 230 rows 232
+    assert padded_bytes((230, 230, 3), 4, TPU_V5E) == 230 * 232 * 128 * 4
+    # packed bf16 rows come in 16s
+    assert padded_bytes((230, 230, 3), 2, TPU_V5E) == 230 * 240 * 128 * 2
+    assert padded_bytes((8, 128), 4, TPU_V5E) == 8 * 128 * 4
+    assert padded_bytes((5,), 4, TPU_V5E) == 8 * 128 * 4
+
+
+@pytest.mark.parametrize("hw,k,s", [(8, 3, 1), (8, 3, 2), (9, 7, 2), (7, 1, 2)])
+def test_conv_geometry_taps_read_the_strided_windows(hw, k, s):
+    """Tap (dy, dx) of the phase-split frame == the strided window
+    x_pad[dy::s, dx::s] of the SAME-padded frame (§II-E)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.tpu_tiles import conv_geometry
+    from repro.kernels.common import conv_taps, phase_split
+
+    x = jnp.arange(2 * hw * hw * 3, dtype=jnp.float32).reshape(2, hw, hw, 3)
+    geo = conv_geometry((hw, hw), (k, k), s)
+    ho, wo = geo.out_hw
+    assert ho == -(-hw // s)
+    xp = np.pad(np.asarray(x), ((0, 0), *geo.pads, (0, 0)))
+    xs = np.asarray(phase_split(x, geo))
+    assert xs.shape[1] == len(geo.phases)
+    for (dy, dx), (p, oy, ox) in conv_taps(geo, (k, k)):
+        want = xp[:, dy : dy + (ho - 1) * s + 1 : s, dx : dx + (wo - 1) * s + 1 : s]
+        np.testing.assert_array_equal(xs[:, p, oy : oy + ho, ox : ox + wo], want)
+
+
+@pytest.mark.parametrize(
+    "family", ["resnet18", "resnet34", "mobilenet_v1", "mobilenet_v2"]
+)
+def test_kernel_plan_at_224_fits_vmem_and_routes_only_stems(family):
+    from repro.core.hw_specs import TPU_V5E
+    from repro.core.tpu_tiles import vmem_budget
+    from repro.models.registry import get_cnn_api
+
+    api = get_cnn_api(family)
+    gp = api.partition(api.make_config(), F(3), 1)
+    kp = gp.kernel_plan(batch=8)
+    budget = vmem_budget(TPU_V5E)
+    tiled = {n: p for n, p in kp.items() if p.tile is not None}
+    assert tiled
+    for name, node in tiled.items():
+        assert node.tile.vmem_bytes <= budget, (name, node.tile)
+        # only the lane-sparse 3-channel stem overflows its frame budget
+        assert node.tile.im2col == (name == "conv1"), (name, node.tile)
+
+
+def test_tpu_spec_is_keyed_by_device_kind():
+    from repro.core.hw_specs import TPU_V5E, target_spec, tpu_spec
+
+    assert tpu_spec("TPU v5 lite") is TPU_V5E
+    assert TPU_V5E.vmem_bytes == 128 * 1024**2
+    with pytest.raises(KeyError, match="no TPUSpec"):
+        tpu_spec("TPU v9 imaginary")
+    # off a TPU, planning keeps v5e as its explicit target
+    assert target_spec() is TPU_V5E

@@ -1,8 +1,10 @@
 """DevicePipeline: placement resolution, GPipe schedule correctness,
 donation/double-buffering safety, the wall-clock report, and the
-serving engine's execute="devices" path — all on the single-CPU host
-(the fewer-devices-than-stages fallback; genuine multi-device overlap
-is exercised by examples/pipeline_demo.py's forced 4-device child)."""
+serving engine's execute="devices" path — all on the single-CPU host,
+with stages co-resident by request (``placement=len(jax.devices())``,
+``n_devices=``); asking for more distinct devices than exist raises.
+Genuine multi-device overlap is exercised by examples/pipeline_demo.py's
+4-virtual-CPU-device child."""
 
 from fractions import Fraction as F
 
@@ -59,16 +61,19 @@ def test_plan_graph_records_placement(resnet):
 
 def test_resolve_stage_devices_forms():
     devs = jax.devices()
+    n = len(devs)
     # None/False: unplaced
     assert cnn.resolve_stage_devices(None, 3) is None
     assert cnn.resolve_stage_devices(False, 3) is None
-    # int: round-robin over min(n, available)
-    got = cnn.resolve_stage_devices(2, 3)
-    pool = devs[: min(2, len(devs))]
-    assert got == tuple(pool[s % len(pool)] for s in range(3))
-    # ordinal sequence folds modulo the live device count (fallback)
-    got = cnn.resolve_stage_devices((0, 1, 2), 3)
-    assert len(got) == 3 and all(d in devs for d in got)
+    # int: round-robin over the first n devices
+    got = cnn.resolve_stage_devices(1, 3)
+    assert got == (devs[0],) * 3
+    got = cnn.resolve_stage_devices(n, n + 1)
+    assert got == tuple(devs[s % n] for s in range(n + 1))
+    # ordinal sequence: cycled over the stages; repeats co-locate
+    assert cnn.resolve_stage_devices((0,), 3) == (devs[0],) * 3
+    got = cnn.resolve_stage_devices(tuple(range(n)), n)
+    assert got == tuple(devs)
     # explicit Device objects round-robin
     got = cnn.resolve_stage_devices((devs[0],), 3)
     assert got == (devs[0],) * 3
@@ -76,6 +81,13 @@ def test_resolve_stage_devices_forms():
         cnn.resolve_stage_devices(0, 3)
     with pytest.raises(cnn.GraphExecutionError):
         cnn.resolve_stage_devices((), 3)
+    # more distinct devices than exist: an error, never a silent fold
+    with pytest.raises(cnn.GraphExecutionError, match="exist"):
+        cnn.resolve_stage_devices(n + 1, n + 1)
+    with pytest.raises(cnn.GraphExecutionError, match="exist"):
+        cnn.resolve_stage_devices(tuple(range(n + 1)), n + 1)
+    with pytest.raises(cnn.GraphExecutionError, match="exist"):
+        cnn.resolve_stage_devices(True, n + 1)
 
 
 def test_device_pipeline_requires_placement(resnet):
@@ -95,7 +107,7 @@ def test_device_placement_rows_structural():
 
 
 # ---------------------------------------------------------------------------
-# schedule correctness (single-CPU mesh: placement degrades to co-resident)
+# schedule correctness (single-CPU host: stages co-resident by request)
 # ---------------------------------------------------------------------------
 
 
@@ -105,7 +117,9 @@ def test_gpipe_matches_staged_forward(resnet):
     plan = api.partition(cfg, F(1), 3)
     sf = cnn.staged_forward(graph, partition=plan)
     ref = np.asarray(sf(params, x)["fc"])
-    dp = DevicePipeline.build(graph, params, partition=plan, placement=True)
+    dp = DevicePipeline.build(
+        graph, params, partition=plan, placement=len(jax.devices())
+    )
     assert dp.n_stages == 3
     assert len(dp.placement_ordinals()) == 3
     for mb in (1, 2, 4):  # M = 4, 2, 1 (1 micro-batch = degenerate schedule)
@@ -129,7 +143,7 @@ def test_repeated_runs_and_donation_safety(resnet):
     api, cfg, params, x = resnet
     plan = api.partition(cfg, F(1), 2)
     dp = DevicePipeline.build(
-        api.graph(cfg), params, partition=plan, placement=True
+        api.graph(cfg), params, partition=plan, placement=len(jax.devices())
     )
     a = np.asarray(dp.run(x, microbatch=2))
     b = np.asarray(dp.run(x, microbatch=2))
@@ -142,7 +156,7 @@ def test_measure_report_fields(resnet):
     api, cfg, params, x = resnet
     plan = api.partition(cfg, F(1), 2)
     dp = DevicePipeline.build(
-        api.graph(cfg), params, partition=plan, placement=True
+        api.graph(cfg), params, partition=plan, placement=len(jax.devices())
     )
     rep = dp.measure(x, microbatch=1, warmup=1, repeats=1)
     assert rep.frames == 4 and rep.n_micro == 4 and rep.n_stages == 2
@@ -172,6 +186,7 @@ def test_serve_execute_devices_matches_host(resnet):
     )
     placed, rep = serve_frames(
         graph, params, x, input_rate=F(1), n_stages=2,
+        n_devices=len(jax.devices()),
         config=ServeConfig(execute="devices", microbatch=2),
     )
     np.testing.assert_allclose(placed, host, atol=1e-5, rtol=1e-5)

@@ -6,12 +6,16 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
 
+# `with mesh:` and with_sharding_constraint need Auto axes (jax.make_mesh
+# defaults to Explicit ones).
+_AUTO = (jax.sharding.AxisType.Auto,) * 2
+
 
 @pytest.fixture(scope="module")
 def mesh():
     # single-device mesh with the production axis names: rules must emit
     # specs whose axis sizes (1) divide everything -> specs still correct.
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_AUTO)
 
 
 def test_param_rules(mesh):
@@ -34,7 +38,7 @@ def test_param_rules(mesh):
 def test_divisibility_guard():
     import numpy as np
     import types
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = jax.make_mesh((1, 1), ("data", "model"), axis_types=_AUTO)
     assert shd._fits(92553, mesh1, "model")     # 1 divides everything
     # production-shaped stub (mesh_sizes only reads names + shape)
     fm = types.SimpleNamespace(axis_names=("data", "model"),

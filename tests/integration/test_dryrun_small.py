@@ -31,7 +31,8 @@ def test_cost_analysis_is_per_device():
     out = _run("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         w = jax.ShapeDtypeStruct((256, 512), jnp.float32,
             sharding=NamedSharding(mesh, P("data", "model")))
         x = jax.ShapeDtypeStruct((64, 256), jnp.float32,
@@ -60,7 +61,8 @@ def test_small_mesh_train_cell_compiles():
         cfg = reduced(get_config("qwen2-7b"), layers=2, d_model=64, vocab=256)
         cfg = dataclasses.replace(cfg, grad_accum=2)
         shape = ShapeSuite("t", seq_len=64, global_batch=8, kind="train")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         fn, args = build_cell(cfg, shape, mesh)
         with mesh:
             compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
@@ -90,7 +92,8 @@ def test_serve_cells_compile_small_mesh():
             cfg = reduced(get_config(arch), layers=2, d_model=64, vocab=256)
             for kind, seq, b in (("prefill", 64, 8), ("decode", 64, 8)):
                 shape = ShapeSuite("s", seq_len=seq, global_batch=b, kind=kind)
-                mesh = jax.make_mesh((4, 2), ("data", "model"))
+                mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
                 fn, args = build_cell(cfg, shape, mesh)
                 with mesh:
                     donate = (2,) if kind == "prefill" else (1,)
@@ -105,7 +108,8 @@ def test_multipod_mesh_axis():
     out = _run("""
         import jax, jax.numpy as jnp
         from repro.distributed import sharding as shd
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         batch = {"tokens": jax.ShapeDtypeStruct((8, 16), jnp.int32)}
         sh = shd.batch_specs(batch, mesh)
         spec = sh["tokens"].spec
@@ -122,7 +126,8 @@ def test_pipeline_parallel_ring():
         from jax.sharding import Mesh
         from repro.distributed.pipeline_parallel import pipeline_forward
 
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = jax.make_mesh((4,), ("stage",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         L, S, mb, d = 8, 4, 2, 16   # 8 layers -> 4 stages x 2 layers
         key = jax.random.key(0)
         w = jax.random.normal(key, (L, d, d)) * 0.1

@@ -73,3 +73,20 @@ def test_kpu_first_layer_mobilenet_shape():
     assert got.shape == (1, 8, 8, 32)
     np.testing.assert_allclose(got, kpu_conv_ref(x, w, stride=2),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "hw,k,stride,cin",
+    [(12, 7, 2, 3), (9, 3, 2, 5), (8, 3, 1, 8), (8, 1, 2, 16)],
+)
+def test_kpu_im2col_route_matches_ref(hw, k, stride, cin):
+    """The im2col route (patches through the FCU matmul — the stems whose
+    whole frame overflows VMEM) computes the same conv as the KPU route."""
+    k1, k2 = jax.random.split(jax.random.key(5))
+    x = _rand(k1, (2, hw, hw, cin))
+    w = _rand(k2, (k, k, cin, 16))
+    got = kpu_conv(x, w, stride=stride, bci=cin, bco=16, bm=8, im2col=True)
+    kpu = kpu_conv(x, w, stride=stride, im2col=False)
+    want = kpu_conv_ref(x, w, stride=stride)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(got, kpu, rtol=1e-4, atol=1e-4)

@@ -5,9 +5,10 @@ compute the same network as the sequential staged executor — allclose
 in fp32, and **bit-exact** with int8 quantized cut crossings when the
 comparison is at matched micro-batch granularity (the per-tensor
 dynamic link scales include the batch dim, so the reference must see
-the same micro-batches the schedule pumps).  Runs on the single-CPU
-host: stages co-resident (the fewer-devices fallback), schedule and
-transfers exercised in full."""
+the same micro-batches the schedule pumps).  Stages are placed round-robin
+over this host's devices (``placement=len(jax.devices())``): on the
+single-CPU host they are co-resident by request, schedule and transfers
+exercised in full."""
 
 from fractions import Fraction as F
 
@@ -46,7 +47,9 @@ def test_fp32_allclose(workloads, family, n_stages):
     graph = api.graph(cfg)
     plan = api.partition(cfg, F(1), n_stages)
     sf = cnn.staged_forward(graph, partition=plan)
-    dp = DevicePipeline.build(graph, params, partition=plan, placement=True)
+    dp = DevicePipeline.build(
+        graph, params, partition=plan, placement=len(jax.devices())
+    )
     ref = np.asarray(sf(params, x)[dp.pipeline.out_name])
     got = np.asarray(dp.run(x, microbatch=MB))
     np.testing.assert_allclose(got, ref, atol=1e-5, rtol=1e-5)
@@ -60,7 +63,11 @@ def test_int8_links_bit_exact(workloads, family, n_stages):
     plan = api.partition(cfg, F(1), n_stages, link_dtype="int8")
     sf = cnn.staged_forward(graph, partition=plan, link_quant=True)
     dp = DevicePipeline.build(
-        graph, params, partition=plan, placement=True, link_quant=True
+        graph,
+        params,
+        partition=plan,
+        placement=len(jax.devices()),
+        link_quant=True,
     )
     out = dp.pipeline.out_name
     ref = np.concatenate(
