@@ -20,8 +20,10 @@ dependent and ignored by the regression gate as always):
   pumped on one shared deterministic clock, per-tenant BestRate
   admission, zero stalls at <= the target rate, per-chip occupancy.
 * ``fleet/wallclock`` — the same pool executed for real (execute=True
-  with the shared ``obs.Tracer`` on): per-tenant measured fps from the
-  host-clock ``exec`` spans, next to the tick-domain throughput.
+  with the shared ``obs.Tracer`` on): per-tenant measured fps over the
+  host-clock envelope from the tenant's first ``ingest`` span to its
+  last ``fetch`` span (outputs back on the host, so the device's work
+  is inside it), next to the tick-domain throughput.
   Measured rows match check_regression's ``/wallclock`` default
   exclude — timing noise is not a regression.
 """
@@ -145,7 +147,8 @@ def _fleet_rows(pp) -> list:
 
 def _fleet_wallclock_rows(pp) -> list:
     """Measured per-tenant fps: the fleet executed on live devices with
-    the shared tracer recording host-clock ``exec`` spans.  A handful
+    the shared tracer recording host-clock ``ingest``/``fetch`` spans
+    (first ingest to last fetch, per tenant).  A handful
     of frames per tenant keeps the CI budget honest; every value here
     is wall-clock (unpinned by the ``/wallclock`` exclude)."""
     rows = []

@@ -42,7 +42,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from fractions import Fraction
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -50,7 +49,7 @@ import jax.numpy as jnp
 
 from repro.distributed.pipeline_parallel import microbatch_utilization
 from repro.models import cnn
-from repro.obs.trace import resolve_tracer
+from repro.obs.trace import host_now, resolve_tracer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,13 +186,13 @@ class DevicePipeline:
                 if not 0 <= m < M:
                     continue
                 if tr is not None:
-                    t0 = time.perf_counter()
+                    t0 = host_now()
                 pipe.run_stage(s, self.params, bnds[m], splits[m] if s == 0 else None)
                 if tr is not None:
                     tr.span(
                         "dispatch",
-                        Fraction(t0),
-                        Fraction(time.perf_counter()),
+                        t0,
+                        host_now(),
                         pid=f"dev{ords[s]}",
                         tid=f"stage{s}",
                         clock="host",
@@ -209,13 +208,13 @@ class DevicePipeline:
                     # double-buffer: start the cut crossing toward stage
                     # s+1 now, overlapping every other stage's compute
                     if tr is not None:
-                        t0 = time.perf_counter()
+                        t0 = host_now()
                     pipe.prefetch(s + 1, bnds[m])
                     if tr is not None:
                         tr.span(
                             "transfer",
-                            Fraction(t0),
-                            Fraction(time.perf_counter()),
+                            t0,
+                            host_now(),
                             pid=f"dev{ords[s]}",
                             tid=f"stage{s}",
                             clock="host",
@@ -284,12 +283,12 @@ class DevicePipeline:
             if self.tracer is None:
                 jax.block_until_ready(outs)
                 return
-            t0 = time.perf_counter()
+            t0 = host_now()
             jax.block_until_ready(outs)
             self.tracer.span(
                 "block_until_ready",
-                Fraction(t0),
-                Fraction(time.perf_counter()),
+                t0,
+                host_now(),
                 pid="host",
                 tid="measure",
                 clock="host",

@@ -98,8 +98,9 @@ class FleetReport:
     outputs: Dict[str, Optional[np.ndarray]]
     makespan_cycles: Fraction  # latest tenant finish, shared clock
     chip_occupancy: Dict[str, float]  # busy cycles / fleet makespan
-    # host wall-clock per tenant (seconds first dispatch -> last), from
-    # the shared obs.Tracer's "exec" spans; empty unless the fleet ran
+    # host wall-clock per tenant in seconds, from the first "ingest"
+    # span's start to the last "fetch" span's end on the shared
+    # obs.Tracer (outputs back on the host); empty unless the fleet ran
     # with tracing on AND execute (see docs/observability.md)
     tenant_wall_s: Dict[str, float] = dataclasses.field(default_factory=dict)
     # the shared obs.Tracer the engines recorded into (None when off)
@@ -362,11 +363,11 @@ class FleetScheduler:
         wall: Dict[str, float] = {}
         if self.tracer is not None:
             for name in reports:
-                spans = self.tracer.spans("exec", pid=name, clock="host")
-                if spans:
-                    wall[name] = float(
-                        max(s.end for s in spans) - min(s.start for s in spans)
-                    )
+                ingest = self.tracer.spans("ingest", pid=name, clock="host")
+                fetch = self.tracer.spans("fetch", pid=name, clock="host")
+                if ingest and fetch:
+                    ns = max(s.end for s in fetch) - min(s.start for s in ingest)
+                    wall[name] = ns * 1e-9
         return FleetReport(
             reports=reports,
             outputs=outputs,

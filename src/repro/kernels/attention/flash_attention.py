@@ -114,6 +114,7 @@ def flash_attention_p(
             block_k=block_k,
             grid_k=grid[2],
         ),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),

@@ -14,6 +14,8 @@ kernel too.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -23,9 +25,17 @@ from repro.core.hw_specs import target_spec
 from repro.core.tpu_tiles import ConvGeometry
 
 
-def pallas_call(kernel, **kwargs):
-    """``pl.pallas_call(kernel, **kwargs)`` whose interpret mode and VMEM
-    limit are decided by the backend; returns ``f(*operands)``."""
+def kernel_name(kind: str, node: Optional[str] = None) -> str:
+    """A kernel call's stable name: its kind, then the graph node it
+    serves (``kpu_conv.l1b1_conv1``), or the kind alone."""
+    return kind if node is None else f"{kind}.{node}"
+
+
+def pallas_call(kernel, *, name: str, **kwargs):
+    """``pl.pallas_call(kernel, name=name, **kwargs)`` whose interpret
+    mode and VMEM limit are decided by the backend; returns
+    ``f(*operands)``.  ``name`` (``kernel_name``) names the Mosaic call
+    in the compiled program, so that a trace finds it after a refactor."""
 
     def call(*operands):
         compiled = pl.pallas_call(
@@ -33,9 +43,10 @@ def pallas_call(kernel, **kwargs):
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=target_spec().vmem_bytes
             ),
+            name=name,
             **kwargs,
         )
-        interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+        interpreted = pl.pallas_call(kernel, interpret=True, name=name, **kwargs)
         return jax.lax.platform_dependent(
             *operands, tpu=compiled, default=interpreted
         )

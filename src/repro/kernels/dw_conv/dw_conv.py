@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import pallas_call
+from repro.kernels.common import kernel_name, pallas_call
 
 
 def _dw_kernel(x_ref, w_ref, o_ref, *, taps: tuple):
@@ -42,6 +42,7 @@ def dw_conv_p(
     out_hw: tuple,
     bc: int,
     out_dtype=None,
+    node=None,
 ) -> jax.Array:
     n, n_ph, hq, wq, c = x_phases.shape
     kh, kw, c2 = w.shape
@@ -50,6 +51,7 @@ def dw_conv_p(
     out_dtype = out_dtype or x_phases.dtype
     return pallas_call(
         functools.partial(_dw_kernel, taps=taps),
+        name=kernel_name("dw_conv", node),
         grid=(n, c // bc),
         in_specs=[
             pl.BlockSpec((1, n_ph, hq, wq, bc), lambda nn, cc: (nn, 0, 0, 0, cc)),

@@ -29,7 +29,7 @@ def _pick_bc(c: int, rate: Optional[Fraction]) -> int:
     return plan_dim_tile(c, min(want, c), 128)
 
 
-@functools.partial(jax.jit, static_argnames=("stride", "rate", "bc"))
+@functools.partial(jax.jit, static_argnames=("stride", "rate", "bc", "node"))
 def dw_conv(
     x: jax.Array,          # [N, H, W, C]
     w: jax.Array,          # [kh, kw, C]
@@ -37,6 +37,7 @@ def dw_conv(
     stride: int = 1,
     rate: Optional[Fraction] = None,
     bc: Optional[int] = None,
+    node: Optional[str] = None,
 ) -> jax.Array:
     n, h, wdt, c = x.shape
     kh, kw, _ = w.shape
@@ -47,6 +48,7 @@ def dw_conv(
         taps=conv_taps(geo, (kh, kw)),
         out_hw=geo.out_hw,
         bc=bc or _pick_bc(c, rate),
+        node=node,
     )
 
 
@@ -55,6 +57,7 @@ def dw_conv_impl(
     rate: Optional[Fraction] = None,
     tile: Optional[TileChoice] = None,
     record: Optional[Callable[..., None]] = None,
+    node: Optional[str] = None,
 ):
     """Adapter to the CNN executor's 'dwconv' signature (models/cnn.py).
 
@@ -62,7 +65,8 @@ def dw_conv_impl(
     layout, ``[kh, kw, 1, C]``); the kernel wants ``[kh, kw, C]``.
     ``tile`` pins the channel tile to a plan's choice; ``record`` is
     called with ``bk`` = the executed channel tile (bn is always 1 —
-    depthwise has no cross-channel output tiling).
+    depthwise has no cross-channel output tiling).  ``node`` names the
+    graph node in the kernel's name.
     """
     def impl(x, w, stride):
         if w.shape[-1] != x.shape[-1]:
@@ -72,7 +76,7 @@ def dw_conv_impl(
                 f"{x.shape[-1]} channels); use the lax dwconv impl"
             )
         bc = tile.bk if tile is not None else None
-        y = dw_conv(x, w[:, :, 0, :], stride=stride, rate=rate, bc=bc)
+        y = dw_conv(x, w[:, :, 0, :], stride=stride, rate=rate, bc=bc, node=node)
         if record is not None:
             record(bk=bc, bn=1, d_in=x.shape[-1], d_out=x.shape[-1])
         return y

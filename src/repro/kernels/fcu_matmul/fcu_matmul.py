@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import pallas_call
+from repro.kernels.common import kernel_name, pallas_call
 
 
 def _fcu_kernel(x_ref, w_ref, o_ref, acc_ref, *, grid_k: int):
@@ -52,6 +52,7 @@ def fcu_matmul_p(
     bk: int,
     bn: int,
     out_dtype=None,
+    node=None,
 ) -> jax.Array:
     """[m, d_in] @ [d_in, d_out] with explicit (bm, bk, bn) VMEM tiling.
 
@@ -68,6 +69,7 @@ def fcu_matmul_p(
     out_dtype = out_dtype or x.dtype
     return pallas_call(
         functools.partial(_fcu_kernel, grid_k=grid[2]),
+        name=kernel_name("fcu_matmul", node),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

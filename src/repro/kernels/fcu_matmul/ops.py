@@ -31,7 +31,7 @@ from repro.core.tpu_tiles import TileChoice, fit_bm, select_tile
 from .fcu_matmul import fcu_matmul_p
 
 
-@functools.partial(jax.jit, static_argnames=("rate", "bm", "bk", "bn"))
+@functools.partial(jax.jit, static_argnames=("rate", "bm", "bk", "bn", "node"))
 def fcu_matmul(
     x: jax.Array,
     w: jax.Array,
@@ -40,8 +40,10 @@ def fcu_matmul(
     bm: Optional[int] = None,
     bk: Optional[int] = None,
     bn: Optional[int] = None,
+    node: Optional[str] = None,
 ) -> jax.Array:
-    """x: [..., d_in] @ w: [d_in, d_out] -> [..., d_out]."""
+    """x: [..., d_in] @ w: [d_in, d_out] -> [..., d_out]; ``node`` names
+    the graph node in the kernel's name."""
     lead = x.shape[:-1]
     d_in = x.shape[-1]
     d_out = w.shape[-1]
@@ -56,7 +58,7 @@ def fcu_matmul(
         bm = bm or fit_bm(m, t.bm)
     else:
         bm = fit_bm(m, bm)
-    out = fcu_matmul_p(xm, w, bm=bm, bk=bk, bn=bn)
+    out = fcu_matmul_p(xm, w, bm=bm, bk=bk, bn=bn, node=node)
     return out.reshape(*lead, d_out)
 
 
@@ -64,15 +66,16 @@ def _fcu_impl(
     rate: Optional[Fraction],
     tile: Optional[TileChoice],
     record: Optional[Callable[..., None]],
+    node: Optional[str],
 ):
     def impl(x, w):
         if tile is None:
-            return fcu_matmul(x, w, rate=rate)
+            return fcu_matmul(x, w, rate=rate, node=node)
         m = 1
         for s in x.shape[:-1]:
             m *= s
         bm = fit_bm(m, tile.bm)
-        y = fcu_matmul(x, w, bm=bm, bk=tile.bk, bn=tile.bn)
+        y = fcu_matmul(x, w, bm=bm, bk=tile.bk, bn=tile.bn, node=node)
         if record is not None:
             record(
                 bk=tile.bk,
@@ -92,10 +95,11 @@ def pointwise_impl(
     rate: Optional[Fraction] = None,
     tile: Optional[TileChoice] = None,
     record: Optional[Callable[..., None]] = None,
+    node: Optional[str] = None,
 ):
     """Adapter to the CNN executor's 'pointwise' signature (models/cnn.py):
     a 1x1 conv is exactly the FCU matmul over the pixel axis."""
-    return _fcu_impl(rate, tile, record)
+    return _fcu_impl(rate, tile, record, node)
 
 
 def dense_impl(
@@ -103,6 +107,7 @@ def dense_impl(
     rate: Optional[Fraction] = None,
     tile: Optional[TileChoice] = None,
     record: Optional[Callable[..., None]] = None,
+    node: Optional[str] = None,
 ):
     """Adapter to the CNN executor's 'dense' signature (models/cnn.py)."""
-    return _fcu_impl(rate, tile, record)
+    return _fcu_impl(rate, tile, record, node)

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import pallas_call
+from repro.kernels.common import kernel_name, pallas_call
 
 
 def _kpu_kernel(x_ref, w_ref, o_ref, acc_ref, *, taps: tuple, grid_ci: int):
@@ -75,6 +75,7 @@ def kpu_conv_p(
     bci: int,
     bco: int,
     out_dtype=None,
+    node=None,
 ) -> jax.Array:
     n, n_ph, hq, wq, d_in = x_phases.shape
     kh, kw, d_in2, d_out = w.shape
@@ -87,6 +88,7 @@ def kpu_conv_p(
     out_dtype = out_dtype or x_phases.dtype
     return pallas_call(
         functools.partial(_kpu_kernel, taps=taps, grid_ci=grid[2]),
+        name=kernel_name("kpu_conv", node),
         grid=grid,
         in_specs=[
             pl.BlockSpec(

@@ -57,7 +57,8 @@ def _im2col(x: jax.Array, geo: ConvGeometry, kernel) -> jax.Array:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("stride", "rate", "bci", "bco", "bm", "im2col")
+    jax.jit,
+    static_argnames=("stride", "rate", "bci", "bco", "bm", "im2col", "node"),
 )
 def kpu_conv(
     x: jax.Array,            # [N, H, W, d_in]
@@ -69,9 +70,11 @@ def kpu_conv(
     bco: Optional[int] = None,
     bm: Optional[int] = None,
     im2col: Optional[bool] = None,
+    node: Optional[str] = None,
 ) -> jax.Array:
     """``im2col=None`` picks the route by the frame's VMEM working set;
-    on the im2col route ``bm`` is the pixel tile of the FCU matmul."""
+    on the im2col route ``bm`` is the pixel tile of the FCU matmul.
+    ``node`` names the graph node in the kernel's name."""
     n, h, wdt, d_in = x.shape
     kh, kw, _, d_out = w.shape
     geo = conv_geometry((h, wdt), (kh, kw), stride)
@@ -96,6 +99,7 @@ def kpu_conv(
             bm=fit_bm(m, bm or 512),
             bk=k,
             bn=bco,
+            node=node,
         )
         return y.reshape(n, ho, wo, d_out)
     return kpu_conv_p(
@@ -105,6 +109,7 @@ def kpu_conv(
         out_hw=(ho, wo),
         bci=bci,
         bco=bco,
+        node=node,
     )
 
 
@@ -113,6 +118,7 @@ def conv_impl(
     rate: Optional[Fraction] = None,
     tile: Optional[TileChoice] = None,
     record: Optional[Callable[..., None]] = None,
+    node: Optional[str] = None,
 ):
     """Adapter to the CNN executor's 'conv' signature (models/cnn.py):
     ``impl(x, w_hwio, stride) -> y`` with the KPU kernel underneath.
@@ -122,10 +128,11 @@ def conv_impl(
     search.  ``record(bk=..., bn=..., d_in=..., d_out=...)`` is called
     with the executed tile at trace time — plus ``bm`` and ``m`` on the
     im2col route, whose pixel tile the plan pins like the FCU kinds'.
+    ``node`` names the graph node in the kernel's name.
     """
     def impl(x, w, stride):
         if tile is None:
-            y = kpu_conv(x, w, stride=stride, rate=rate)
+            y = kpu_conv(x, w, stride=stride, rate=rate, node=node)
             if record is not None:
                 record(bk=None, bn=None, d_in=x.shape[-1], d_out=w.shape[-1])
             return y
@@ -143,6 +150,7 @@ def conv_impl(
             bco=tile.bn,
             bm=extra.get("bm"),
             im2col=tile.im2col,
+            node=node,
         )
         if record is not None:
             record(

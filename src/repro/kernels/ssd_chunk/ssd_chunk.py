@@ -103,6 +103,7 @@ def ssd_chunk_p(
 
     y, s = pallas_call(
         functools.partial(_ssd_kernel, n_chunks=nc, q=chunk),
+        name="ssd_chunk",
         grid=grid,
         in_specs=[
             pl.BlockSpec(

@@ -149,7 +149,8 @@ def kernel_impls(
     own (j, h)-derived tiling.  When ``executed`` is given, each node
     impl records the tile it actually ran into ``executed[name]`` at
     trace time (``apply_graph(plan=...)`` uses this for its per-node
-    plan-vs-executed assertion).
+    plan-vs-executed assertion).  Each node impl names its kernel
+    ``<kind>.<node>`` (``kernels.common.kernel_name``).
     """
     from repro.kernels.dw_conv.ops import dw_conv_impl
     from repro.kernels.fcu_matmul.ops import dense_impl, pointwise_impl
@@ -176,7 +177,9 @@ def kernel_impls(
         record = None
         if executed is not None:
             record = _tile_recorder(executed, name)
-        table[name] = factories[node_plan.kind](tile=node_plan.tile, record=record)
+        table[name] = factories[node_plan.kind](
+            tile=node_plan.tile, record=record, node=name
+        )
     return table
 
 
@@ -584,7 +587,10 @@ def _run_nodes(
         p = params.get(name)
         if _is_arith(spec) and p is None:
             raise GraphExecutionError(f"{name}: missing parameters")
-        y = _node_forward(spec, operands, p, table)
+        # the node's name scopes its ops in the compiled program's
+        # metadata, so a device trace can tell whose ops they are
+        with jax.named_scope(name):
+            y = _node_forward(spec, operands, p, table)
         if check:
             _check_node(spec, p, y)
         if plan is not None:

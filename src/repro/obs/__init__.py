@@ -3,8 +3,8 @@
 Three layers, all opt-in and zero-overhead when off:
 
 * ``obs.trace`` — per-frame lifecycle spans on the exact rational clock
-  (plus host wall-clock spans), Chrome trace-event JSON export, and a
-  plain-Python query API;
+  (plus host wall-clock spans on the profiler's clock, ``host_now``),
+  Chrome trace-event JSON export, and a plain-Python query API;
 * ``obs.metrics`` — counters / gauges / histograms snapshotable at any
   tick and folded into ``ServeSummary``;
 * ``obs.audit`` — the continuous drift auditor: replays a trace
@@ -36,6 +36,7 @@ from repro.obs.trace import (
     TraceError,
     TraceEvent,
     Tracer,
+    host_now,
     iter_spans,
     resolve_tracer,
 )
@@ -57,6 +58,7 @@ __all__ = [
     "WindowVerdict",
     "audit",
     "audit_fleet",
+    "host_now",
     "iter_spans",
     "metric_key",
     "resolve_tracer",

@@ -18,10 +18,18 @@ Two clock domains share one trace:
   the plan's input rate).  Every serving/fleet event lives here, so the
   trace is bit-reproducible and the drift auditor can do exact
   arithmetic against Eq. 9/10.
-* ``clock="host"`` — ``time.perf_counter`` seconds, for the wall-clock
-  spans around real JAX dispatch/transfer/``block_until_ready``
-  (``DevicePipeline``, fleet measured-fps columns).  Tick-model and
-  measured timelines land in one file, directly comparable.
+* ``clock="host"`` — integer nanoseconds of ``host_now()``
+  (``time.time_ns``), the wall clock the JAX profiler's timeline is
+  aligned to, for the spans around real host work: the serving engine's
+  ingest/dispatch/fetch (``serving/cnn_stream.py``),
+  ``DevicePipeline``'s dispatch/transfer/``block_until_ready``, and the
+  fleet's measured-fps envelope.  Tick-model and measured timelines
+  land in one file.
+
+``Tracer(clocks=("host",))`` records the host clock alone: emitters
+skip every tick-domain event (and the engine its ``MetricsRegistry``),
+so a profiled window pays for a few host spans per call and nothing per
+frame.  ``Tracer()`` records both.
 
 Events follow the Chrome trace-event phases: ``B``/``E`` span begin/end,
 ``i`` instant, ``C`` counter.  ``to_chrome()`` exports the
@@ -40,8 +48,9 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from fractions import Fraction
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple, Union
 
 
 class TraceError(ValueError):
@@ -50,10 +59,20 @@ class TraceError(ValueError):
 
 # Chrome trace-event phases this tracer emits/understands.
 _PHASES = ("B", "E", "i", "C")
+CLOCKS = ("ticks", "host")
 
 # tick-domain events export at 1 tick = 1 us; host-domain events are
-# perf_counter seconds and export at 1 s = 1e6 us.
-_HOST_US = 1_000_000.0
+# nanoseconds and export at 1 ns = 0.001 us.
+_HOST_US = 1e-3
+
+# a timestamp: exact Fraction ticks, or integer host nanoseconds
+Time = Union[Fraction, int]
+
+
+def host_now() -> int:
+    """The host clock of every ``clock="host"`` event: ``time.time_ns()``,
+    the wall clock the JAX profiler's timeline is aligned to."""
+    return time.time_ns()
 
 
 def _fraction_str(f: Fraction) -> str:
@@ -90,15 +109,16 @@ def _dec_args(args: Dict) -> Dict:
     return out
 
 
-@dataclasses.dataclass(frozen=True)
-class TraceEvent:
+class TraceEvent(NamedTuple):
     """One trace event.  ``t`` is exact: Fraction ticks in the tick
-    domain, Fraction-of-seconds (from ``perf_counter``) in the host
-    domain.  ``value`` is set for counter (``C``) events only."""
+    domain, integer nanoseconds (``host_now``) in the host domain.
+    ``value`` is set for counter (``C``) events only.  A named tuple:
+    immutable, and cheap enough to record a few per micro-batch inside a
+    profiled window."""
 
     name: str
     ph: str  # "B" | "E" | "i" | "C"
-    t: Fraction
+    t: Time
     pid: str
     tid: str
     clock: str = "ticks"  # "ticks" | "host"
@@ -114,18 +134,19 @@ class TraceEvent:
 
 @dataclasses.dataclass(frozen=True)
 class Span:
-    """A paired B/E interval; ``args`` merges both ends (E wins)."""
+    """A paired B/E interval; ``args`` merges both ends (E wins).
+    ``duration`` is in ticks, or in nanoseconds on the host clock."""
 
     name: str
     pid: str
     tid: str
-    start: Fraction
-    end: Fraction
+    start: Time
+    end: Time
     clock: str = "ticks"
     args: Tuple[Tuple[str, object], ...] = ()
 
     @property
-    def duration(self) -> Fraction:
+    def duration(self) -> Time:
         return self.end - self.start
 
     def arg(self, key: str, default=None):
@@ -147,9 +168,19 @@ class Tracer:
     attaches one JSON-able blob per pid — the serving engine stores its
     plan's analytic model there so ``obs.audit`` can replay the trace
     *alone*, with no live plan object in hand.
+
+    ``clocks`` names the clock domains the tracer records; an event on
+    any other clock is an error, so emitters check ``"ticks" in
+    tracer.clocks`` before building tick-domain events.
     """
 
-    def __init__(self) -> None:
+    def __init__(self, clocks: Tuple[str, ...] = CLOCKS) -> None:
+        unknown = set(clocks) - set(CLOCKS)
+        if unknown or not clocks:
+            raise TraceError(
+                f"clocks={clocks!r} — expected a non-empty subset of {CLOCKS}"
+            )
+        self.clocks = tuple(clocks)
         self.events: List[TraceEvent] = []
         self.meta: Dict[str, dict] = {}
 
@@ -169,16 +200,21 @@ class Tracer:
     ) -> None:
         if ph not in _PHASES:
             raise TraceError(f"unknown phase {ph!r} (expected {_PHASES})")
+        if clock not in self.clocks:
+            raise TraceError(
+                f"{name}: clock {clock!r} not recorded by this tracer "
+                f"(clocks={self.clocks})"
+            )
         self.events.append(
             TraceEvent(
-                name=name,
-                ph=ph,
-                t=Fraction(t),
-                pid=str(pid),
-                tid=str(tid),
-                clock=clock,
-                value=value,
-                args=_as_args(args),
+                name,
+                ph,
+                int(t) if clock == "host" else Fraction(t),
+                str(pid),
+                str(tid),
+                clock,
+                value,
+                _as_args(args),
             )
         )
 
@@ -191,8 +227,8 @@ class Tracer:
     def span(self, name: str, start, end, **kw) -> None:
         """Emit a balanced B/E pair in one call (the common case for the
         deterministic tick model, where the end is known at the start)."""
-        self.begin(name, start, **kw)
-        self.end(name, end, **kw)
+        self.emit(name, "B", start, **kw)
+        self.emit(name, "E", end, **kw)
 
     def instant(self, name: str, t, **kw) -> None:
         self.emit(name, "i", t, **kw)
@@ -316,9 +352,9 @@ class Tracer:
         viewable): one numeric ``pid`` per emitter with a
         ``process_name`` metadata record, one numeric ``tid`` per
         (pid, stage) track with a ``thread_name`` record.  Tick-domain
-        timestamps export at 1 tick = 1 us, host-domain at real us; the
-        exact Fraction timestamp and the clock ride along in ``args``
-        so ``from_chrome`` reconstructs events losslessly."""
+        timestamps export at 1 tick = 1 us, host-domain nanoseconds at
+        real us; the exact timestamp and the clock ride along in
+        ``args`` so ``from_chrome`` reconstructs events losslessly."""
         pids, tids = self._ids()
         events = []
         for label, npid in sorted(pids.items(), key=lambda kv: kv[1]):
@@ -384,7 +420,7 @@ class Tracer:
             data = json.loads(data)
         if isinstance(data, list):
             data = {"traceEvents": data, "otherData": {}}
-        tr = cls()
+        tr = cls()  # both clocks: an import keeps whatever was recorded
         tr.meta = dict(
             data.get("otherData", {}).get("repro_meta", {})
         )
@@ -406,9 +442,12 @@ class Tracer:
             t_str = args.pop("__t__", None)
             if t_str is not None:
                 t = _parse_fraction(t_str)
+            elif clock == "host":
+                t = round(row["ts"] / _HOST_US)
             else:
-                scale = _HOST_US if clock == "host" else 1.0
-                t = Fraction(row["ts"]) / Fraction(scale)
+                t = Fraction(row["ts"])
+            if clock == "host":
+                t = int(t)
             value = args.pop("value", None) if ph == "C" else None
             tr.events.append(
                 TraceEvent(

@@ -89,7 +89,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 import warnings
 from collections import deque
 from fractions import Fraction
@@ -102,7 +101,7 @@ from repro.core.replicate import lane_multiplicity, replicate_params
 from repro.core.stage_partition import LINK_DTYPE_BITS
 from repro.models import cnn
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import resolve_tracer
+from repro.obs.trace import host_now, resolve_tracer
 from repro.serving.config import ServeConfig
 from repro.serving.overload import ShedPolicy, SwitchPolicy
 from repro.serving.scenarios import ArrivalProcess
@@ -720,13 +719,20 @@ class CNNStreamEngine:
         self._requests: List[FrameRequest] = []
         # observability (docs/observability.md): None when off — every
         # emission below is guarded on it, so an untraced run touches
-        # no obs code at all (event-identical to pre-obs engines)
+        # no obs code at all (event-identical to pre-obs engines).
+        # ``_ticks`` is the tracer when it records the tick domain too;
+        # a host-only tracer gets the host spans and no metrics.
         self._tracer = resolve_tracer(config.trace)
+        self._ticks = (
+            self._tracer
+            if self._tracer is not None and "ticks" in self._tracer.clocks
+            else None
+        )
         self._trace_pid = config.trace_pid
         self._trace_chips = dict(config.trace_chips or {})
         self.metrics: Optional[MetricsRegistry] = None
         if (
-            self._tracer is not None
+            self._ticks is not None
             and config.execute
             and config.pipeline_cache is None
         ):
@@ -836,18 +842,37 @@ class CNNStreamEngine:
 
     # -- execution helpers -------------------------------------------------
 
+    def _host_span(self, name: str, t0: int, t1: int, batch: _Batch) -> None:
+        """One host-clock span of this batch on the engine's host track."""
+        self._tracer.span(
+            name,
+            t0,
+            t1,
+            pid=self._trace_pid,
+            tid="host",
+            clock="host",
+            bid=batch.bid,
+            frames=len(batch.frames),
+        )
+
     def _start_batch_exec(self, s: int, batch: _Batch) -> None:
         if not self.execute:
             return
-        t0 = time.perf_counter() if self._tracer is not None else None
+        tr = self._tracer
         rung = self._rungs[batch.rung]
+        t0 = host_now() if tr is not None else None
         if s == 0:
+            # ingest: the frames onto the device as one padded batch
             xs = [f.x for f in batch.frames]
             pad = self.microbatch - len(xs)
             if pad:
                 xs = xs + [np.zeros_like(xs[0])] * pad
             x = jnp.asarray(np.stack(xs)).astype(self.dtype)
             batch.boundary = {}
+            if tr is not None:
+                t1 = host_now()
+                self._host_span("ingest", t0, t1, batch)
+                t0 = t1
             rung.pipeline.run_stage(0, rung.params, batch.boundary, x)
         else:
             rung.pipeline.run_stage(s, rung.params, batch.boundary)
@@ -855,39 +880,34 @@ class CNNStreamEngine:
         for k in list(batch.boundary):
             if k not in keep:
                 del batch.boundary[k]
-        if t0 is not None:
-            # host wall-clock around the (async) stage dispatch — the
-            # measured twin of the tick-domain stage span
-            self._tracer.span(
-                "exec",
-                Fraction(t0),
-                Fraction(time.perf_counter()),
-                pid=self._trace_pid,
-                tid=f"stage{s}",
-                clock="host",
-                bid=batch.bid,
-                frames=len(batch.frames),
-            )
+        if tr is not None:
+            # the (asynchronous) enqueue of the stage's program
+            self._host_span("dispatch", t0, host_now(), batch)
 
     def _finish_batch(self, batch: _Batch, t: Fraction) -> None:
         out = None
         if self.execute:
             rung = self._rungs[batch.rung]
+            t0 = host_now() if self._tracer is not None else None
+            # fetch: wait for the device, copy the outputs to the host
             out = np.asarray(batch.boundary[rung.pipeline.out_name])
+            if t0 is not None:
+                self._host_span("fetch", t0, host_now(), batch)
         for i, f in enumerate(batch.frames):
             f.t_done = t
             f.rung = batch.rung
             if out is not None:
                 f.out = out[i]
 
-    # -- observability (opt-in; every call guarded on self._tracer) --------
+    # -- observability (opt-in; every call guarded on self._ticks) ---------
     #
     # The tracer only ever APPENDS: nothing here reads back into the
     # event loop, so a traced run is event-identical to an untraced one
     # (tests/obs/test_audit.py pins this).  All tick-domain
     # timestamps are emitted in ticks (cycles / slot) on the exact
     # rational clock; pid is the engine label (tenant name in a fleet),
-    # tid is "stage{s}".
+    # tid is "stage{s}".  The host-clock spans above (ingest, dispatch,
+    # fetch) share the pid on tid "host".
 
     def _begin_trace(self, offered: Fraction, n: int) -> None:
         """Fresh run: new metrics registry, plan metadata (the analytic
@@ -1082,7 +1102,7 @@ class CNNStreamEngine:
             pending=deque(),
             forming=[],
         )
-        if self._tracer is not None:
+        if self._ticks is not None:
             self._begin_trace(offered, n)
         return self._rt
 
@@ -1183,7 +1203,7 @@ class CNNStreamEngine:
             )
         )
         rt.switches.append((now, self._active, to))
-        if self._tracer is not None:
+        if self._ticks is not None:
             self._tracer.instant(
                 "switch",
                 now / self.slot,
@@ -1210,7 +1230,7 @@ class CNNStreamEngine:
     def _settle(self, now: Fraction) -> None:
         rt = self._rt
         reqs = self._requests
-        tr = self._tracer
+        tr = self._ticks
 
         def enqueue(s: int, batch: _Batch) -> None:
             rt.queues[s].append(batch)
@@ -1540,10 +1560,25 @@ def serve_frames(
     stages, kwargs) so repeated
     calls — e.g. through ``CNNApi.serve`` — skip re-planning; pair with
     ``config.pipeline_cache`` to also skip re-jitting the stages.
+
+    With ``config.trace`` on, the call records host-clock spans under
+    ``config.trace_pid`` (tid ``host``): ``serve_frames`` around the
+    whole call, ``plan`` around a plan-cache miss, ``build`` around the
+    engine's construction (arg ``hit``: every pipeline came from
+    ``config.pipeline_cache``), and the engine's per-batch ``ingest`` /
+    ``dispatch`` / ``fetch``; counters ``plan_builds`` and
+    ``pipeline_builds`` count the misses.
     """
     from repro.core.graph import plan_graph
 
     cfg = config if config is not None else ServeConfig()
+    tr = resolve_tracer(cfg.trace)
+    if tr is not None:
+        # one tracer for the call and its engine (trace=True makes one)
+        cfg = cfg.with_(trace=tr)
+        pid = cfg.trace_pid
+        tr.begin("serve_frames", host_now(), pid=pid, tid="host",
+                 clock="host")
     overrides = {
         "microbatch": microbatch,
         "dtype": dtype,
@@ -1571,15 +1606,40 @@ def serve_frames(
             plan_key, plan = cnn._pipeline_cache_get(
                 plan_cache, plan_refs, knobs)
     if plan is None:
+        if tr is not None:
+            tr.begin("plan", host_now(), pid=pid, tid="host", clock="host")
         plan = plan_graph(graph, input_rate, n_stages=n_stages, **dse_kwargs)
         if plan_key is not None:
             plan_cache[plan_key] = (plan_refs, plan)
+        if tr is not None:
+            t = host_now()
+            tr.end("plan", t, pid=pid, tid="host", clock="host")
+            tr.counter("plan_builds", 1, t, pid=pid, tid="host", clock="host")
+    if tr is not None:
+        tr.begin("build", host_now(), pid=pid, tid="host", clock="host")
+        cached = cfg.pipeline_cache
+        n_cached = len(cached) if cached is not None else 0
     if plan.replications:
         graph = plan.graph
         params = replicate_params(params, plan.replications)
     if rate_matched:
         cfg = cfg.with_(kernel_plan=plan.kernel_plan(batch=cfg.microbatch))
     engine = CNNStreamEngine(graph, params, plan, cfg)
+    if tr is not None:
+        # pipelines built by this call: new cache entries, or every
+        # rung's when nothing is cached
+        if not cfg.execute:
+            builds = 0
+        elif cached is not None:
+            builds = len(cached) - n_cached
+        else:
+            builds = len(engine._rungs)
+        t = host_now()
+        tr.end("build", t, pid=pid, tid="host", clock="host",
+               hit=builds == 0)
+        if builds:
+            tr.counter("pipeline_builds", builds, t, pid=pid, tid="host",
+                       clock="host")
     if cfg.execute:
         engine.submit_all(frames)
     else:
@@ -1587,4 +1647,7 @@ def serve_frames(
             engine.submit(None)
     report = engine.run()
     outputs = engine.outputs() if cfg.execute else None
+    if tr is not None:
+        tr.end("serve_frames", host_now(), pid=pid, tid="host", clock="host",
+               frames=report.frames)
     return outputs, report
