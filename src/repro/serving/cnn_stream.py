@@ -82,7 +82,12 @@ kwargs of ``__init__``/``run`` keep working as a deprecated shim.
 Timing is a deterministic tick model (exact ``fractions.Fraction``
 cycle arithmetic), never wall-clock; the JAX execution underneath
 produces the real outputs (bit-exact vs ``models.cnn.apply_graph``)
-but does not influence the clock.
+but does not influence the clock.  The host collects a micro-batch's
+outputs one batch behind the dispatch: it waits on batch k only once
+batch k+1 is dispatched behind it, just before batch k+2's last stage
+is, so at most two micro-batches are dispatched and not yet collected
+and the device does not wait for the host's copy of the logits;
+``finish`` collects the rest.
 """
 
 from __future__ import annotations
@@ -343,6 +348,9 @@ class _RunState:
     )  # (t_cycles, from_rung, to_rung)
     history: List[_Segment] = dataclasses.field(default_factory=list)
     seg_start: Fraction = Fraction(0)
+    # batches the tick model completed whose outputs are not collected
+    # yet, oldest first: (batch, completion time)
+    unfetched: deque = dataclasses.field(default_factory=deque)
 
 
 # ==========================================================================
@@ -860,6 +868,12 @@ class CNNStreamEngine:
             return
         tr = self._tracer
         rung = self._rungs[batch.rung]
+        last = s == rung.n_stages - 1
+        if last:
+            # collect one micro-batch behind the dispatch: the host waits
+            # on batch k while k+1 is queued on the device, so at most two
+            # are dispatched and not yet collected
+            self._collect(1)
         t0 = host_now() if tr is not None else None
         if s == 0:
             # ingest: the frames onto the device as one padded batch
@@ -880,17 +894,40 @@ class CNNStreamEngine:
         for k in list(batch.boundary):
             if k not in keep:
                 del batch.boundary[k]
+        if last:
+            # the copy to the host starts the moment the program ends
+            batch.boundary[rung.pipeline.out_name].copy_to_host_async()
         if tr is not None:
             # the (asynchronous) enqueue of the stage's program
-            self._host_span("dispatch", t0, host_now(), batch)
+            t1 = host_now()
+            self._host_span("dispatch", t0, t1, batch)
+            if last:
+                tr.counter(
+                    "batches_in_flight",
+                    len(self._rt.unfetched) + 1,
+                    t1,
+                    pid=self._trace_pid,
+                    tid="host",
+                    clock="host",
+                )
+
+    def _collect(self, keep: int) -> None:
+        """Finish the oldest completed micro-batches until at most
+        ``keep`` wait for collection."""
+        unfetched = self._rt.unfetched
+        while len(unfetched) > keep:
+            self._finish_batch(*unfetched.popleft())
 
     def _finish_batch(self, batch: _Batch, t: Fraction) -> None:
+        """The batch leaves the engine: its outputs on the host, and
+        ``t_done`` the tick at which the tick model completed it."""
         out = None
         if self.execute:
             rung = self._rungs[batch.rung]
             t0 = host_now() if self._tracer is not None else None
             # fetch: wait for the device, copy the outputs to the host
             out = np.asarray(batch.boundary[rung.pipeline.out_name])
+            batch.boundary = None
             if t0 is not None:
                 self._host_span("fetch", t0, host_now(), batch)
         for i, f in enumerate(batch.frames):
@@ -1142,6 +1179,7 @@ class CNNStreamEngine:
             raise ServingError(
                 f"run not drained: {rt.completed}/{rt.n} frames served"
             )
+        self._collect(0)
         return self._report(rt)
 
     # -- overload-policy hooks ---------------------------------------------
@@ -1256,7 +1294,9 @@ class CNNStreamEngine:
                 if st.batch is None or st.busy_until > now:
                     continue
                 if s == n_stages - 1:
-                    self._finish_batch(st.batch, now)
+                    # collected (outputs on the host) just before batch
+                    # k+2's last stage is dispatched, or in finish()
+                    rt.unfetched.append((st.batch, now))
                     rt.completed += len(st.batch.frames)
                     if tr is not None:
                         self._trace_done(st.batch, now, len(rt.history))
@@ -1567,7 +1607,9 @@ def serve_frames(
     engine's construction (arg ``hit``: every pipeline came from
     ``config.pipeline_cache``), and the engine's per-batch ``ingest`` /
     ``dispatch`` / ``fetch``; counters ``plan_builds`` and
-    ``pipeline_builds`` count the misses.
+    ``pipeline_builds`` count the misses, and ``batches_in_flight``
+    (at each last-stage dispatch) the micro-batches dispatched and not
+    yet collected.
     """
     from repro.core.graph import plan_graph
 

@@ -5,8 +5,9 @@ JAX profiler stamps (``obs.host_now``, integer ns): ``serve_frames``
 around the call, ``plan`` / ``build`` around a plan-cache miss and the
 engine's construction, and one ``ingest``, ``dispatch`` and ``fetch``
 per micro-batch; counters ``plan_builds`` / ``pipeline_builds`` count the
-cache misses.  A host-only tracer records those and nothing of the tick
-domain.
+cache misses, and ``batches_in_flight`` the micro-batches dispatched and
+not yet collected at each dispatch.  A host-only tracer records those
+and nothing of the tick domain.
 """
 import time
 from fractions import Fraction as F
@@ -79,6 +80,16 @@ def test_engine_spans_nest_in_serve_frames(served):
             assert bids == [0, 1, 2], name
         assert sorted(s.arg("frames") for s in inside if s.name == "fetch") == [2, 4, 4]
         assert sum(s.duration for s in inside) <= call.duration
+        # outputs collected one batch behind: batch 1 is dispatched before
+        # batch 0 is fetched, and the last two are fetched after the last
+        # dispatch, so the counter reads 1, then 2
+        order = [(s.name, s.arg("bid")) for s in sorted(inside, key=lambda s: s.start)
+                 if s.name in ("dispatch", "fetch")]
+        assert order == [("dispatch", 0), ("dispatch", 1), ("fetch", 0),
+                         ("dispatch", 2), ("fetch", 1), ("fetch", 2)]
+        series = tr.counter_series("batches_in_flight", pid="engine", tid="host")
+        flight = [v for t, v in series if call.start <= t <= call.end]
+        assert flight == [1, 2, 2]
     for s in tr.spans(pid="engine"):
         if s.name in CHILDREN:
             assert any(c.start <= s.start <= s.end <= c.end for c in calls), s
@@ -108,6 +119,7 @@ def test_full_tracer_keeps_tick_domain_and_adds_host_spans(served):
     assert rep.metrics is not None
     assert len(tr.spans("stage", clock="ticks")) == 2
     assert len(tr.spans("fetch", clock="host")) == 2
+    assert [v for _, v in tr.counter_series("batches_in_flight")] == [1, 2]
     assert not tr.spans("exec")
 
 
