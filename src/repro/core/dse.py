@@ -34,11 +34,13 @@ from typing import List, Tuple
 
 from .rate import LayerSpec, divisors
 
-# Layers with no multipliers: comparators (pool), elementwise adders (add),
-# wiring only (concat, and the Multi-CLP split/merge lane steering of
-# core.replicate), running means (gap).  The DSE tracks their phases and
-# pass cadence but explores no (j, h) space.
-NON_ARITH_KINDS = ("pool", "add", "gap", "concat", "split", "merge")
+# Layers with no weights and no reduction: comparators (pool), elementwise
+# adders (add), elementwise gate multipliers (scale: one multiply per
+# feature against its frame's gate, so no j to aggregate and no h to
+# multiplex), wiring only (concat, and the Multi-CLP split/merge lane
+# steering of core.replicate), running means (gap).  The DSE tracks their
+# phases and pass cadence but explores no (j, h) space.
+NON_ARITH_KINDS = ("pool", "add", "gap", "concat", "scale", "split", "merge")
 
 
 @dataclasses.dataclass(frozen=True)

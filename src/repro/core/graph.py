@@ -36,6 +36,16 @@ Timing model (exact fractions, validated by ``schedule.simulate_graph``):
   (P = the join's pixel phases; P extra slots cover multi-pixel intake).
   ``simulate_graph`` asserts the measured occupancy never exceeds this.
 
+  A 'scale' join (squeeze-and-excitation) multiplies a trunk stream of
+  H*W pixels a frame by a gate of one pixel a frame, computed from the
+  whole frame (gap -> dense -> dense).  Pixel m of frame f needs gate f,
+  which leaves its producer at offset_g + (f+1)*H*W/q, so in trunk-pixel
+  terms the gate path's offset is offset_g + (H*W - 1)/q.  The trunk
+  edge's FIFO therefore parks one whole frame plus the gate path's
+  latency, floor(skew * q) + P pixels as above; the gate edge holds at
+  most floor(skew * q / (H*W)) + 2 gates (the one in use and the next
+  frame's).
+
 Plan-threading contract (who produces what, who consumes it):
 
   ``plan_graph`` is the single producer of per-node kernel plans: its
@@ -47,10 +57,11 @@ Plan-threading contract (who produces what, who consumes it):
   each arithmetic node's kernel with its own tile instead of one global
   rate, and asserts at trace time that the tile the kernel *executed*
   equals the tile planned here.  Invariants: plan keys == graph node
-  names; every non-wiring node (kind outside ``core.dse.
-  NON_ARITH_KINDS``) carries a tile whose dimensions divide the node's
-  (d_in, d_out); for feasible impls the tile preserves Eq. 9
-  (capacity >= demand) under the MXU-alignment growth.
+  names; every node with a kernel (kind in ``core.tpu_tiles.
+  KERNEL_KINDS``: the arithmetic kinds, and the 'scale' join) carries a
+  tile whose dimensions divide the node's (d_in, d_out); for feasible
+  impls the tile preserves Eq. 9 (capacity >= demand) under the
+  MXU-alignment growth.
 """
 from __future__ import annotations
 
@@ -60,7 +71,7 @@ from collections import OrderedDict
 from fractions import Fraction
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from .dse import NON_ARITH_KINDS, LayerImpl, select_impl
+from .dse import LayerImpl, select_impl
 from .hw_specs import TPUSpec, target_spec
 from .rate import LayerSpec, RatePoint
 from .stage_partition import (
@@ -75,9 +86,12 @@ from .stage_partition import (
     stage_stream_bits,
     stream_buffers,
 )
-from .tpu_tiles import VMEM_FRACTION, TileChoice, select_tile_for_impl
+from .tpu_tiles import KERNEL_KINDS, VMEM_FRACTION, TileChoice, select_tile_for_impl
 
-JOIN_KINDS = ("add", "concat")
+JOIN_KINDS = ("add", "concat", "scale")
+# Gates a 'scale' join holds beyond its skew: the current frame's, and the
+# next frame's, which may arrive before the current frame's last pixel.
+SCALE_GATE_SLOTS = 2
 
 
 class GraphError(ValueError):
@@ -96,7 +110,7 @@ class LayerGraph:
     every producer to exist already), so ``topo_order()`` is simply the
     insertion order.  Branch points are nodes with more than one consumer
     (the stream is forked — each consumer sees the full rate); join nodes
-    are 'add'/'concat' specs with more than one producer.
+    are 'add'/'concat'/'scale' specs with more than one producer.
     """
 
     def __init__(self) -> None:
@@ -123,7 +137,9 @@ class LayerGraph:
         return name
 
     def _check_shapes(self, spec: LayerSpec, preds: List[str]) -> None:
-        if spec.kind in JOIN_KINDS:
+        if spec.kind == "scale":
+            self._check_scale(spec, preds)
+        elif spec.kind in JOIN_KINDS:
             if len(preds) < 2:
                 raise GraphError(
                     f"{spec.name}: join kind {spec.kind!r} "
@@ -200,6 +216,38 @@ class LayerGraph:
                         f"producer {pred.name} emits {pred.out_hw}"
                     )
 
+    def _check_scale(self, spec: LayerSpec, preds: List[str]) -> None:
+        """A 'scale' join takes [trunk, gate]: the trunk at the join's
+        H x W, the gate one 1x1 pixel a frame, both with the join's C
+        channels."""
+        if len(preds) != 2:
+            raise GraphError(
+                f"{spec.name}: scale needs [trunk, gate] producers, "
+                f"got {len(preds)}"
+            )
+        if spec.d_out != spec.d_in or spec.out_hw != spec.in_hw:
+            raise GraphError(
+                f"{spec.name}: scale keeps its trunk's shape — needs "
+                f"d_out == d_in and out_hw == in_hw"
+            )
+        trunk, gate = (self._specs[p] for p in preds)
+        if trunk.out_hw != spec.in_hw:
+            raise GraphError(
+                f"{spec.name}: trunk {trunk.name} emits {trunk.out_hw} "
+                f"but scale expects {spec.in_hw}"
+            )
+        if gate.out_hw != (1, 1):
+            raise GraphError(
+                f"{spec.name}: gate {gate.name} emits {gate.out_hw}, "
+                f"not one 1x1 pixel a frame"
+            )
+        for p in (trunk, gate):
+            if p.d_out != spec.d_in:
+                raise GraphError(
+                    f"{spec.name}: scale needs equal operand channels "
+                    f"(=d_in), got {p.name} d_out={p.d_out}, d_in={spec.d_in}"
+                )
+
     @classmethod
     def from_chain(cls, layers: Sequence[LayerSpec]) -> "LayerGraph":
         g = cls()
@@ -271,7 +319,9 @@ def propagate_graph(
 
     Every source node receives ``input_rate``.  Joins require all operand
     *pixel* rates to agree — a structural property of correct CNN DAGs
-    (both residual paths decimate identically); violations raise.
+    (both residual paths decimate identically); violations raise.  A
+    'scale' join runs at its trunk's rate, and its gate must arrive at
+    that rate over the trunk's H*W pixels: one gate a frame.
 
     Replication wiring (core.replicate) extends the fluid algebra:
 
@@ -292,6 +342,15 @@ def propagate_graph(
         preds = graph.preds(name)
         if not preds:
             q_in = Fraction(input_rate) / spec.d_in
+        elif spec.kind == "scale":
+            q_in = out[preds[0]].pixels_per_clock
+            q_gate = out[preds[1]].pixels_per_clock
+            if q_gate * frame_pixels(spec) != q_in:
+                raise GraphError(
+                    f"{name}: gate {preds[1]} runs at {q_gate} pixels/clock, "
+                    f"not one per frame of trunk {preds[0]} ({q_in} over "
+                    f"{frame_pixels(spec)} pixels)"
+                )
         else:
             qs = {out[p].pixels_per_clock for p in preds}
             if len(qs) > 1:
@@ -353,6 +412,25 @@ def fill_pixels(spec: LayerSpec) -> int:
     return 0
 
 
+def frame_pixels(spec: LayerSpec) -> int:
+    """Input pixels in one frame of ``spec``'s stream."""
+    return spec.in_hw[0] * spec.in_hw[1]
+
+
+def operand_offsets(
+    graph: LayerGraph, name: str, timing: Dict[str, NodeTiming], q_in: Fraction
+) -> List[Fraction]:
+    """Per producer of ``name``, the offset at which its stream lets the
+    node consume pixels at ``q_in``: the producer's own offset, except
+    for a 'scale' join's gate, which arrives once a frame, after the
+    frame's last trunk pixel (module docstring)."""
+    spec = graph.spec(name)
+    offsets = [timing[p].offset for p in graph.preds(name)]
+    if spec.kind == "scale":
+        offsets[1] += Fraction(frame_pixels(spec) - 1) / q_in
+    return offsets
+
+
 def decimation_keep(spec: LayerSpec) -> int:
     """1-in-keep pixel survival through this node (1 for non-decimating)."""
     ratio = 1 / spec.spatial_ratio
@@ -389,8 +467,8 @@ def compute_timing(
             o_in = Fraction(0)
             q_in = Fraction(input_rate) / spec.d_in
         else:
-            o_in = max(timing[p].offset for p in preds)
             q_in = timing[preds[0]].q_out
+            o_in = max(operand_offsets(graph, name, timing, q_in))
         c = pass_cycles(impls[name])
         fill = Fraction(fill_pixels(spec)) / q_in if fill_pixels(spec) else Fraction(0)
         if spec.kind == "split":
@@ -417,7 +495,7 @@ class JoinBuffer:
     join: str
     src: str  # producer whose stream this FIFO parks
     skew_cycles: Fraction  # slowest-branch offset minus this branch's
-    q: Fraction  # pixel rate through the join
+    q: Fraction  # pixel rate on this edge (a gate edge: one pixel a frame)
     d: int  # channels per pixel on this edge
     bound_pixels: int  # max pixels resident (the analytical bound)
     width_bits: int  # FIFO word = one stream beat
@@ -440,21 +518,29 @@ def join_buffers(
     the full frame rate only during lane k's turn, so a lane accumulates
     up to ceil(px * (R-1) / R) pixels while the other R-1 lanes' frames
     are being forwarded (px = pixels per frame on the edge).
+
+    A 'scale' join's gate edge holds gates, not pixels: its skew is
+    counted at the gate's own rate (one a frame), plus
+    ``SCALE_GATE_SLOTS``.
     """
     buffers: List[JoinBuffer] = []
     for join in graph.joins():
         preds = graph.preds(join)
         spec = graph.spec(join)
-        o_max = max(timing[p].offset for p in preds)
-        q = timing[join].q_in
+        offsets = operand_offsets(graph, join, timing, timing[join].q_in)
+        o_max = max(offsets)
         burst = 0
         if spec.kind == "merge":
-            px = spec.in_hw[0] * spec.in_hw[1]
+            px = frame_pixels(spec)
             burst = math.ceil(Fraction(px * (len(preds) - 1), len(preds)))
-        for p in preds:
-            skew = o_max - timing[p].offset
+        for i, p in enumerate(preds):
+            skew = o_max - offsets[i]
             d = graph.spec(p).d_out
-            bound = math.floor(skew * q) + max(1, impls[join].p_raw) + burst
+            q = timing[p].q_out
+            slots = max(1, impls[join].p_raw)
+            if spec.kind == "scale" and i == 1:
+                slots = SCALE_GATE_SLOTS
+            bound = math.floor(skew * q) + slots + burst
             r_edge = q * d  # features/clock on the edge
             lanes = max(1, math.ceil(r_edge))
             width = 8 * lanes
@@ -545,7 +631,7 @@ class ImplPlan:
     p: int  # pixel phases after stride pruning
     demand: Fraction  # decimation-adjusted features/clock
     q_in: Fraction  # pixels/clock entering the node
-    tile: Optional[TileChoice]  # None for non-arithmetic (wiring) kinds
+    tile: Optional[TileChoice]  # None for kinds outside KERNEL_KINDS
     batch: Optional[int] = None  # serving batch the tile's bm was pinned to
 
     @property
@@ -697,7 +783,7 @@ class GraphPlan:
         for name, impl in self.impls.items():
             spec = self.graph.spec(name)
             tile = None
-            if spec.kind not in NON_ARITH_KINDS:
+            if spec.kind in KERNEL_KINDS:
                 tile = select_tile_for_impl(
                     impl,
                     dtype_bytes=dtype_bytes,
@@ -726,8 +812,8 @@ def _plan_edge_traffic(plan: GraphPlan) -> Dict[Tuple[str, str], EdgeTraffic]:
     graph = plan.graph
     out: Dict[Tuple[str, str], EdgeTraffic] = {}
     for dst in graph.topo_order():
-        q = plan.timing[dst].q_in
         for src in graph.preds(dst):
+            q = plan.timing[src].q_out  # a 'scale' gate: one pixel a frame
             try:
                 base = plan.buffer_for(dst, src).bound_pixels
             except KeyError:

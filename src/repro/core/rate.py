@@ -22,11 +22,14 @@ from typing import List, Sequence, Tuple
 
 LayerKind = str
 # 'conv' | 'dwconv' | 'pointwise' | 'dense' | 'pool' | 'add' | 'gap' | 'concat'
-#   | 'split' | 'merge'
-# 'add' and 'concat' are JOIN kinds: in a LayerGraph they may have several
-# producers (residual sums, inception-style concatenations).  For 'add',
-# d_in is the per-operand channel count; for 'concat' it is the sum over
-# operands.  'split' / 'merge' are the Multi-CLP replication wiring of
+#   | 'scale' | 'split' | 'merge'
+# 'add', 'concat' and 'scale' are JOIN kinds: in a LayerGraph they may have
+# several producers (residual sums, inception-style concatenations,
+# squeeze-and-excitation gates).  For 'add', d_in is the per-operand
+# channel count; for 'concat' it is the sum over operands.  'scale'
+# multiplies a trunk stream [H, W, C] per channel by a gate of one 1x1 pixel
+# [C] per frame (its second producer): d_in == d_out == C and the gate's
+# pixel rate is the trunk's divided by H*W.  'split' / 'merge' are the Multi-CLP replication wiring of
 # core.replicate: a 'split' round-robin-deals its frame stream across its
 # >= 2 consumers (each lane carries pixel rate q / R), and a 'merge'
 # re-interleaves R lane streams in order (q_out = q_lane * R).  Both are
@@ -48,7 +51,8 @@ class LayerSpec:
     stride: Tuple[int, int] = (1, 1)
     channel_multiplier: int = 1       # depthwise only
     padding: str = "same"
-    # post-layer nonlinearity ('none' | 'relu' | 'relu6').  Irrelevant to
+    # post-layer nonlinearity ('none' | 'relu' | 'relu6' | 'swish' |
+    # 'sigmoid'; swish is x * sigmoid(x)).  Irrelevant to
     # the rate/DSE algebra (activations are free on the FPGA datapath) but
     # carried on the spec so the executable JAX network (models/cnn.py) is
     # generated from the *same* description as the DSE graph — topology

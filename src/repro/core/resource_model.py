@@ -156,6 +156,12 @@ def estimate_layer(impl: LayerImpl, spec: FPGASpec = XCVU37P) -> ResourceEstimat
         elif lay.kind == "add":
             # elementwise residual sum: one 8b adder per arriving feature lane
             est.lut = 8.0 * max(1, math.ceil(impl.demand))
+        elif lay.kind == "scale":
+            # squeeze-and-excitation gate: one 8b soft-logic multiplier per
+            # arriving feature lane (the frame's gate held in a register);
+            # the whole-frame trunk FIFO is a JoinBuffer, priced with the
+            # other join FIFOs
+            est.lut = _DW_MULT_LUT * max(1, math.ceil(impl.demand))
         elif lay.kind in ("split", "merge"):
             # Multi-CLP deal/interleave steering (core.replicate): an 8b
             # mux/demux per feature lane at the full-stream rate, plus one

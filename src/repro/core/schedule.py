@@ -13,7 +13,9 @@ pixel/pass granularity and measures exactly that:
   phase finished its previous pass;
 * at a DAG join, pixel n has "arrived" only when EVERY operand branch has
   delivered it — the fast branch's pixels wait in a skew FIFO whose
-  occupancy is measured against the analytical bound from core.graph.
+  occupancy is measured against the analytical bound from core.graph;
+  at a 'scale' join (squeeze-and-excitation), trunk pixel n of a frame of
+  H*W pixels waits for gate n // (H*W), its frame's.
 
 `simulate_chain` returns per-layer busy fractions and buffer bounds;
 `simulate_graph` additionally returns per-join-edge occupancy maxima.
@@ -240,6 +242,19 @@ def simulate_graph(
         elif len(preds) == 1:
             arrivals = outputs[preds[0]]
             edge_arrivals = []
+        elif spec.kind == "scale":
+            # Trunk pixel i needs its frame's gate, i // px, and the join
+            # reads its trunk FIFO at the stream's rate q from the gate's
+            # arrival on (pixel m of a frame no earlier than gate + m/q),
+            # so the stream it emits keeps the rate every later node was
+            # planned for.  Only whole frames with a gate are consumed.
+            trunk, gates = outputs[preds[0]], outputs[preds[1]]
+            px = spec.in_hw[0] * spec.in_hw[1]
+            q = plan.timing[name].q_in
+            n_avail = min(len(trunk), len(gates) * px)
+            arrivals = [max(trunk[i], gates[i // px] + (i % px) / q)
+                        for i in range(n_avail)]
+            edge_arrivals = [(preds[0], trunk[:n_avail])]
         elif spec.kind == "merge":
             # Order-preserving re-interleave: output pixel m is lane
             # (m mod R)'s pixel m // R; truncate to complete rounds.
@@ -272,6 +287,22 @@ def simulate_graph(
                     src=src,
                     max_pixels=peak,
                     bound_pixels=plan.buffer_for(name, src).bound_pixels,
+                )
+            )
+        if spec.kind == "scale":
+            # Gate f is held from its arrival until frame f's last pixel
+            # starts: at pixel i's start, the gates of frames before
+            # i // px are spent.
+            gates = outputs[preds[1]]
+            peak = 0
+            for i, s in enumerate(started):
+                peak = max(peak, bisect.bisect_right(gates, s) - i // px)
+            occupancy.append(
+                JoinOccupancy(
+                    join=name,
+                    src=preds[1],
+                    max_pixels=peak,
+                    bound_pixels=plan.buffer_for(name, preds[1]).bound_pixels,
                 )
             )
         if spec.kind == "merge":

@@ -732,7 +732,7 @@ def stream_buffers(
                     f"edge {src}->{dst} flows backwards across stages "
                     f"({stage_of[src]} -> {stage_of[dst]})"
                 )
-            q = plan.timing[dst].q_in
+            q = plan.timing[src].q_out  # a 'scale' gate: one pixel a frame
             d = graph.spec(src).d_out
             try:
                 # A join skew FIFO or a split->lane deal FIFO on this edge

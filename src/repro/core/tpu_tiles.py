@@ -24,6 +24,9 @@ Extra constraints that exist on TPU but not on the FPGA:
     frame cannot fit the budget (the lane-sparse 3-channel stems) is
     planned as im2col patches through the FCU matmul instead
     (``TileChoice.im2col``).
+  * The 'scale' join (``kernels/se_scale``) is one multiply per feature,
+    bound by memory: it streams blocks of whole rows by a channel tile,
+    the largest that fit ``SCALE_BLOCK_BYTES``.
 
 Two selection paths share those constraints:
 
@@ -81,6 +84,16 @@ class TileChoice:
 # against; the rest is headroom for what the count leaves out (Mosaic's
 # own temporaries and relayouts).
 VMEM_FRACTION = 0.5
+
+# Kinds whose nodes run a Pallas kernel on the rate-matched path: the
+# arithmetic kinds, and the 'scale' join (squeeze-and-excitation).
+KERNEL_KINDS = ("conv", "dwconv", "pointwise", "dense", "scale")
+
+# Largest VMEM block (padded bytes) of the 'scale' join's trunk: a pass
+# bound by memory streams best in blocks of a few MiB, long enough that
+# each copy runs at full bandwidth and small enough that the grid has
+# steps for the copies to overlap.
+SCALE_BLOCK_BYTES = 2 * 1024**2
 
 
 def vmem_budget(spec: TPUSpec, fraction: float = VMEM_FRACTION) -> int:
@@ -199,6 +212,31 @@ def conv_frame_vmem_bytes(
     acc = padded_bytes((ho, wo, bco), 4, spec)
     win = padded_bytes((ho, wo, bci), dtype_bytes, spec)
     return 2 * (x + w + o) + 2 * acc + win
+
+
+def scale_tile(
+    hw: Tuple[int, int],
+    c: int,
+    floor: int,
+    *,
+    dtype_bytes: int,
+    spec: TPUSpec,
+) -> Tuple[int, int, int]:
+    """(rows, channel tile, padded block bytes) of the 'scale' join's
+    blocks ``[1, rows, W, bc]``: the largest block within
+    ``SCALE_BLOCK_BYTES`` over the row counts that divide H and the legal
+    channel tiles >= ``floor`` (the node's j: tiles only grow), ties to
+    the wider channel tile; the smallest block when none fits."""
+    h, w = hw
+    cands = [
+        (padded_bytes((bh, w, bc), dtype_bytes, spec), bc, bh)
+        for bh in divisors(h)
+        for bc in legal_tiles(c, spec.lanes)
+        if bc >= min(floor, c)
+    ]
+    fits = [t for t in cands if t[0] <= SCALE_BLOCK_BYTES]
+    nbytes, bc, bh = max(fits) if fits else min(cands)
+    return bh, bc, nbytes
 
 
 def select_tile(
@@ -338,6 +376,8 @@ def select_tile_for_impl(
       * dwconv — the channel tile ``bk`` = smallest legal divisor of
         ``d_in`` >= j (h = 1 per §II-B: the channel multiplier replaces
         d_out); ``bn`` is reported as 1.
+      * scale — ``scale_tile``: ``bk`` the channel tile (>= j), ``bm``
+        the pixels of one block (rows x W), ``bn`` reported as 1.
 
     When the impl's own (j, h) satisfy Eq. 9 — always true for scheme
     'ours' — the resulting tile provably still satisfies
@@ -363,7 +403,7 @@ def select_tile_for_impl(
     runtime re-fit.
     """
     lay = impl.layer
-    if lay.kind not in ("conv", "dwconv", "pointwise", "dense"):
+    if lay.kind not in KERNEL_KINDS:
         raise ValueError(
             f"{lay.name}: kind {lay.kind!r} has no kernel tiling "
             f"(non-arithmetic nodes carry no tile in an ImplPlan)"
@@ -376,6 +416,22 @@ def select_tile_for_impl(
         m *= batch
     r_phase = impl.demand / impl.p_raw
     budget = vmem_budget(spec, vmem_fraction)
+
+    if lay.kind == "scale":
+        bh, bc, block = scale_tile(
+            lay.in_hw, lay.d_in, impl.j, dtype_bytes=dtype_bytes, spec=spec
+        )
+        gate = padded_bytes((1, bc), dtype_bytes, spec)
+        return TileChoice(
+            bm=bh * lay.in_hw[1],
+            bk=bc,
+            bn=1,
+            grid_m=lay.in_hw[0] // bh,
+            grid_k=lay.d_in // bc,
+            grid_n=1,
+            vmem_bytes=2 * (2 * block + gate),
+            mxu_aligned=_align_ok(bc, lane),
+        )
 
     if lay.kind == "dwconv":
         bc = plan_dim_tile(lay.d_in, min(impl.j, lay.d_in), lane)

@@ -1,6 +1,7 @@
 """Unified CNN inference machinery: execute a ``LayerGraph`` in JAX.
 
-Every CNN family in the repo (MobileNetV1/V2, ResNet-18/34) describes
+Every CNN family in the repo (MobileNetV1/V2, ResNet-18/34,
+EfficientNet-B0) describes
 itself **once**, as the ``LayerSpec`` DAG consumed by the data-rate DSE
 (core.graph).  This module is the other half of that contract: a generic
 interpreter that runs the *same* graph as a JAX network —
@@ -17,7 +18,7 @@ interpreter that runs the *same* graph as a JAX network —
     in flight,
   * ``quantize_params`` / ``apply_int8`` — the paper's 8-bit datapath,
   * ``default_impls`` / ``kernel_impls`` — XLA ops vs the Pallas KPU /
-    FCU / DW kernels, swappable per layer kind, with node-keyed
+    FCU / DW / SE-gate kernels, swappable per layer kind, with node-keyed
     ``overrides`` for user-supplied per-node implementations.
 
 Because topology and inference share one description they cannot drift:
@@ -53,6 +54,7 @@ from repro.core.dse import NON_ARITH_KINDS
 from repro.core.graph import JOIN_KINDS, ImplPlan, LayerGraph
 from repro.core.rate import LayerSpec
 from repro.core.stage_partition import resolve_link_dtype
+from repro.core.tpu_tiles import KERNEL_KINDS
 from repro.nn.quant import dequantize_link, fake_quant_link, quantize_link
 
 Impl = Callable[..., jax.Array]
@@ -62,7 +64,9 @@ Params = Dict[str, Dict[str, jax.Array]]
 # (core.dse.NON_ARITH_KINDS).  Membership checks below go through
 # NON_ARITH_KINDS directly so a kind added on the DSE side cannot be
 # silently treated as parameterless wiring here: it reaches
-# ``_weight_shape``, which raises for layouts it does not know.
+# ``_weight_shape``, which raises for layouts it does not know.  Which
+# nodes run a kernel, and so may take a node-keyed override, is
+# core.tpu_tiles.KERNEL_KINDS: these and the weightless 'scale' join.
 ARITH_KINDS = ("conv", "dwconv", "pointwise", "dense")
 
 
@@ -78,6 +82,8 @@ _ACTIVATIONS: Dict[str, Callable[[jax.Array], jax.Array]] = {
     "none": lambda x: x,
     "relu": jax.nn.relu,
     "relu6": lambda x: jnp.clip(x, 0.0, 6.0),
+    "swish": lambda x: x * jax.nn.sigmoid(x),  # beta = 1 (EfficientNet)
+    "sigmoid": jax.nn.sigmoid,
 }
 
 
@@ -115,6 +121,12 @@ def _dense(x: jax.Array, w: jax.Array) -> jax.Array:
     return x @ w
 
 
+def _scale(x: jax.Array, gate: jax.Array) -> jax.Array:
+    """The squeeze-and-excitation join: trunk [N, H, W, C] times its
+    frame's gate [N, C]."""
+    return x * gate[:, None, None, :]
+
+
 def default_impls() -> Dict[str, Impl]:
     """Pure-XLA implementations (the lax fallback; runs anywhere)."""
     return {
@@ -122,6 +134,7 @@ def default_impls() -> Dict[str, Impl]:
         "dwconv": _dwconv,
         "pointwise": _pointwise,
         "dense": _dense,
+        "scale": _scale,
     }
 
 
@@ -131,14 +144,14 @@ def kernel_impls(
     plan: Optional[Mapping[str, ImplPlan]] = None,
     executed: Optional[Dict[str, Dict[str, int]]] = None,
 ) -> Dict[str, Impl]:
-    """Pallas-kernel-backed implementations (KPU / DW / FCU).
+    """Pallas-kernel-backed implementations (KPU / DW / FCU / SE gate).
 
     Imported lazily so graph-only callers never pay for (or break on)
     the Pallas stack.  Where the kernels run is the backend's choice
     (``kernels.common.pallas_call``): Mosaic-compiled on a TPU, the
     Pallas interpreter elsewhere.
 
-    Without ``plan`` this is the **uniform** path: four kind-level impls
+    Without ``plan`` this is the **uniform** path: five kind-level impls
     whose tiles come from ``select_tile`` under one global ``rate``
     (or the max-intensity tile when ``rate`` is None).
 
@@ -155,12 +168,14 @@ def kernel_impls(
     from repro.kernels.dw_conv.ops import dw_conv_impl
     from repro.kernels.fcu_matmul.ops import dense_impl, pointwise_impl
     from repro.kernels.kpu_conv.ops import conv_impl
+    from repro.kernels.se_scale.ops import se_scale_impl
 
     factories = {
         "conv": conv_impl,
         "dwconv": dw_conv_impl,
         "pointwise": pointwise_impl,
         "dense": dense_impl,
+        "scale": se_scale_impl,
     }
     table: Dict[str, Impl] = {
         kind: make(rate=rate) for kind, make in factories.items()
@@ -169,7 +184,7 @@ def kernel_impls(
         return table
     for name, node_plan in plan.items():
         if not node_plan.has_kernel:
-            continue  # pool / add / gap / concat: wiring, no kernel
+            continue  # pool / add / gap / concat: no kernel
         if name in factories:
             raise GraphExecutionError(
                 f"node name {name!r} collides with an impl kind key"
@@ -289,6 +304,8 @@ def _node_forward(
         y = x
         for other in operands[1:]:
             y = y + other
+    elif spec.kind == "scale":
+        y = fn("scale")(x, operands[1])
     elif spec.kind == "concat":
         y = jnp.concatenate(operands, axis=-1)
     elif spec.kind == "split":
@@ -393,6 +410,11 @@ def _check_planned_tile(
             f"{spec.name}: planned tile (bk={t.bk}, bn={t.bn}) does not "
             f"divide live dims ({d_in}, {d_out})"
         )
+    if spec.kind == "scale" and got.get("bm") != t.bm:
+        raise GraphExecutionError(
+            f"{spec.name}: executed block of {got.get('bm')} pixels != "
+            f"ImplPlan bm={t.bm}"
+        )
     if node_plan.batch is not None and (
         spec.kind in ("pointwise", "dense") or t.im2col
     ):
@@ -443,11 +465,9 @@ def _build_table(
         unknown = [n for n in overrides if n not in graph]
         if unknown:
             raise GraphExecutionError(f"overrides for unknown nodes: {unknown}")
-        bad = [n for n in overrides if not _is_arith(graph.spec(n))]
+        bad = [n for n in overrides if graph.spec(n).kind not in KERNEL_KINDS]
         if bad:
-            raise GraphExecutionError(
-                f"overrides for non-arithmetic (wiring) nodes: {bad}"
-            )
+            raise GraphExecutionError(f"overrides for nodes with no kernel: {bad}")
         table.update(overrides)
     return table
 
@@ -616,8 +636,8 @@ def apply_graph(
 ) -> jax.Array:
     """Forward pass of a LayerGraph network.  ``x``: [N, H, W, d_in].
 
-    ``impls`` overrides any of {'conv', 'dwconv', 'pointwise', 'dense'}
-    with kernel-backed implementations (see ``kernel_impls``).  With
+    ``impls`` overrides any of {'conv', 'dwconv', 'pointwise', 'dense',
+    'scale'} with kernel-backed implementations (see ``kernel_impls``).  With
     ``check=True`` (trace-time only — free under jit) every node's output
     shape and MAC count are asserted against its ``LayerSpec``.
 

@@ -31,6 +31,7 @@ from repro.models.topology import (
     conv_spec as _conv,
     dense_spec,
     gap_spec,
+    scale_spec,
 )
 
 
@@ -79,16 +80,32 @@ def mobilenet_v1_chain(
     return layers
 
 
-_V2_CFG = [
-    # (expansion t, out channels c, repeats n, first stride s)
-    (1, 16, 1, 1),
-    (6, 24, 2, 2),
-    (6, 32, 3, 2),
-    (6, 64, 4, 2),
-    (6, 96, 3, 1),
-    (6, 160, 3, 2),
-    (6, 320, 1, 1),
-]
+@dataclasses.dataclass(frozen=True)
+class InvertedResidualNet:
+    """An inverted-residual network's block description: a stem conv
+    3x3/2 to 32 channels, stages of MBConv blocks, a 1x1 head.  One row per stage:
+    (expansion t, out channels c, repeats n, first stride s, depthwise
+    kernel k).  ``se_ratio`` > 0 adds a squeeze-and-excitation gate to
+    every block, reducing to ``max(1, int(se_ratio * block input
+    channels))``; ``act`` is every non-linear layer's activation."""
+
+    rows: Tuple[Tuple[int, int, int, int, int], ...]
+    act: str
+    se_ratio: float = 0.0
+
+
+MOBILENET_V2 = InvertedResidualNet(
+    rows=(
+        (1, 16, 1, 1, 3),
+        (6, 24, 2, 2, 3),
+        (6, 32, 3, 2, 3),
+        (6, 64, 4, 2, 3),
+        (6, 96, 3, 1, 3),
+        (6, 160, 3, 2, 3),
+        (6, 320, 1, 1, 3),
+    ),
+    act="relu6",
+)
 
 
 def _v2_channels(alpha: float):
@@ -115,9 +132,13 @@ class _ChainSink:
     def join(self, name: str, d: int, hw: Tuple[int, int]) -> None:
         pass
 
+    def squeeze_excite(self, name, d, d_se, hw, act) -> None:
+        pass
+
 
 class _GraphSink:
-    """Builds the true DAG: an explicit 'add' join per residual block."""
+    """Builds the true DAG: an explicit 'add' join per residual block, and
+    a 'scale' join per squeeze-and-excitation gate."""
 
     def __init__(self) -> None:
         self.g = LayerGraph()
@@ -133,18 +154,31 @@ class _GraphSink:
     def join(self, name: str, d: int, hw: Tuple[int, int]) -> None:
         self.prev = self.g.add(add_spec(name, d, hw), [self.prev, self.block_in])
 
+    def squeeze_excite(self, name, d, d_se, hw, act) -> None:
+        """gap -> dense reduce -> dense expand (sigmoid) -> the 'scale'
+        join of the trunk by its frame's gate."""
+        trunk = self.prev
+        gap = self.g.add(gap_spec(f"{name}_se_gap", d, hw), [trunk])
+        red = self.g.add(
+            dense_spec(f"{name}_se_reduce", d, d_se, act=act), [gap])
+        gate = self.g.add(
+            dense_spec(f"{name}_se_expand", d_se, d, act="sigmoid"), [red])
+        self.prev = self.g.add(scale_spec(f"{name}_scale", d, hw), [trunk, gate])
 
-def _v2_body(sink, input_hw, alpha):
-    """Walk the V2 block description once, emitting into ``sink`` — the
-    single source both the DSE topology and the executable net derive
-    from.  Returns (final channels, final hw)."""
-    c = _v2_channels(alpha)
+
+def inverted_residual_body(sink, input_hw, net: InvertedResidualNet, c, head):
+    """Walk ``net``'s block description once, emitting into ``sink`` —
+    the single source both the DSE topology and the executable net
+    derive from.  ``c`` rounds a published channel count (the width
+    multiplier); ``head`` is the 1x1 head's width.  Returns (final
+    channels, final hw)."""
+    act = net.act
     hw = input_hw
-    spec, hw = _conv("conv1", "conv", 3, c(32), hw, 3, 2, act="relu6")
+    spec, hw = _conv("conv1", "conv", 3, c(32), hw, 3, 2, act=act)
     sink.layer(spec)
     d = c(32)
     blk = 0
-    for t, ch, n, s in _V2_CFG:
+    for t, ch, n, s, k in net.rows:
         for i in range(n):
             blk += 1
             stride = s if i == 0 else 1
@@ -152,13 +186,16 @@ def _v2_body(sink, input_hw, alpha):
             sink.start_block()
             if t != 1:
                 spec, hw = _conv(
-                    f"b{blk}_expand", "pointwise", d, exp, hw, 1, 1, act="relu6"
+                    f"b{blk}_expand", "pointwise", d, exp, hw, 1, 1, act=act
                 )
                 sink.layer(spec)
             spec, hw = _conv(
-                f"b{blk}_dw", "dwconv", exp, exp, hw, 3, stride, act="relu6"
+                f"b{blk}_dw", "dwconv", exp, exp, hw, k, stride, act=act
             )
             sink.layer(spec)
+            if net.se_ratio:
+                d_se = max(1, int(net.se_ratio * d))
+                sink.squeeze_excite(f"b{blk}", exp, d_se, hw, act)
             # linear bottleneck: no activation on the projection
             spec, hw = _conv(
                 f"b{blk}_project", "pointwise", exp, c(ch), hw, 1, 1, act="none"
@@ -167,10 +204,15 @@ def _v2_body(sink, input_hw, alpha):
             if stride == 1 and d == c(ch):
                 sink.join(f"b{blk}_add", c(ch), hw)
             d = c(ch)
-    last = c(1280) if alpha > 1.0 else 1280
-    spec, hw = _conv("conv_last", "pointwise", d, last, hw, 1, 1, act="relu6")
+    spec, hw = _conv("conv_last", "pointwise", d, head, hw, 1, 1, act=act)
     sink.layer(spec)
-    return last, hw
+    return head, hw
+
+
+def _v2_body(sink, input_hw, alpha):
+    c = _v2_channels(alpha)
+    head = c(1280) if alpha > 1.0 else 1280
+    return inverted_residual_body(sink, input_hw, MOBILENET_V2, c, head)
 
 
 def mobilenet_v2_chain(

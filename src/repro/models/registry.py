@@ -11,7 +11,7 @@ LM side — everything the launcher / dry-run needs:
       jax.ShapeDtypeStruct pytrees (no allocation — dry-run safe).
 
 CNN side — the paper's workloads, same lookup shape:
-  api = get_cnn_api("resnet18")          # or mobilenet_v1/v2, resnet34
+  api = get_cnn_api("resnet18")   # or mobilenet_v1/v2, resnet34, efficientnet_b0
   cfg = api.make_config(input_hw=(32, 32), num_classes=10)
   params = api.init(cfg, rng)
   logits = api.apply(params, x, cfg)     # conv_impls= swaps in Pallas
@@ -31,7 +31,16 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.configs.shapes import ShapeSuite
-from repro.models import encdec, hybrid, lm, mamba, mobilenet, resnet, vlm
+from repro.models import (
+    efficientnet,
+    encdec,
+    hybrid,
+    lm,
+    mamba,
+    mobilenet,
+    resnet,
+    vlm,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -293,6 +302,9 @@ def _resnet_api(depth: int) -> CNNApi:
 
 
 _CNN_FAMILIES: Dict[str, Callable[[], CNNApi]] = {
+    "efficientnet_b0": functools.partial(
+        _cnn_api, "efficientnet_b0", efficientnet.EfficientNetConfig,
+        efficientnet),
     "mobilenet_v1": functools.partial(_mobilenet_api, 1),
     "mobilenet_v2": functools.partial(_mobilenet_api, 2),
     "resnet18": functools.partial(_resnet_api, 18),

@@ -50,6 +50,13 @@ def add_spec(name: str, d: int, hw: Tuple[int, int],
                      in_hw=hw, out_hw=hw, activation=act)
 
 
+def scale_spec(name: str, d: int, hw: Tuple[int, int]) -> LayerSpec:
+    """Squeeze-and-excitation join: a trunk [hw, d] times its frame's
+    gate [d] (the join's second producer)."""
+    return LayerSpec(name=name, kind="scale", d_in=d, d_out=d,
+                     in_hw=hw, out_hw=hw)
+
+
 def gap_spec(name: str, d: int, hw: Tuple[int, int]) -> LayerSpec:
     """Global average pool: whole-frame running mean down to 1x1."""
     return LayerSpec(name=name, kind="gap", d_in=d, d_out=d,

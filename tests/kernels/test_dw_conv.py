@@ -51,3 +51,17 @@ def test_dw_mobilenet_block():
     assert got.shape == (1, 7, 7, 96)
     np.testing.assert_allclose(got, dw_conv_ref(x, w, stride=2),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("stride", [1, 2])
+@pytest.mark.parametrize("hw, c", [(14, 40), (7, 96), (8, 48)])
+def test_dw_5x5(hw, c, stride):
+    """EfficientNet's 5x5 depthwise: SAME padding of 2 on each side at
+    stride 1, and 1 before / 2 after (plus the phase round-up) at stride 2."""
+    k1, k2 = jax.random.split(jax.random.key(3))
+    x = _rand(k1, (2, hw, hw, c))
+    w = _rand(k2, (5, 5, c))
+    got = dw_conv(x, w, stride=stride)
+    assert got.shape == (2, -(-hw // stride), -(-hw // stride), c)
+    np.testing.assert_allclose(got, dw_conv_ref(x, w, stride=stride),
+                               rtol=1e-4, atol=1e-4)

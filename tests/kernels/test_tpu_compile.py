@@ -78,6 +78,7 @@ def _wide_conv_graph():
 GRAPHS = {
     "resnet18": lambda: _family_graph("resnet18"),
     "mobilenet_v2": lambda: _family_graph("mobilenet_v2"),
+    "efficientnet_b0": lambda: _family_graph("efficientnet_b0"),
     "wide": _wide_conv_graph,
 }
 
@@ -96,6 +97,13 @@ CASES = [
     ("mobilenet_v2", "b2_dw", jnp.float32),  # dw 3x3/2, 112 -> 56, C=96
     ("mobilenet_v2", "b2_dw", jnp.bfloat16),
     ("mobilenet_v2", "b1_dw", jnp.bfloat16),
+    ("efficientnet_b0", "b4_dw", jnp.float32),  # dw 5x5/2, 56 -> 28, C=144
+    ("efficientnet_b0", "b10_dw", jnp.float32),  # dw 5x5/1 at 14x14x672
+    ("efficientnet_b0", "b2_se_reduce", jnp.float32),  # SE dense 96 -> 4
+    ("efficientnet_b0", "b2_se_expand", jnp.float32),  # SE dense 4 -> 96
+    ("efficientnet_b0", "b1_scale", jnp.float32),  # se_scale at 112x112x32
+    ("efficientnet_b0", "b6_scale", jnp.float32),  # se_scale at 28x28x240
+    ("efficientnet_b0", "b16_scale", jnp.float32),  # se_scale at 7x7x1152
     ("wide", "c3x3_112", jnp.float32),
 ]
 
@@ -106,7 +114,8 @@ def _operands(spec, dtype, sharding):
         x = (n, spec.d_in)
     else:
         x = (n, *spec.in_hw, spec.d_in)
-    w = cnn._weight_shape(spec)
+    # a scale join's second operand is its gate, one [C] a frame
+    w = (n, spec.d_in) if spec.kind == "scale" else cnn._weight_shape(spec)
     return [
         jax.ShapeDtypeStruct(s, dtype, sharding=sharding) for s in (x, w)
     ]

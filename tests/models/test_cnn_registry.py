@@ -1,4 +1,4 @@
-"""The unified CNN registry: one lookup + one apply machinery, 4 families."""
+"""The unified CNN registry: one lookup + one apply machinery, 5 families."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -7,7 +7,8 @@ from repro.core.flops import graph_macs
 from repro.models import cnn
 from repro.models.registry import cnn_families, get_cnn_api
 
-FAMILIES = ("mobilenet_v1", "mobilenet_v2", "resnet18", "resnet34")
+FAMILIES = ("efficientnet_b0", "mobilenet_v1", "mobilenet_v2", "resnet18",
+            "resnet34")
 
 
 def test_registry_lists_all_families():
