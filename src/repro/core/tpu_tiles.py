@@ -48,7 +48,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from fractions import Fraction
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 from .dse import LayerImpl
 from .hw_specs import TPU_V5E, TPUSpec
@@ -199,11 +199,13 @@ def conv_frame_vmem_bytes(
     dtype_bytes: int,
     spec: TPUSpec,
     depthwise: bool = False,
+    frames: int = 1,
 ) -> int:
     """Working set of one whole-frame conv grid step (kpu_conv, or
-    dw_conv with ``depthwise``): the phase-split input, weight and
-    output blocks double-buffered, the f32 accumulator and one f32 tap
-    product, and one loaded tap window."""
+    dw_conv with ``depthwise``) holding ``frames`` frames: the
+    phase-split input, weight and output blocks double-buffered, the f32
+    accumulator and one f32 tap product, and one loaded tap window.
+    Every term but the weight block scales with ``frames``."""
     (hq, wq), (ho, wo), (kh, kw) = geo.phase_hw, geo.out_hw, kernel
     x = padded_bytes((len(geo.phases), hq, wq, bci), dtype_bytes, spec)
     w_shape = (kh, kw, bci) if depthwise else (kh, kw, bci, bco)
@@ -211,7 +213,25 @@ def conv_frame_vmem_bytes(
     o = padded_bytes((ho, wo, bco), dtype_bytes, spec)
     acc = padded_bytes((ho, wo, bco), 4, spec)
     win = padded_bytes((ho, wo, bci), dtype_bytes, spec)
-    return 2 * (x + w + o) + 2 * acc + win
+    return 2 * (frames * (x + o) + w) + frames * (2 * acc + win)
+
+
+def conv_block_frames(
+    n: int,
+    out_px: int,
+    bm: Optional[int],
+    fits: Optional[Callable[[int], bool]] = None,
+) -> int:
+    """Frames a whole-frame conv grid step holds: the largest divisor of
+    the ``n`` frames whose output pixels stay within the pixel tile
+    ``bm`` (the plan's multi-pixel P) and, given ``fits``, whose working
+    set fits.  One frame when ``bm`` is None or below a frame."""
+    cap = max(bm or 0, out_px)
+    return max(
+        d
+        for d in divisors(n)
+        if d == 1 or (d * out_px <= cap and (fits is None or fits(d)))
+    )
 
 
 def scale_tile(

@@ -14,8 +14,11 @@ is the schedule:
     one MXU pass  x_shifted[(Ho*Wo), bci] @ w_tap[bci, bco]  and
     accumulate in an f32 VMEM scratch — the KPU's adder tree becomes the
     MXU's systolic reduction + scratch accumulation;
-  * multi-pixel P: every output position of the block is computed per
-    pass (the lane dimension), i.e. P = Wo;
+  * multi-pixel P: every output position of the block's ``nb`` frames
+    is computed per pass, i.e. P = nb * Ho * Wo rows through each
+    weight tap: ``nb`` > 1 where the data rate has fallen (the small
+    late-layer frames), so each weight block is fetched once per ``nb``
+    frames;
   * stride pruning (§II-E): the padded frame arrives split into its
     stride phases (``kernels.common.phase_split``), so every tap reads
     one contiguous window of one phase — only surviving-phase windows
@@ -40,22 +43,27 @@ from repro.kernels.common import kernel_name, pallas_call
 
 
 def _kpu_kernel(x_ref, w_ref, o_ref, acc_ref, *, taps: tuple, grid_ci: int):
-    """Grid: (n, co_blocks, ci_blocks).  Blocks:
-    x: [1, P, Hq, Wq, bci] (padded frame split into P stride phases),
-    w: [kh, kw, bci, bco], o/acc: [1, Ho, Wo, bco].  ``taps`` gives per
-    (dy, dx) the (phase, row, col) its window starts at."""
+    """Grid: (n/nb, co_blocks, ci_blocks).  Blocks:
+    x: [nb, P, Hq, Wq, bci] (nb padded frames split into P stride
+    phases), w: [kh, kw, bci, bco], o: [nb, Ho, Wo, bco], acc:
+    [nb*Ho, Wo, bco].  ``taps`` gives per (dy, dx) the (phase, row, col)
+    its window starts at."""
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    _, ho, wo, _ = acc_ref.shape
-    # weight-stationary tap loop (static unroll = the C configurations)
+    nb, ho, wo, bco = o_ref.shape
+    bci = x_ref.shape[-1]
+    # weight-stationary tap loop (static unroll = the C configurations);
+    # the nb frames' windows merge into the leading (row) axis for free,
+    # so one pass streams all nb * Ho * Wo positions (Mosaic collapses
+    # (rows, Wo) for the MXU: a relayout unless Wo is a multiple of 8)
     for (dy, dx), (p, oy, ox) in taps:
-        win = x_ref[0, p, oy : oy + ho, ox : ox + wo, :]  # [Ho, Wo, bci]
-        acc_ref[0] += jax.lax.dot_general(
-            win,
+        win = x_ref[:, p, oy : oy + ho, ox : ox + wo, :]  # [nb, Ho, Wo, bci]
+        acc_ref[...] += jax.lax.dot_general(
+            win.reshape(nb * ho, wo, bci),
             w_ref[dy, dx],  # [bci, bco]
             dimension_numbers=(((2,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -63,7 +71,7 @@ def _kpu_kernel(x_ref, w_ref, o_ref, acc_ref, *, taps: tuple, grid_ci: int):
 
     @pl.when(ci == grid_ci - 1)
     def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[...] = acc_ref[...].reshape(nb, ho, wo, bco).astype(o_ref.dtype)
 
 
 def kpu_conv_p(
@@ -74,17 +82,22 @@ def kpu_conv_p(
     out_hw: tuple,
     bci: int,
     bco: int,
+    frames: int = 1,
     out_dtype=None,
     node=None,
 ) -> jax.Array:
+    """``frames`` (nb) is the frames each grid step holds: its weight
+    blocks are fetched once per nb frames."""
     n, n_ph, hq, wq, d_in = x_phases.shape
     kh, kw, d_in2, d_out = w.shape
     assert d_in == d_in2
     assert d_in % bci == 0 and d_out % bco == 0, (
         f"(bci={bci}, bco={bco}) must divide ({d_in}, {d_out})"
     )
+    assert n % frames == 0, f"frames={frames} must divide the batch {n}"
     ho, wo = out_hw
-    grid = (n, d_out // bco, d_in // bci)
+    nb = frames
+    grid = (n // nb, d_out // bco, d_in // bci)
     out_dtype = out_dtype or x_phases.dtype
     return pallas_call(
         functools.partial(_kpu_kernel, taps=taps, grid_ci=grid[2]),
@@ -92,11 +105,13 @@ def kpu_conv_p(
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (1, n_ph, hq, wq, bci), lambda nn, co, ci: (nn, 0, 0, 0, ci)
+                (nb, n_ph, hq, wq, bci), lambda nn, co, ci: (nn, 0, 0, 0, ci)
             ),
             pl.BlockSpec((kh, kw, bci, bco), lambda nn, co, ci: (0, 0, ci, co)),
         ],
-        out_specs=pl.BlockSpec((1, ho, wo, bco), lambda nn, co, ci: (nn, 0, 0, co)),
+        out_specs=pl.BlockSpec(
+            (nb, ho, wo, bco), lambda nn, co, ci: (nn, 0, 0, co)
+        ),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, d_out), out_dtype),
-        scratch_shapes=[pltpu.VMEM((1, ho, wo, bco), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((nb * ho, wo, bco), jnp.float32)],
     )(x_phases, w)

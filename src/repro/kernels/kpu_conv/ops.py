@@ -11,9 +11,11 @@ Two ways to pick the (bci, bco) channel tiles:
     the plan per node).
 
 Two routes run a conv: the whole-frame KPU kernel on the phase-split
-padded input, or — when that frame cannot fit VMEM (the lane-sparse
-3-channel stems; ``TileChoice.im2col``, or the same budget check on the
-uniform path) — im2col patches through the FCU matmul kernel.
+padded input, holding as many frames per grid step as the pixel tile
+``bm`` covers (``block_frames``), or — when that frame cannot fit VMEM
+(the lane-sparse 3-channel stems; ``TileChoice.im2col``, or the same
+budget check on the uniform path) — im2col patches through the FCU
+matmul kernel.
 """
 from __future__ import annotations
 
@@ -28,6 +30,7 @@ from repro.core.hw_specs import target_spec
 from repro.core.tpu_tiles import (
     ConvGeometry,
     TileChoice,
+    conv_block_frames,
     conv_frame_vmem_bytes,
     conv_geometry,
     fit_bm,
@@ -72,9 +75,11 @@ def kpu_conv(
     im2col: Optional[bool] = None,
     node: Optional[str] = None,
 ) -> jax.Array:
-    """``im2col=None`` picks the route by the frame's VMEM working set;
-    on the im2col route ``bm`` is the pixel tile of the FCU matmul.
-    ``node`` names the graph node in the kernel's name."""
+    """``im2col=None`` picks the route by the frame's VMEM working set.
+    ``bm`` is the pixel tile: of the FCU matmul on the im2col route; on
+    the whole-frame route it sets the frames a grid step holds
+    (``block_frames``; one without it).  ``node`` names the graph
+    node in the kernel's name."""
     n, h, wdt, d_in = x.shape
     kh, kw, _, d_out = w.shape
     geo = conv_geometry((h, wdt), (kh, kw), stride)
@@ -109,8 +114,38 @@ def kpu_conv(
         out_hw=(ho, wo),
         bci=bci,
         bco=bco,
+        frames=block_frames(n, geo, (kh, kw), bci, bco, bm, x.dtype.itemsize),
         node=node,
     )
+
+
+def block_frames(
+    n: int,
+    geo: ConvGeometry,
+    kernel,
+    bci: int,
+    bco: int,
+    bm: Optional[int],
+    dtype_bytes: int,
+) -> int:
+    """Frames per grid step of the whole-frame route: as many of the
+    ``n`` as the pixel tile ``bm`` covers, within the VMEM budget."""
+    spec = target_spec()
+    budget = vmem_budget(spec)
+    ho, wo = geo.out_hw
+
+    def fits(frames):
+        return conv_frame_vmem_bytes(
+            geo,
+            kernel,
+            bci,
+            bco,
+            dtype_bytes=dtype_bytes,
+            spec=spec,
+            frames=frames,
+        ) <= budget
+
+    return conv_block_frames(n, ho * wo, bm, fits)
 
 
 def conv_impl(
@@ -123,11 +158,12 @@ def conv_impl(
     """Adapter to the CNN executor's 'conv' signature (models/cnn.py):
     ``impl(x, w_hwio, stride) -> y`` with the KPU kernel underneath.
 
-    ``tile`` pins the channel tiling and the route to a plan's choice
-    (rate-matched path); without it ``rate`` parameterizes the uniform
-    search.  ``record(bk=..., bn=..., d_in=..., d_out=...)`` is called
-    with the executed tile at trace time — plus ``bm`` and ``m`` on the
-    im2col route, whose pixel tile the plan pins like the FCU kinds'.
+    ``tile`` pins the channel tiling, the route and the pixel tile to a
+    plan's choice (rate-matched path); without it ``rate`` parameterizes
+    the uniform search.  ``record(bk=..., bn=..., d_in=..., d_out=...)``
+    is called with the executed tile at trace time — plus ``bm`` and
+    ``m`` on the im2col route, whose pixel tile the plan pins like the
+    FCU kinds', and ``frames`` (per grid step) on the whole-frame route.
     ``node`` names the graph node in the kernel's name.
     """
     def impl(x, w, stride):
@@ -136,19 +172,26 @@ def conv_impl(
             if record is not None:
                 record(bk=None, bn=None, d_in=x.shape[-1], d_out=w.shape[-1])
             return y
-        extra = {}
+        n, h, wdt, _ = x.shape
+        geo = conv_geometry((h, wdt), w.shape[:2], stride)
         if tile.im2col:
-            n, h, wdt, _ = x.shape
-            ho, wo = conv_geometry((h, wdt), w.shape[:2], stride).out_hw
-            m = n * ho * wo
-            extra = {"bm": fit_bm(m, tile.bm), "m": m}
+            m = n * geo.out_hw[0] * geo.out_hw[1]
+            bm = fit_bm(m, tile.bm)
+            extra = {"bm": bm, "m": m}
+        else:
+            bm = tile.bm
+            extra = {
+                "frames": block_frames(
+                    n, geo, w.shape[:2], tile.bk, tile.bn, bm, x.dtype.itemsize
+                )
+            }
         y = kpu_conv(
             x,
             w,
             stride=stride,
             bci=tile.bk,
             bco=tile.bn,
-            bm=extra.get("bm"),
+            bm=bm,
             im2col=tile.im2col,
             node=node,
         )

@@ -54,7 +54,7 @@ from repro.core.dse import NON_ARITH_KINDS
 from repro.core.graph import JOIN_KINDS, ImplPlan, LayerGraph
 from repro.core.rate import LayerSpec
 from repro.core.stage_partition import resolve_link_dtype
-from repro.core.tpu_tiles import KERNEL_KINDS
+from repro.core.tpu_tiles import KERNEL_KINDS, conv_block_frames
 from repro.nn.quant import dequantize_link, fake_quant_link, quantize_link
 
 Impl = Callable[..., jax.Array]
@@ -383,7 +383,9 @@ def _check_planned_tile(
     kinds (and convs planned on the im2col route, which run the FCU)
     additionally must execute the planned bm on the planned m:
     the micro-batcher promised that shape, so a mismatch is a serving
-    bug, not a legal re-fit.
+    bug, not a legal re-fit; and a whole-frame conv must hold the frames
+    per grid step that the planned bm covers on that batch
+    (``conv_block_frames``): the plan's multi-pixel P, executed.
     """
     if node_plan is None:
         raise GraphExecutionError(f"{spec.name}: node missing from the kernel plan")
@@ -429,6 +431,15 @@ def _check_planned_tile(
             raise GraphExecutionError(
                 f"{spec.name}: executed bm={got.get('bm')} != batch-pinned "
                 f"plan bm={t.bm}"
+            )
+    if node_plan.batch is not None and spec.kind == "conv" and not t.im2col:
+        want = conv_block_frames(
+            node_plan.batch, spec.out_hw[0] * spec.out_hw[1], t.bm
+        )
+        if got.get("frames") != want:
+            raise GraphExecutionError(
+                f"{spec.name}: executed {got.get('frames')} frames per grid "
+                f"step != {want} that the batch-pinned plan bm={t.bm} covers"
             )
 
 

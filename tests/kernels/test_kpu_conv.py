@@ -5,7 +5,11 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core.tpu_tiles import conv_geometry
+from repro.kernels.common import conv_taps, phase_split
 from repro.kernels.kpu_conv import kpu_conv, kpu_conv_ref
+from repro.kernels.kpu_conv.kpu_conv import kpu_conv_p
+from repro.kernels.kpu_conv.ops import block_frames
 
 
 def _rand(key, shape, dtype=jnp.float32):
@@ -90,3 +94,35 @@ def test_kpu_im2col_route_matches_ref(hw, k, stride, cin):
     want = kpu_conv_ref(x, w, stride=stride)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(got, kpu, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("nb", [2, 4, 8])
+@pytest.mark.parametrize(
+    "hw,stride",
+    [(7, 1), (14, 1), (14, 2)],
+    ids=["7x7", "14x14", "stride2-4phase"],
+)
+def test_kpu_frames_per_step_match_one_frame(hw, stride, nb):
+    """nb frames per grid step (the plan's multi-pixel P over frames)
+    compute each frame's sums exactly as one frame per step does."""
+    k1, k2 = jax.random.split(jax.random.key(6))
+    x = _rand(k1, (8, hw, hw, 128))
+    w = _rand(k2, (3, 3, 128, 128))
+    geo = conv_geometry((hw, hw), (3, 3), stride)
+    ho, wo = geo.out_hw
+    xs, taps = phase_split(x, geo), conv_taps(geo, (3, 3))
+    assert xs.shape[1] == (4 if stride == 2 else 1)
+
+    def run(frames):
+        return kpu_conv_p(xs, w, taps=taps, out_hw=geo.out_hw, bci=128,
+                          bco=128, frames=frames)
+
+    got = run(nb)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(run(1)))
+    np.testing.assert_allclose(got, kpu_conv_ref(x, w, stride=stride),
+                               rtol=1e-4, atol=1e-4)
+    # the wrapper holds as many frames as the pixel tile bm covers
+    assert block_frames(8, geo, (3, 3), 128, 128, nb * ho * wo, 4) == nb
+    wrapped = kpu_conv(x, w, stride=stride, bci=128, bco=128,
+                       bm=nb * ho * wo, im2col=False)
+    np.testing.assert_array_equal(np.asarray(wrapped), np.asarray(got))

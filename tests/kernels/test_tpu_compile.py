@@ -88,6 +88,7 @@ CASES = [
     ("resnet18", "l2b1_conv1", jnp.float32),  # 3x3/2, 56 -> 28
     ("resnet18", "l2b1_down", jnp.float32),  # 1x1/2 projection
     ("resnet18", "l3b1_conv1", jnp.float32),  # 3x3/2, 28 -> 14
+    ("resnet18", "l3b2_conv2", jnp.float32),  # 3x3/1 at 14x14x256
     ("resnet18", "l4b1_conv1", jnp.float32),  # 3x3/2, 14 -> 7
     ("resnet18", "l4b2_conv2", jnp.float32),  # 3x3/1 at 7x7x512
     ("resnet18", "fc", jnp.float32),  # 512 -> 1000 head
@@ -106,6 +107,18 @@ CASES = [
     ("efficientnet_b0", "b16_scale", jnp.float32),  # se_scale at 7x7x1152
     ("wide", "c3x3_112", jnp.float32),
 ]
+
+
+# frames per grid step of the whole-frame convs: the planned bm (448-512
+# pixels at layers 1-2, 392 at layers 3-4) over one frame's output pixels
+FRAMES = {
+    "l1b1_conv1": 1,
+    "l2b1_conv1": 1,
+    "l3b1_conv1": 2,
+    "l3b2_conv2": 2,
+    "l4b1_conv1": 8,
+    "l4b2_conv2": 8,
+}
 
 
 def _operands(spec, dtype, sharding):
@@ -146,3 +159,7 @@ def test_served_kernel_compiles_for_v5e(
     assert "tpu_custom_call" in compiled.as_text()
     assert executed[node]["bk"] == node_plan.tile.bk
     assert executed[node]["bn"] == node_plan.tile.bn
+    if family == "resnet18" and node in FRAMES:
+        ho, wo = spec.out_hw
+        assert executed[node]["frames"] == FRAMES[node]
+        assert FRAMES[node] == max(1, node_plan.tile.bm // (ho * wo))

@@ -120,6 +120,32 @@ def test_tampered_plan_is_detected():
                         executed=executed)
 
 
+def test_frames_per_step_other_than_the_batch_pinned_plan_is_detected():
+    """A whole-frame conv must hold the frames per grid step that its
+    batch-pinned bm covers (the plan's multi-pixel P): kernels run with
+    one frame a step against that plan must trip the per-node check."""
+    api, cfg = _setup("resnet18")
+    graph = api.graph(cfg)
+    kp = plan_graph(graph, RATE).kernel_plan(batch=2)
+    params = api.init(cfg, jax.random.key(0))
+    x = jax.random.normal(jax.random.key(1), (2, 32, 32, 3))
+    victim = "l3b2_conv1"  # 2x2 output: bm covers both frames
+    executed = {}
+    cnn.apply_graph(params, x, graph, plan=kp, executed=executed)
+    assert executed[victim]["frames"] == 2
+    spec = graph.spec(victim)
+    one_frame = dict(kp)
+    one_frame[victim] = dataclasses.replace(
+        kp[victim],
+        tile=dataclasses.replace(kp[victim].tile, bm=spec.out_hw[0] * spec.out_hw[1]),
+    )
+    executed = {}
+    impls = cnn.kernel_impls(plan=one_frame, executed=executed)
+    with pytest.raises(cnn.GraphExecutionError, match=f"{victim}.*frames"):
+        cnn.apply_graph(params, x, graph, impls=impls, plan=kp,
+                        executed=executed)
+
+
 @pytest.mark.parametrize("family", FAMILIES)
 def test_rate_matched_equals_uniform_fp32_and_int8(family):
     """Equivalence: per-layer tiling follows the DSE but the arithmetic
