@@ -30,6 +30,7 @@ from repro.fleet import (
     plan_pool,
 )
 from repro.models.registry import get_cnn_api
+from repro.serving import ServeConfig
 
 
 def replication_headline() -> None:
@@ -70,7 +71,7 @@ def main() -> None:
           f"{pool.fair_share()}")
 
     print("\n=== 3. concurrent serving on one shared clock ===")
-    sched = FleetScheduler(pool, execute=True)
+    sched = FleetScheduler(pool, config=ServeConfig(execute=True))
     sched.init_params("vision-a", jax.random.key(0))
     sched.init_params("vision-b", jax.random.key(1))
     rng = np.random.default_rng(0)

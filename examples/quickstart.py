@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.core import (estimate_network, fps, plan_network,
                         propagate_chain)
 from repro.models import mobilenet as mn
+from repro.models.registry import get_cnn_api
 
 RATE = F(3, 1)   # 3 features/clock = 1 pixel/clock at the RGB input
 
@@ -46,15 +47,16 @@ def main() -> None:
           f"(paper: 8026.4)\n")
 
     print("=== 3. JAX inference: XLA vs Pallas KPU/FCU kernels ===")
-    small = mn.MobileNetConfig(version=2, input_hw=(32, 32), num_classes=10)
-    params = mn.init_params(small, jax.random.key(0))
+    api = get_cnn_api("mobilenet_v2")
+    small = api.make_config(input_hw=(32, 32), num_classes=10)
+    params = api.init(small, jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (1, 32, 32, 3))
-    base = mn.apply(params, x, small)
+    base = api.apply(params, x, small)
 
     from repro.kernels.dw_conv import dw_conv
     from repro.kernels.fcu_matmul import fcu_matmul
     from repro.kernels.kpu_conv import kpu_conv
-    kern = mn.apply(params, x, small, conv_impls={
+    kern = api.apply(params, x, small, conv_impls={
         "conv": lambda a, w, s: kpu_conv(a, w, stride=s),
         "dwconv": lambda a, w, s: dw_conv(a, w[:, :, 0, :], stride=s),
         "pointwise": lambda a, w: fcu_matmul(a, w),

@@ -14,7 +14,7 @@ import time
 import numpy as np
 
 from repro.configs.registry import get_config, reduced
-from repro.models.registry import get_api
+from repro.models.lm_api import get_api
 from repro.serving.engine import Engine, Request
 
 

@@ -23,10 +23,9 @@ takes a fleet-wide config (execution knobs shared by every engine) and
 ``TenantWorkload.config`` overrides it per tenant — including per-
 tenant arrival scenarios (``serving.scenarios``) and overload policies
 (``serving.overload``), so one tenant can shed under an SLA while its
-neighbor plan-switches.  The pre-ServeConfig keyword arguments
-(``execute``/``check``/``jit`` on the scheduler,
-``arrival_rate``/``microbatch``/``flush_after_ticks`` on the workload)
-keep working as a deprecated shim.
+neighbor plan-switches.  Without a ``config`` a workload's own
+``arrival_rate``/``microbatch``/``flush_after_ticks`` fields are
+layered over the fleet-wide config.
 
 ``FleetReport`` aggregates per-tenant telemetry (p50/p99 service
 latency, stall/bound flags, shed/switch counts) with per-chip occupancy
@@ -38,7 +37,6 @@ with the single-engine report (``serving.telemetry``).
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from fractions import Fraction
 from typing import Dict, List, Mapping, Optional, Tuple
 
@@ -165,11 +163,6 @@ class FleetReport:
         return rows
 
 
-_UNSET = object()
-
-_LEGACY_SCHED = ("execute", "check", "jit")
-
-
 class FleetScheduler:
     """Drive every pooled tenant's pipeline on one shared clock.
 
@@ -178,8 +171,7 @@ class FleetScheduler:
     the hot node's weights onto replication lanes itself).  ``config``
     is the fleet-wide ``serving.ServeConfig`` (default: timing model,
     ``execute=False``); per-tenant ``TenantWorkload.config`` overrides
-    it wholesale.  The pre-ServeConfig keyword arguments keep working
-    as a deprecated shim.
+    it wholesale.
     """
 
     def __init__(
@@ -188,29 +180,9 @@ class FleetScheduler:
         *,
         params: Optional[Mapping[str, object]] = None,
         config: Optional[ServeConfig] = None,
-        execute=_UNSET,
-        check=_UNSET,
-        jit=_UNSET,
     ) -> None:
-        legacy = {
-            k: v
-            for k, v in zip(_LEGACY_SCHED, (execute, check, jit))
-            if v is not _UNSET
-        }
         if config is None:
-            if legacy:
-                warnings.warn(
-                    "FleetScheduler(..., execute=/check=/jit=) is "
-                    "deprecated — pass a serving.ServeConfig",
-                    DeprecationWarning,
-                    stacklevel=2,
-                )
-            config = ServeConfig(execute=False).with_(**legacy)
-        elif legacy:
-            raise FleetError(
-                "pass either config= or the deprecated kwargs, not both: "
-                f"{sorted(legacy)}"
-            )
+            config = ServeConfig(execute=False)
         self.pool = pool
         self.params = dict(params or {})
         self.config = config
@@ -232,7 +204,9 @@ class FleetScheduler:
         api = get_cnn_api(t.family)
         self.params[tenant] = api.init(cand.cfg, rng)
 
-    def _tenant_config(self, w: TenantWorkload, cand) -> ServeConfig:
+    def _tenant_config(
+        self, w: TenantWorkload, cand, max_ticks: Optional[int] = None
+    ) -> ServeConfig:
         if w.config is not None:
             cfg = w.config
         else:
@@ -241,6 +215,9 @@ class FleetScheduler:
                 arrival=w.arrival_rate,
                 flush_after_ticks=w.flush_after_ticks,
             )
+        if max_ticks is not None:
+            # the fleet's run bound holds for every tenant
+            cfg = cfg.with_(max_ticks=max_ticks)
         if cfg.dtype is None:
             dtype = getattr(cand.cfg, "dtype", None)
             if dtype is not None:
@@ -261,9 +238,11 @@ class FleetScheduler:
             )
         return cfg
 
-    def _engine(self, w: TenantWorkload) -> CNNStreamEngine:
+    def _engine(
+        self, w: TenantWorkload, max_ticks: Optional[int] = None
+    ) -> CNNStreamEngine:
         cand = self.pool.candidate_for(w.tenant)
-        cfg = self._tenant_config(w, cand)
+        cfg = self._tenant_config(w, cand, max_ticks)
         params = self.params.get(w.tenant)
         if cfg.execute:
             if params is None:
@@ -302,11 +281,8 @@ class FleetScheduler:
                 raise FleetError(f"duplicate workload for {w.tenant!r}")
             seen.add(w.tenant)
 
-        engines = {w.tenant: self._engine(w) for w in workloads}
-        runs = {
-            w.tenant: engines[w.tenant].begin(max_ticks=max_ticks)
-            for w in workloads
-        }
+        engines = {w.tenant: self._engine(w, max_ticks) for w in workloads}
+        runs = {name: e.begin() for name, e in engines.items()}
 
         t = Fraction(0)
         active = dict(engines)

@@ -39,7 +39,7 @@ from repro.core.hlo_analysis import (collective_bytes, normalize_cost_analysis,
 from repro.distributed import sharding as shd
 from repro.launch.mesh import make_production_mesh
 from repro.launch.train import adam_config_for, build_train_step
-from repro.models import registry as models
+from repro.models import lm_api
 from repro.optim import optimizers as opt
 
 
@@ -61,13 +61,13 @@ def model_flops(cfg: ModelConfig, shape: ShapeSuite) -> float:
 
 def build_cell(cfg: ModelConfig, shape: ShapeSuite, mesh):
     """-> (fn, abstract_args): the jit-able step + sharded abstract args."""
-    api = models.get_api(cfg)
+    api = lm_api.get_api(cfg)
 
     if shape.kind == "train":
         adam = adam_config_for(cfg)
         p_abs = jax.eval_shape(lambda: api.init(cfg, jax.random.key(0)))
         o_abs = jax.eval_shape(lambda: opt.init(adam, p_abs))
-        b_abs = models.train_batch_specs(cfg, shape)
+        b_abs = lm_api.train_batch_specs(cfg, shape)
         p_sh = shd.params_shardings(p_abs, mesh)
         step = build_train_step(cfg, adam, grad_shardings=p_sh)
         o_sh = shd.opt_state_shardings(o_abs, p_abs, mesh)
@@ -85,12 +85,12 @@ def build_cell(cfg: ModelConfig, shape: ShapeSuite, mesh):
         p_abs = jax.eval_shape(lambda: api.init(cfg, jax.random.key(0)))
     p_sh = shd.params_shardings(p_abs, mesh)
     p_in = shd.abstract_with_shardings(p_abs, p_sh)
-    st_abs = models.serve_state_specs(cfg, shape)
+    st_abs = lm_api.serve_state_specs(cfg, shape)
     st_sh = shd.serve_state_specs(st_abs, mesh)
     st_in = shd.abstract_with_shardings(st_abs, st_sh)
 
     if shape.kind == "prefill":
-        b_abs = models.prefill_batch_specs(cfg, shape)
+        b_abs = lm_api.prefill_batch_specs(cfg, shape)
         b_in = shd.abstract_with_shardings(b_abs, shd.batch_specs(b_abs, mesh))
 
         def prefill_step(params, batch, state):
@@ -99,7 +99,7 @@ def build_cell(cfg: ModelConfig, shape: ShapeSuite, mesh):
         return prefill_step, (p_in, b_in, st_in)
 
     # decode
-    b_abs = models.decode_batch_specs(cfg, shape)
+    b_abs = lm_api.decode_batch_specs(cfg, shape)
     b_in = shd.abstract_with_shardings(b_abs, shd.batch_specs(b_abs, mesh))
     pos = jax.ShapeDtypeStruct((), jnp.int32)
 

@@ -17,7 +17,7 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import SyntheticLM
 from repro.distributed.fault_tolerance import Heartbeat, StragglerWatchdog
-from repro.models.registry import get_api
+from repro.models.lm_api import get_api
 from repro.optim import optimizers as opt
 
 

@@ -60,12 +60,3 @@ class EfficientNetConfig:
 
     def graph(self) -> LayerGraph:
         return efficientnet_b0_graph(self.input_hw, self.num_classes)
-
-
-# the shared executor, as for MobileNet: every entry point reads only
-# ``cfg.graph()`` and ``cfg.dtype``
-init_params = mobilenet.init_params
-apply = mobilenet.apply
-apply_staged = mobilenet.apply_staged
-quantize_params = mobilenet.quantize_params
-apply_int8 = mobilenet.apply_int8

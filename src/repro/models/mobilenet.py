@@ -5,11 +5,12 @@ Two faces, generated from one block description:
 1. ``mobilenet_v1_chain()`` / ``mobilenet_v2_chain()`` — the ``LayerSpec``
    chains consumed by the core DSE + resource model (Tables I & II), and
    ``mobilenet_v2_graph()`` — the true DAG with residual joins.
-2. ``init_params`` / ``apply`` — JAX inference (NHWC, folded BN,
-   optional int8 simulated quantization to honour the paper's 8-bit
-   datapath) via the shared ``LayerGraph`` executor in models/cnn.py.
-   A ``conv_impls`` mapping lets the caller swap XLA convs for the
-   Pallas KPU/FCU/DW kernels (repro.kernels.*.ops).
+2. JAX inference (NHWC, folded BN, optional int8 simulated
+   quantization to honour the paper's 8-bit datapath) through
+   ``registry.get_cnn_api("mobilenet_v2")``: the shared ``LayerGraph``
+   executor in models/cnn.py.  A ``conv_impls`` mapping lets the caller
+   swap XLA convs for the Pallas KPU/FCU/DW kernels
+   (repro.kernels.*.ops).
 
 The executor interprets the same graph the DSE plans, asserting per-node
 shapes/MACs against the specs, so topology and inference cannot drift.
@@ -18,14 +19,12 @@ BatchNorm is folded into conv scale/bias (inference-time, as on the FPGA).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import List, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.graph import LayerGraph
 from repro.core.rate import LayerSpec
-from repro.models import cnn
 from repro.models.topology import (
     add_spec,
     conv_spec as _conv,
@@ -266,112 +265,3 @@ class MobileNetConfig:
         if self.version == 2:
             return mobilenet_v2_graph(self.input_hw, self.alpha, self.num_classes)
         return LayerGraph.from_chain(self.chain())
-
-
-def init_params(cfg: MobileNetConfig, rng: jax.Array) -> cnn.Params:
-    """He-init weights + folded-BN bias for every layer in the graph."""
-    return cnn.init_graph_params(cfg.graph(), rng, cfg.dtype)
-
-
-def apply(
-    params: cnn.Params,
-    x: jax.Array,
-    cfg: MobileNetConfig,
-    *,
-    conv_impls: Optional[Dict[str, cnn.Impl]] = None,
-    plan=None,
-    overrides=None,
-    check: bool = True,
-) -> jax.Array:
-    """Forward pass.  ``x``: [N, H, W, 3].  Returns logits [N, classes].
-
-    ``conv_impls`` may override {'conv', 'dwconv', 'pointwise', 'dense'}
-    with kernel-backed implementations (see repro.kernels.*.ops and
-    ``cnn.kernel_impls``); ``plan`` (a ``GraphPlan.kernel_plan()``
-    table) runs the rate-matched path instead — each node's Pallas call
-    tiled per its own DSE choice; ``overrides`` supplies
-    node-name-keyed impls that win over both.
-    """
-    return cnn.apply_graph(
-        params,
-        x,
-        cfg.graph(),
-        impls=conv_impls,
-        plan=plan,
-        overrides=overrides,
-        dtype=cfg.dtype,
-        check=check,
-    )
-
-
-def apply_staged(
-    params: cnn.Params,
-    x: jax.Array,
-    cfg: MobileNetConfig,
-    *,
-    partition,
-    conv_impls: Optional[Dict[str, cnn.Impl]] = None,
-    plan=None,
-    overrides=None,
-    check: bool = True,
-    jit: bool = True,
-    check_monolithic: bool = False,
-    link_quant=None,
-    placement=None,
-    cache=None,
-    graph=None,
-) -> jax.Array:
-    """Multi-chip forward pass over a stage partition (a
-    ``GraphStagePlan`` or a ``GraphPlan`` planned with ``n_stages=``):
-    each stage jitted separately, cut-crossing activations — including
-    the skew-buffered residual shortcuts — threaded across the
-    boundaries.  ``graph`` defaults to ``cfg.graph()`` (pass a cached
-    instance so ``cache`` can memoize the compiled pipeline across
-    calls).  See ``cnn.apply_staged``."""
-    return cnn.apply_staged(
-        params,
-        x,
-        cfg.graph() if graph is None else graph,
-        partition=partition,
-        impls=conv_impls,
-        plan=plan,
-        overrides=overrides,
-        dtype=cfg.dtype,
-        check=check,
-        jit=jit,
-        check_monolithic=check_monolithic,
-        link_quant=link_quant,
-        placement=placement,
-        cache=cache,
-    )
-
-
-# the paper's 8-bit datapath — shared with every CNN family
-quantize_params = cnn.quantize_params
-
-
-def apply_int8(
-    q_params,
-    scales,
-    x,
-    cfg: MobileNetConfig,
-    *,
-    plan=None,
-    overrides=None,
-    partition=None,
-    jit: bool = True,
-) -> jax.Array:
-    """Inference with int8 weights dequantized on the fly (sim of the
-    FPGA's int8 datapath; activations stay float — activation quant is
-    exercised in the kernels' int8 mode)."""
-    return cnn.apply_int8(
-        q_params,
-        scales,
-        x,
-        cfg.graph(),
-        plan=plan,
-        overrides=overrides,
-        partition=partition,
-        dtype=cfg.dtype,
-        jit=jit,
-    )

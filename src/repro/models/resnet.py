@@ -12,22 +12,19 @@ Both faces are generated from the *same* block description:
 1. ``resnet18_graph()`` / ``resnet34_graph()`` — the ``LayerGraph``
    driving DSE, resource estimation, the discrete-event validator and
    benchmarks/table3_dag_buffers.py.
-2. ``init_params`` / ``apply`` / ``quantize_params`` / ``apply_int8`` —
-   JAX inference (NHWC, folded BN, optional Pallas kernels) via the
-   shared executor in models/cnn.py, which *interprets that same graph*
-   and asserts per-node shapes/MACs against it.  Topology and inference
-   cannot drift.
+2. JAX inference (NHWC, folded BN, optional Pallas kernels) through
+   ``registry.get_cnn_api("resnet18")``: the shared executor in
+   models/cnn.py *interprets that same graph* and asserts per-node
+   shapes/MACs against it.  Topology and inference cannot drift.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import List, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.graph import LayerGraph
-from repro.models import cnn
 from repro.models.topology import (
     add_spec,
     conv_spec,
@@ -126,105 +123,3 @@ class ResNetConfig:
         return _resnet_graph(
             _RESNET_STAGES[self.depth], self.input_hw, self.num_classes
         )
-
-
-def init_params(cfg: ResNetConfig, rng: jax.Array) -> cnn.Params:
-    return cnn.init_graph_params(cfg.graph(), rng, cfg.dtype)
-
-
-def apply(
-    params: cnn.Params,
-    x: jax.Array,
-    cfg: ResNetConfig,
-    *,
-    conv_impls: Optional[Dict[str, cnn.Impl]] = None,
-    plan=None,
-    overrides=None,
-    check: bool = True,
-) -> jax.Array:
-    """Forward pass.  ``x``: [N, H, W, 3].  Returns logits [N, classes].
-
-    ``conv_impls`` may override {'conv', 'dwconv', 'pointwise', 'dense'}
-    with kernel-backed implementations (see ``cnn.kernel_impls``);
-    ``plan`` (a ``GraphPlan.kernel_plan()`` table) runs the rate-matched
-    path instead — each node's Pallas call tiled per its own DSE choice;
-    ``overrides`` supplies node-name-keyed impls that win over both.
-    """
-    return cnn.apply_graph(
-        params,
-        x,
-        cfg.graph(),
-        impls=conv_impls,
-        plan=plan,
-        overrides=overrides,
-        dtype=cfg.dtype,
-        check=check,
-    )
-
-
-def apply_staged(
-    params: cnn.Params,
-    x: jax.Array,
-    cfg: ResNetConfig,
-    *,
-    partition,
-    conv_impls: Optional[Dict[str, cnn.Impl]] = None,
-    plan=None,
-    overrides=None,
-    check: bool = True,
-    jit: bool = True,
-    check_monolithic: bool = False,
-    link_quant=None,
-    placement=None,
-    cache=None,
-    graph=None,
-) -> jax.Array:
-    """Multi-chip forward pass over a stage partition (a
-    ``GraphStagePlan`` or a ``GraphPlan`` planned with ``n_stages=``):
-    each stage jitted separately, cut-crossing activations threaded
-    across the boundaries.  ``graph`` defaults to ``cfg.graph()`` (pass
-    a cached instance so ``cache`` can memoize the compiled pipeline
-    across calls).  See ``cnn.apply_staged``."""
-    return cnn.apply_staged(
-        params,
-        x,
-        cfg.graph() if graph is None else graph,
-        partition=partition,
-        impls=conv_impls,
-        plan=plan,
-        overrides=overrides,
-        dtype=cfg.dtype,
-        check=check,
-        jit=jit,
-        check_monolithic=check_monolithic,
-        link_quant=link_quant,
-        placement=placement,
-        cache=cache,
-    )
-
-
-quantize_params = cnn.quantize_params
-
-
-def apply_int8(
-    q_params,
-    scales,
-    x,
-    cfg: ResNetConfig,
-    *,
-    plan=None,
-    overrides=None,
-    partition=None,
-    jit: bool = True,
-) -> jax.Array:
-    return cnn.apply_int8(
-        q_params,
-        scales,
-        x,
-        cfg.graph(),
-        plan=plan,
-        overrides=overrides,
-        partition=partition,
-        dtype=cfg.dtype,
-        jit=jit,
-    )

@@ -1,24 +1,23 @@
-"""Serving engines.
+"""CNN stream serving.
 
-Two front doors, one admission calculus (Eq. 9: admit only into free
-capacity):
+``CNNStreamEngine`` streams CNN frame pipelines (the CNN registry
+families) with BestRate admission (Eq. 9: admit only into free
+capacity), micro-batching to the planned kernel tiles, and bounded
+inter-stage queues.  The one front door is
+``registry.CNNApi.serve`` -> ``serve_frames`` -> ``CNNStreamEngine``.
 
-* ``Engine`` — continuous batching of token streams (LM/SSM/hybrid
-  families) over a slotted KV cache;
-* ``CNNStreamEngine`` — data-rate-aware streaming of CNN frame
-  pipelines (the four CNN registry families) with BestRate admission,
-  micro-batching to the planned kernel tiles, and bounded inter-stage
-  queues (``serve_frames`` / ``registry.CNNApi.serve`` are the
-  one-call forms).
-
-The CNN engine is configured by one frozen ``ServeConfig`` (execution
-knobs + arrival source + flush/SLA/overload policy).  Traffic shapes
+The engine is configured by one frozen ``ServeConfig`` (execution
+knobs + arrival source + flush/SLA/overload policy), its only serving
+surface.  Traffic shapes
 come from ``serving.scenarios`` (constant / bursty / diurnal /
 adversarial — seeded, deterministic, exact-rational); overload behavior
 from ``serving.overload`` (``ShedPolicy`` SLA shedding, ``SwitchPolicy``
 online plan switching over a ``PlanLadder``); rendered telemetry from
 ``serving.telemetry.ServeSummary``, the schema ``ServeReport`` and
 ``fleet.FleetReport`` share.
+
+The token-stream engine of the language-model families is
+``serving.engine.Engine``; this package does not import it.
 """
 
 from repro.serving.cnn_stream import (
@@ -30,7 +29,6 @@ from repro.serving.cnn_stream import (
     serve_frames,
 )
 from repro.serving.config import ServeConfig
-from repro.serving.engine import Engine, Request
 from repro.serving.overload import (
     LadderRung,
     OverloadError,
@@ -57,12 +55,10 @@ __all__ = [
     "CNNStreamEngine",
     "Constant",
     "Diurnal",
-    "Engine",
     "FrameRequest",
     "LadderRung",
     "OverloadError",
     "PlanLadder",
-    "Request",
     "ScenarioError",
     "ServeConfig",
     "ServeReport",

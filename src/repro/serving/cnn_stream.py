@@ -77,8 +77,7 @@ as a software pipeline under load:
   stream-buffer bounds under backpressure above it.
 
 Configuration is one frozen ``serving.ServeConfig`` (execution knobs +
-arrival source + flush/SLA/overload policy); the pre-ServeConfig
-kwargs of ``__init__``/``run`` keep working as a deprecated shim.
+arrival source + flush/SLA/overload policy), the only serving surface.
 Timing is a deterministic tick model (exact ``fractions.Fraction``
 cycle arithmetic), never wall-clock; the JAX execution underneath
 produces the real outputs (bit-exact vs ``models.cnn.apply_graph``)
@@ -94,7 +93,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import warnings
 from collections import deque
 from fractions import Fraction
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -579,14 +577,12 @@ class _Rung:
         if config.execute:
             # partition=plan (not plan.stage_plan): stage_functions
             # unwraps the GraphPlan itself, and link_quant=True needs it
-            # to read the plan's link_dtype.
+            # to read the plan's link_dtype.  Default impls, no node
+            # overrides, shape/MAC checks on (trace-time only).
             self.pipeline = cnn.stage_functions(
                 graph,
                 partition=plan,
-                impls=config.impls,
                 plan=kernel_plan,
-                overrides=config.overrides,
-                check=config.check,
                 jit=config.jit,
                 link_quant=config.link_quant,
                 # "devices": one device per stage (the plan's recorded
@@ -605,34 +601,19 @@ class _Rung:
 # The engine
 # ==========================================================================
 
-_UNSET = object()
-
-_LEGACY_INIT = (
-    "microbatch",
-    "kernel_plan",
-    "impls",
-    "overrides",
-    "dtype",
-    "check",
-    "jit",
-    "execute",
-)
-
-
 class CNNStreamEngine:
     """Streaming server for one planned CNN (see module docstring).
 
     ``plan`` must be a ``core.graph.GraphPlan`` carrying a stage
     partition (``plan_graph(..., n_stages=S)``; S=1 is the single-chip
-    pipeline).  ``config`` is the unified ``serving.ServeConfig``
-    (execution knobs + arrival source + flush/SLA/overload policy); the
-    pre-ServeConfig keyword arguments keep working as a deprecated shim
-    that builds the equivalent config.  ``config.kernel_plan``
+    pipeline).  ``config`` is the ``serving.ServeConfig`` (execution
+    knobs + arrival source + flush/SLA/overload policy; default
+    ``ServeConfig()``).  ``config.kernel_plan``
     optionally threads the rate-matched per-node Pallas tiling (pass
     ``plan.kernel_plan(batch=microbatch)`` so the pixel tiles are
     pinned to the micro-batch — the engine checks the pin matches).
-    ``execute=False`` runs the deterministic tick model alone (no JAX,
-    no outputs) — what the benchmark tables use; tests run
+    ``config.execute=False`` runs the deterministic tick model alone (no
+    JAX, no outputs) — what the benchmark tables use; tests run
     ``execute=True`` and assert the served outputs bit-exact against
     ``models.cnn.apply_graph``.
 
@@ -650,47 +631,9 @@ class CNNStreamEngine:
         params,
         plan,
         config: Optional[ServeConfig] = None,
-        *,
-        microbatch=_UNSET,
-        kernel_plan=_UNSET,
-        impls=_UNSET,
-        overrides=_UNSET,
-        dtype=_UNSET,
-        check=_UNSET,
-        jit=_UNSET,
-        execute=_UNSET,
     ) -> None:
-        legacy = {
-            k: v
-            for k, v in zip(
-                _LEGACY_INIT,
-                (
-                    microbatch,
-                    kernel_plan,
-                    impls,
-                    overrides,
-                    dtype,
-                    check,
-                    jit,
-                    execute,
-                ),
-            )
-            if v is not _UNSET
-        }
         if config is None:
-            if legacy:
-                warnings.warn(
-                    "CNNStreamEngine(..., **kwargs) is deprecated — pass a "
-                    "serving.ServeConfig instead",
-                    DeprecationWarning,
-                    stacklevel=2,
-                )
-            config = ServeConfig(**legacy)
-        elif legacy:
-            raise ServingError(
-                "pass either config= or the deprecated kwargs, not both: "
-                f"{sorted(legacy)}"
-            )
+            config = ServeConfig()
         if config.microbatch < 1:
             raise ServingError(
                 f"microbatch must be >= 1, got {config.microbatch}"
@@ -1068,34 +1011,23 @@ class CNNStreamEngine:
     # ``fleet.scheduler.FleetScheduler`` drives several engines' states
     # on one shared rational clock with exactly these four calls.
 
-    def begin(
-        self,
-        *,
-        arrival_rate=None,
-        max_ticks: Optional[int] = None,
-        flush_after_ticks=_UNSET,
-    ) -> _RunState:
+    def begin(self) -> _RunState:
         """Install a fresh run over the submitted frames.
 
-        The arrival source, run bound, and flush knob default to the
-        engine's ``ServeConfig``; the keyword arguments override them
-        per run (the pre-ServeConfig calling convention).
+        The arrival source (``config.arrival``), run bound
+        (``config.max_ticks``) and flush knob come from the engine's
+        ``ServeConfig``.
 
-        ``flush_after_ticks`` bounds how long a partial micro-batch may
-        wait for more arrivals: once the *oldest* admitted frame has been
-        forming for that many ticks, the partial batch is flushed into
-        the pipeline (padded at execution, exactly like the end-of-stream
-        flush).  ``None`` keeps the original behavior — partial batches
-        flush only when the stream ends.
+        ``config.flush_after_ticks`` bounds how long a partial
+        micro-batch may wait for more arrivals: once the *oldest*
+        admitted frame has been forming for that many ticks, the partial
+        batch is flushed into the pipeline (padded at execution, exactly
+        like the end-of-stream flush).  ``None`` keeps the original
+        behavior — partial batches flush only when the stream ends.
         """
-        cfg = self.config
-        arrival = cfg.arrival if arrival_rate is None else arrival_rate
-        max_ticks = cfg.max_ticks if max_ticks is None else max_ticks
-        flush_after_ticks = (
-            cfg.flush_after_ticks
-            if flush_after_ticks is _UNSET
-            else flush_after_ticks
-        )
+        arrival = self.config.arrival
+        max_ticks = self.config.max_ticks
+        flush_after_ticks = self.config.flush_after_ticks
         flush_cycles = None
         if flush_after_ticks is not None:
             flush_cycles = Fraction(flush_after_ticks) * self.slot
@@ -1407,29 +1339,18 @@ class CNNStreamEngine:
                 rt.forming = []
                 progress = True
 
-    def run(
-        self,
-        *,
-        arrival_rate=None,
-        max_ticks: Optional[int] = None,
-        flush_after_ticks=_UNSET,
-    ) -> ServeReport:
+    def run(self) -> ServeReport:
         """Serve every submitted frame; return the telemetry report.
 
-        With no arguments the run uses the engine's ``ServeConfig``
-        (arrival source, run bound, flush knob); the keyword arguments
-        override it per run.  ``arrival_rate`` is a constant rate in
+        The run uses the engine's ``ServeConfig`` (arrival source, run
+        bound, flush knob).  ``config.arrival`` is a constant rate in
         frames/tick (1 = frames arriving exactly at the plan's input
         rate; ``best_rate`` is the sustainable ceiling) or any
         ``ArrivalProcess``.  The run is a deterministic discrete-event
         loop on an exact rational clock; it ends when the pipeline
         drains (every frame served or shed).
         """
-        rt = self.begin(
-            arrival_rate=arrival_rate,
-            max_ticks=max_ticks,
-            flush_after_ticks=flush_after_ticks,
-        )
+        rt = self.begin()
         while True:
             self.advance(rt.t)
             if self.finished:
@@ -1565,37 +1486,29 @@ def serve_frames(
     input_rate,
     n_stages: int = 1,
     config: Optional[ServeConfig] = None,
-    arrival_rate=None,
-    microbatch: Optional[int] = None,
-    rate_matched: bool = False,
-    dtype=None,
-    check: Optional[bool] = None,
-    jit: Optional[bool] = None,
-    execute=None,
-    max_ticks: Optional[int] = None,
-    flush_after_ticks=_UNSET,
     plan_cache: Optional[dict] = None,
     **dse_kwargs,
 ):
     """Plan, stream, and serve ``frames`` through a staged pipeline.
 
-    Runs the DAG DSE at ``input_rate`` with an ``n_stages`` partition,
-    optionally lowers the rate-matched per-node kernel plan pinned to
-    the micro-batch (``rate_matched=True``), and serves every frame
-    from the configured arrival source.  ``config`` is the unified
-    ``serving.ServeConfig``; the individual keyword arguments override
-    its fields (and keep the pre-ServeConfig calling convention
-    working).  Returns ``(outputs, report)``; ``outputs`` is None when
-    ``execute=False`` (timing model only).  A ``replicate=`` kwarg
-    flows through to ``plan_graph`` — the engine then runs the
-    rewritten graph with the hot node's params aliased onto the lanes.
+    Runs the DAG DSE at ``input_rate`` with an ``n_stages`` partition
+    and serves every frame from the configured arrival source.
+    ``config`` is the ``serving.ServeConfig`` that decides everything
+    about the run.  The rate-matched path is ``config.kernel_plan``:
+    the caller lowers ``GraphPlan.kernel_plan(batch=config.microbatch)``
+    once (e.g. from ``CNNApi.partition``) and passes it on every call,
+    so no call re-plans or retraces.  Returns ``(outputs, report)``;
+    ``outputs`` is None when ``config.execute=False`` (timing model
+    only).  A ``replicate=`` kwarg flows through to ``plan_graph`` —
+    the engine then runs the rewritten graph with the hot node's params
+    aliased onto the lanes.
     ``link_dtype=`` / ``bram_budget=`` flow through the same way (the
     memory-efficient streams: narrow-wire buffer pricing and
     buffer-aware cuts); pair them with ``config.link_quant`` to make
     the executed boundaries match the priced wire format.
 
-    ``execute="devices"`` places each stage on its own device (pass
-    ``n_devices=`` to record a placement that co-locates stages).
+    ``config.execute="devices"`` places each stage on its own device
+    (pass ``n_devices=`` to record a placement that co-locates stages).
     ``plan_cache`` memoizes the DSE result per (graph identity, rate,
     stages, kwargs) so repeated
     calls — e.g. through ``CNNApi.serve`` — skip re-planning; pair with
@@ -1621,20 +1534,6 @@ def serve_frames(
         pid = cfg.trace_pid
         tr.begin("serve_frames", host_now(), pid=pid, tid="host",
                  clock="host")
-    overrides = {
-        "microbatch": microbatch,
-        "dtype": dtype,
-        "check": check,
-        "jit": jit,
-        "execute": execute,
-        "arrival": arrival_rate,
-        "max_ticks": max_ticks,
-    }
-    overrides = {k: v for k, v in overrides.items() if v is not None}
-    if flush_after_ticks is not _UNSET:
-        overrides["flush_after_ticks"] = flush_after_ticks
-    if overrides:
-        cfg = cfg.with_(**overrides)
 
     plan = plan_key = plan_refs = None
     if plan_cache is not None:
@@ -1664,8 +1563,6 @@ def serve_frames(
     if plan.replications:
         graph = plan.graph
         params = replicate_params(params, plan.replications)
-    if rate_matched:
-        cfg = cfg.with_(kernel_plan=plan.kernel_plan(batch=cfg.microbatch))
     engine = CNNStreamEngine(graph, params, plan, cfg)
     if tr is not None:
         # pipelines built by this call: new cache entries, or every

@@ -1,16 +1,15 @@
 """ServeConfig: the one serving surface.
 
-The serving knobs had sprawled: ``CNNStreamEngine.__init__`` took nine
-kwargs, ``run`` three more, and ``CNNApi.serve`` / ``FleetScheduler``
-each re-threaded overlapping subsets.  ``ServeConfig`` collects the
-whole surface in one frozen dataclass with three clearly separated
-groups:
+Every serving decision lives in one frozen dataclass with four
+separated groups:
 
 * **execution knobs** — how admitted micro-batches are computed
-  (``microbatch``, ``kernel_plan``, ``impls``, ``overrides``,
-  ``dtype``, ``check``, ``jit``, ``execute``); where Pallas kernels
-  run (compiled on a TPU, interpreted elsewhere) is the backend's
-  choice, not a knob;
+  (``microbatch``, ``kernel_plan``, ``dtype``, ``jit``, ``execute``,
+  ``link_quant``, ``pipeline_cache``); where Pallas kernels run
+  (compiled on a TPU, interpreted elsewhere) is the backend's choice,
+  not a knob.  The rate-matched path is ``kernel_plan``: the caller
+  lowers it once (``GraphPlan.kernel_plan(batch=microbatch)``) and
+  reuses it across calls;
 * **arrival source** — what traffic the run sees: a bare rate
   (frames/tick, the legacy constant process) or any
   ``serving.scenarios.ArrivalProcess`` (``arrival``), plus the run
@@ -24,11 +23,8 @@ groups:
 
 ``CNNStreamEngine(graph, params, plan, config)``, ``CNNApi.serve(...,
 config=...)``, ``serve_frames(..., config=...)``, and
-``FleetScheduler(pool, config=...)`` (with per-tenant overrides via
-``TenantWorkload.config``) all consume it uniformly.  The pre-existing
-kwargs keep working as a thin deprecated shim that builds the
-equivalent ``ServeConfig`` (``tests/serving/test_serve_config.py`` pins
-kwargs == config equivalence event-for-event).
+``FleetScheduler(pool, config=...)`` (with per-tenant configs via
+``TenantWorkload.config``) take it and nothing else.
 """
 
 from __future__ import annotations
@@ -51,10 +47,7 @@ class ServeConfig:
     # -- execution knobs ---------------------------------------------------
     microbatch: int = 1
     kernel_plan: Optional[Mapping[str, Any]] = None
-    impls: Optional[Mapping[str, Any]] = None
-    overrides: Optional[Mapping[str, Any]] = None
     dtype: Any = None
-    check: bool = True
     jit: bool = True
     # False = plan/validate only; True = run stages (host placement);
     # "devices" = one device per stage (the plan's recorded placement,

@@ -14,7 +14,7 @@ The rate calculus shows up twice (DESIGN.md §3):
 Implementation notes: fixed-size slot pool, greedy sampling, per-slot
 position counters, one jit'd decode for the whole pool (padded slots are
 masked by their own cache_len).  Works with every decoder-capable arch in
-the registry.  CNN families stream through the frame-level engine in
+``models.lm_api``.  CNN families stream through the frame-level engine in
 ``serving.cnn_stream`` instead (same admission calculus, frames for
 tokens).
 """
@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.models.registry import get_api
+from repro.models.lm_api import get_api
 
 
 @dataclasses.dataclass

@@ -22,6 +22,7 @@ from repro.fleet import (
     plan_pool,
 )
 from repro.models.registry import get_cnn_api
+from repro.serving import ServeConfig
 from repro.serving.cnn_stream import best_rate_frames
 
 TENANTS = (
@@ -46,7 +47,7 @@ def _workloads(pool, frac=F(1)):
 
 
 def test_two_families_zero_stalls_at_best_rate(pool):
-    sched = FleetScheduler(pool, execute=False)
+    sched = FleetScheduler(pool, config=ServeConfig(execute=False))
     rep = sched.serve(_workloads(pool, frac=F(1)))
     assert set(rep.reports) == {"alpha", "beta"}
     assert rep.all_stall_free
@@ -59,11 +60,12 @@ def test_two_families_zero_stalls_at_best_rate(pool):
 def test_fleet_matches_standalone(pool):
     """Tenants share the clock but not chips, so the fleet run of each
     tenant is event-for-event its standalone run."""
-    sched = FleetScheduler(pool, execute=False)
+    sched = FleetScheduler(pool, config=ServeConfig(execute=False))
     workloads = _workloads(pool, frac=F(1, 2))
     fleet = sched.serve(workloads)
     for w in workloads:
-        solo = sched._engine(w).run(arrival_rate=w.arrival_rate)
+        # the engine the fleet builds for w: arrival at w.arrival_rate
+        solo = sched._engine(w).run()
         got = fleet.reports[w.tenant]
         assert got.makespan_ticks == solo.makespan_ticks
         assert got.latency_ticks == solo.latency_ticks
@@ -74,7 +76,7 @@ def test_fleet_matches_standalone(pool):
 
 
 def test_chip_occupancy_over_fleet_makespan(pool):
-    sched = FleetScheduler(pool, execute=False)
+    sched = FleetScheduler(pool, config=ServeConfig(execute=False))
     rep = sched.serve(_workloads(pool))
     assert set(rep.chip_occupancy) == {c.name for c in CHIPS}
     for name in pool.spare_chips:
@@ -94,7 +96,7 @@ def test_execute_outputs_match_plain_apply():
     )
     pool = plan_pool(tenants, (Chip("big0", bram36=4096),) + chip_pool(3),
                      s_options=(1, 2))
-    sched = FleetScheduler(pool, execute=True)
+    sched = FleetScheduler(pool, config=ServeConfig(execute=True))
     sched.init_params("a", jax.random.PRNGKey(0))
     sched.init_params("b", jax.random.PRNGKey(1))
     rng = np.random.default_rng(0)
@@ -113,7 +115,7 @@ def test_execute_outputs_match_plain_apply():
 
 
 def test_scheduler_validation_errors(pool):
-    sched = FleetScheduler(pool, execute=False)
+    sched = FleetScheduler(pool, config=ServeConfig(execute=False))
     with pytest.raises(FleetError, match="no workloads"):
         sched.serve([])
     with pytest.raises(FleetError, match="unpooled tenant"):
@@ -121,5 +123,5 @@ def test_scheduler_validation_errors(pool):
     with pytest.raises(FleetError, match="duplicate workload"):
         sched.serve([TenantWorkload("alpha", 4), TenantWorkload("alpha", 4)])
     with pytest.raises(FleetError, match="no params"):
-        FleetScheduler(pool, execute=True).serve(
+        FleetScheduler(pool, config=ServeConfig(execute=True)).serve(
             [TenantWorkload("alpha", 4)])

@@ -15,18 +15,18 @@ from repro.configs.shapes import ShapeSuite
 from repro.core.flops import step_flops
 from repro.core.hlo_analysis import normalize_cost_analysis
 from repro.launch.train import adam_config_for, build_train_step
-from repro.models import registry as models
+from repro.models import lm_api
 from repro.optim import optimizers as opt
 
 
 def _measured_train_flops(cfg, shape):
-    api = models.get_api(cfg)
+    api = lm_api.get_api(cfg)
     adam = adam_config_for(cfg)
     params = api.init(cfg, jax.random.key(0))
     opt_state = opt.init(adam, params)
     batch = jax.tree.map(
         lambda s: jax.numpy.zeros(s.shape, s.dtype),
-        models.train_batch_specs(cfg, shape))
+        lm_api.train_batch_specs(cfg, shape))
     step = build_train_step(cfg, adam)
     compiled = jax.jit(step).lower(params, opt_state, batch).compile()
     return float(normalize_cost_analysis(compiled.cost_analysis())["flops"])

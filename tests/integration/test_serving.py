@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from repro.configs.registry import get_config, reduced
-from repro.models.registry import get_api
+from repro.models.lm_api import get_api
 from repro.serving.engine import Engine, Request
 
 

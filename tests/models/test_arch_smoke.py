@@ -8,7 +8,7 @@ import pytest
 
 from repro.configs.registry import ARCHS, get_config, reduced
 from repro.configs.shapes import ShapeSuite
-from repro.models.registry import get_api, train_batch_specs
+from repro.models.lm_api import get_api, train_batch_specs
 
 SMALL = ShapeSuite("smoke", seq_len=32, global_batch=2, kind="train")
 

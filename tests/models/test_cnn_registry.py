@@ -1,4 +1,10 @@
 """The unified CNN registry: one lookup + one apply machinery, 5 families."""
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -38,7 +44,7 @@ def test_family_end_to_end(family):
     assert arith == set(params)
 
 
-@pytest.mark.parametrize("family", ("mobilenet_v2", "resnet18"))
+@pytest.mark.parametrize("family", FAMILIES)
 def test_family_int8_roundtrip(family):
     api = get_cnn_api(family)
     cfg = api.make_config(input_hw=(32, 32), num_classes=10)
@@ -62,3 +68,26 @@ def test_activation_tags_follow_the_papers_datapaths():
     assert rg.spec("l1b1_conv2").activation == "none"
     assert rg.spec("l1b1_add").activation == "relu"
     assert rg.spec("fc").activation == "none"
+
+
+def test_cnn_front_door_imports_no_language_model_code():
+    """A fresh interpreter that imports the CNN registry and the serving
+    package loads none of the language-model stack."""
+    code = textwrap.dedent("""
+        import sys
+        import repro.models.registry, repro.serving
+        lm = [m for m in sys.modules
+              if m in ("repro.models.lm", "repro.models.encdec",
+                       "repro.models.hybrid", "repro.models.mamba",
+                       "repro.models.vlm", "repro.models.lm_api",
+                       "repro.nn.attention", "repro.nn.moe", "repro.nn.ssm",
+                       "repro.serving.engine")
+              or m == "repro.configs" or m.startswith("repro.configs.")]
+        print(sorted(lm))
+    """)
+    src = Path(__file__).resolve().parents[2] / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(src))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]"

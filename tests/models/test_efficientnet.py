@@ -127,10 +127,11 @@ def test_engine_with_a_cut_across_a_gate(case):
     assert edge.q * h * w == plan.timing["b5_scale"].q_in
     assert gate.bound_pixels >= 1
     eng = CNNStreamEngine(graph, params, plan, ServeConfig(
-        microbatch=2, kernel_plan=plan.kernel_plan(batch=2), dtype=cfg.dtype))
+        microbatch=2, kernel_plan=plan.kernel_plan(batch=2), dtype=cfg.dtype,
+        arrival=F(1)))
     eng.submit_all(x)
     with jax.default_matmul_precision("highest"):
-        eng.run(arrival_rate=F(1))
+        eng.run()
     assert _err(eng.outputs(), want) <= PALLAS_TOL
 
 

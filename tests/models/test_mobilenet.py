@@ -5,6 +5,9 @@ import numpy as np
 import pytest
 
 from repro.models import mobilenet as mn
+from repro.models.registry import get_cnn_api
+
+V2 = get_cnn_api("mobilenet_v2")
 
 
 @pytest.fixture(scope="module")
@@ -19,7 +22,7 @@ def v1_cfg():
 
 
 def test_chain_matches_params(small_cfg):
-    params = mn.init_params(small_cfg, jax.random.key(0))
+    params = V2.init(small_cfg, jax.random.key(0))
     chain = small_cfg.chain()
     named = {s.name for s in chain if s.kind not in ("gap", "pool", "add")}
     assert named == set(params)
@@ -29,9 +32,10 @@ def test_chain_matches_params(small_cfg):
 def test_forward_shapes_finite(version):
     cfg = mn.MobileNetConfig(version=version, input_hw=(32, 32),
                              num_classes=10)
-    params = mn.init_params(cfg, jax.random.key(0))
+    api = get_cnn_api(f"mobilenet_v{version}")
+    params = api.init(cfg, jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (2, 32, 32, 3))
-    logits = mn.apply(params, x, cfg)
+    logits = api.apply(params, x, cfg)
     assert logits.shape == (2, 10)
     assert bool(jnp.all(jnp.isfinite(logits)))
 
@@ -43,15 +47,15 @@ def test_kernel_backed_equals_xla(small_cfg):
     from repro.kernels.fcu_matmul import fcu_matmul
     from repro.kernels.kpu_conv import kpu_conv
 
-    params = mn.init_params(small_cfg, jax.random.key(0))
+    params = V2.init(small_cfg, jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (1, 32, 32, 3))
-    base = mn.apply(params, x, small_cfg)
+    base = V2.apply(params, x, small_cfg)
     impls = {
         "conv": lambda a, w, s: kpu_conv(a, w, stride=s),
         "dwconv": lambda a, w, s: dw_conv(a, w[:, :, 0, :], stride=s),
         "pointwise": lambda a, w: fcu_matmul(a, w),
     }
-    kern = mn.apply(params, x, small_cfg, conv_impls=impls)
+    kern = V2.apply(params, x, small_cfg, conv_impls=impls)
     np.testing.assert_allclose(np.asarray(kern), np.asarray(base),
                                rtol=2e-3, atol=2e-3)
 
@@ -59,11 +63,11 @@ def test_kernel_backed_equals_xla(small_cfg):
 def test_int8_quantization_close(small_cfg):
     """The paper's 8-bit datapath: int8 weights track float within the
     quantization budget and preserve top-1 agreement on most inputs."""
-    params = mn.init_params(small_cfg, jax.random.key(0))
+    params = V2.init(small_cfg, jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (8, 32, 32, 3))
-    ref = mn.apply(params, x, small_cfg)
-    qp, scales = mn.quantize_params(params)
-    got = mn.apply_int8(qp, scales, x, small_cfg)
+    ref = V2.apply(params, x, small_cfg)
+    qp, scales = V2.quantize(params)
+    got = V2.apply_int8(qp, scales, x, small_cfg)
     assert got.shape == ref.shape
     agree = float(jnp.mean((jnp.argmax(got, -1) == jnp.argmax(ref, -1))))
     assert agree >= 0.75, f"top-1 agreement {agree}"

@@ -8,7 +8,10 @@ from repro.core.flops import graph_macs, graph_weight_count
 from repro.core.graph import LayerGraph
 from repro.models import cnn
 from repro.models import resnet as rn
+from repro.models.registry import get_cnn_api
 from repro.models.topology import conv_spec
+
+R18 = get_cnn_api("resnet18")
 
 
 def test_resnet18_macs_match_hand_computed():
@@ -35,9 +38,9 @@ def test_apply_full_resolution_finite():
     (lax fallback), logits finite, and — because apply_graph runs with
     check=True — every layer's shape/MACs assert-matched the LayerGraph."""
     cfg = rn.ResNetConfig(depth=18)
-    params = rn.init_params(cfg, jax.random.key(0))
+    params = R18.init(cfg, jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (1, 224, 224, 3))
-    logits = rn.apply(params, x, cfg)
+    logits = R18.apply(params, x, cfg)
     assert logits.shape == (1, 1000)
     assert bool(jnp.all(jnp.isfinite(logits)))
 
@@ -46,22 +49,22 @@ def test_apply_shape_drift_raises():
     """The executable net cannot silently drift from the DSE graph: a
     wrong head width is caught by the per-node shape check."""
     cfg = rn.ResNetConfig(depth=18, input_hw=(32, 32), num_classes=10)
-    params = rn.init_params(cfg, jax.random.key(0))
+    params = R18.init(cfg, jax.random.key(0))
     params["fc"] = {
         "w": jnp.zeros((512, 9)),
         "b": jnp.zeros((9,)),
     }
     x = jnp.zeros((1, 32, 32, 3))
     with pytest.raises(cnn.GraphExecutionError, match="fc"):
-        rn.apply(params, x, cfg)
+        R18.apply(params, x, cfg)
 
 
 def test_apply_missing_params_raise():
     cfg = rn.ResNetConfig(depth=18, input_hw=(32, 32), num_classes=10)
-    params = rn.init_params(cfg, jax.random.key(0))
+    params = R18.init(cfg, jax.random.key(0))
     del params["l1b1_conv1"]
     with pytest.raises(cnn.GraphExecutionError, match="l1b1_conv1"):
-        rn.apply(params, jnp.zeros((1, 32, 32, 3)), cfg)
+        R18.apply(params, jnp.zeros((1, 32, 32, 3)), cfg)
 
 
 def _small_block_graph():
@@ -91,11 +94,11 @@ def test_int8_quantization_close():
     """The paper's 8-bit datapath on ResNet: int8 weights preserve top-1
     agreement on most random inputs."""
     cfg = rn.ResNetConfig(depth=18, input_hw=(32, 32), num_classes=10)
-    params = rn.init_params(cfg, jax.random.key(0))
+    params = R18.init(cfg, jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (8, 32, 32, 3))
-    ref = rn.apply(params, x, cfg)
-    qp, scales = rn.quantize_params(params)
-    got = rn.apply_int8(qp, scales, x, cfg)
+    ref = R18.apply(params, x, cfg)
+    qp, scales = R18.quantize(params)
+    got = R18.apply_int8(qp, scales, x, cfg)
     assert got.shape == ref.shape
     agree = float(jnp.mean(jnp.argmax(got, -1) == jnp.argmax(ref, -1)))
     assert agree >= 0.75, f"top-1 agreement {agree}"
@@ -104,6 +107,6 @@ def test_int8_quantization_close():
 def test_graph_params_cover_exactly_the_arith_nodes():
     cfg = rn.ResNetConfig(depth=34, input_hw=(64, 64), num_classes=10)
     g = cfg.graph()
-    params = rn.init_params(cfg, jax.random.key(0))
+    params = get_cnn_api("resnet34").init(cfg, jax.random.key(0))
     arith = {n for n in g.topo_order() if g.spec(n).kind in cnn.ARITH_KINDS}
     assert arith == set(params)

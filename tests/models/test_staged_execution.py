@@ -42,12 +42,14 @@ def small():
 # apply_staged == apply_graph
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family", ["resnet18", "mobilenet_v2"])
+@pytest.mark.parametrize("family", ["resnet18", "mobilenet_v2",
+                                    "efficientnet_b0"])
 @pytest.mark.parametrize("n_stages", [2, 3])
 def test_staged_equals_monolithic_fp32(family, n_stages):
     """Acceptance: staged fp32 output allclose to the monolithic pass for
-    ResNet-18 and MobileNet-v2 at S in {2, 3} — with each stage jitted
-    separately and the internal cut-tensor cross-check active."""
+    ResNet-18, MobileNet-v2 and EfficientNet-B0 at S in {2, 3} — with
+    each stage jitted separately and the internal cut-tensor cross-check
+    active."""
     api = get_cnn_api(family)
     cfg = api.make_config(input_hw=(32, 32), num_classes=10)
     params = api.init(cfg, jax.random.key(0))

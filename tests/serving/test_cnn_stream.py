@@ -23,6 +23,7 @@ from repro.core.graph import plan_graph
 from repro.core.schedule import simulate_graph
 from repro.models import cnn
 from repro.models.registry import get_cnn_api
+from repro.serving import ServeConfig
 from repro.serving.cnn_stream import (
     CNNStreamEngine,
     ServingError,
@@ -44,11 +45,11 @@ def _setup(family, n_stages, rate=F(3), hw=32):
 
 
 def _timing_run(plan, graph, *, n_frames, arrival, microbatch=1):
-    eng = CNNStreamEngine(graph, None, plan, microbatch=microbatch,
-                          execute=False)
+    eng = CNNStreamEngine(graph, None, plan, ServeConfig(
+        microbatch=microbatch, execute=False, arrival=arrival))
     for _ in range(n_frames):
         eng.submit(None)
-    return eng.run(arrival_rate=arrival)
+    return eng.run()
 
 
 # ---------------------------------------------------------------------------
@@ -183,9 +184,10 @@ def test_served_outputs_fp32_allclose(family):
     api, cfg, graph, plan = _setup(family, n_stages=2)
     params = api.init(cfg, jax.random.key(0))
     frames = np.asarray(jax.random.normal(jax.random.key(1), (5, 32, 32, 3)))
-    eng = CNNStreamEngine(graph, params, plan, microbatch=2, dtype=cfg.dtype)
+    eng = CNNStreamEngine(graph, params, plan, ServeConfig(
+        microbatch=2, dtype=cfg.dtype, arrival=2 * best_rate_frames(plan)))
     eng.submit_all(frames)
-    rep = eng.run(arrival_rate=2 * best_rate_frames(plan))
+    rep = eng.run()
     assert rep.completed == 5
     out = eng.outputs()
     ref = np.asarray(api.apply(params, frames, cfg))
@@ -200,10 +202,10 @@ def test_served_outputs_bit_exact_with_pinned_plan():
     params = api.init(cfg, jax.random.key(0))
     frames = np.asarray(jax.random.normal(jax.random.key(1), (4, 32, 32, 3)))
     kp = plan.kernel_plan(batch=2)
-    eng = CNNStreamEngine(graph, params, plan, microbatch=2, kernel_plan=kp,
-                          dtype=cfg.dtype)
+    eng = CNNStreamEngine(graph, params, plan, ServeConfig(
+        microbatch=2, kernel_plan=kp, dtype=cfg.dtype, arrival=F(1)))
     eng.submit_all(frames)
-    eng.run(arrival_rate=F(1))
+    eng.run()
     out = eng.outputs()
     ref = np.concatenate([
         np.asarray(api.apply(params, frames[i:i + 2], cfg, plan=kp))
@@ -222,10 +224,11 @@ def test_served_int8_bit_exact(family):
     frames = np.asarray(jax.random.normal(jax.random.key(1), (4, 32, 32, 3)))
     q, s = api.quantize(params)
     deq = cnn.dequantize_params(q, s, cfg.dtype)
-    eng = CNNStreamEngine(graph, deq, plan, microbatch=2, dtype=cfg.dtype,
-                          jit=False)
+    eng = CNNStreamEngine(graph, deq, plan, ServeConfig(
+        microbatch=2, dtype=cfg.dtype, jit=False,
+        arrival=2 * best_rate_frames(plan)))
     eng.submit_all(frames)
-    eng.run(arrival_rate=2 * best_rate_frames(plan))
+    eng.run()
     out = eng.outputs()
     ref = np.concatenate([
         np.asarray(api.apply_int8(q, s, frames[i:i + 2], cfg))
@@ -240,11 +243,12 @@ def test_rid_tracking_under_out_of_order_submission():
     api, cfg, graph, plan = _setup("mobilenet_v2", n_stages=2)
     params = api.init(cfg, jax.random.key(0))
     frames = np.asarray(jax.random.normal(jax.random.key(1), (4, 32, 32, 3)))
-    eng = CNNStreamEngine(graph, params, plan, microbatch=3, dtype=cfg.dtype)
+    eng = CNNStreamEngine(graph, params, plan, ServeConfig(
+        microbatch=3, dtype=cfg.dtype, arrival=F(1)))
     order = [2, 0, 3, 1]
     for rid in order:
         eng.submit(frames[rid], rid=rid)
-    eng.run(arrival_rate=F(1))
+    eng.run()
     out = eng.outputs()  # stacked in rid order
     ref = np.asarray(api.apply(params, frames, cfg))
     assert np.allclose(out, ref, rtol=1e-5, atol=1e-5)
@@ -258,20 +262,20 @@ def test_requires_stage_partition():
     _, cfg, graph, _ = _setup("mobilenet_v2", n_stages=1)
     unstaged = plan_graph(graph, F(3))  # no n_stages
     with pytest.raises(ServingError, match="stage partition"):
-        CNNStreamEngine(graph, None, unstaged, execute=False)
+        CNNStreamEngine(graph, None, unstaged, ServeConfig(execute=False))
 
 
 def test_rejects_mismatched_pin():
     _, cfg, graph, plan = _setup("mobilenet_v2", n_stages=2)
     kp = plan.kernel_plan(batch=4)
     with pytest.raises(ServingError, match="pinned to batch"):
-        CNNStreamEngine(graph, None, plan, microbatch=2, kernel_plan=kp,
-                        execute=False)
+        CNNStreamEngine(graph, None, plan, ServeConfig(
+            microbatch=2, kernel_plan=kp, execute=False))
 
 
 def test_rejects_empty_run():
     _, cfg, graph, plan = _setup("mobilenet_v2", n_stages=2)
-    eng = CNNStreamEngine(graph, None, plan, execute=False)
+    eng = CNNStreamEngine(graph, None, plan, ServeConfig(execute=False))
     with pytest.raises(ServingError, match="no frames"):
         eng.run()
 
@@ -280,7 +284,7 @@ def test_lm_engine_routes_cnn_configs_here():
     """The token-stream Engine names this engine when handed a CNN
     config (which carries no .family — the structural check must fire
     before any attribute access)."""
-    from repro.serving import Engine
+    from repro.serving.engine import Engine
 
     cfg = get_cnn_api("resnet18").make_config(input_hw=(32, 32),
                                               num_classes=10)
@@ -290,7 +294,7 @@ def test_lm_engine_routes_cnn_configs_here():
 
 def test_timing_only_has_no_outputs():
     _, cfg, graph, plan = _setup("mobilenet_v2", n_stages=2)
-    eng = CNNStreamEngine(graph, None, plan, execute=False)
+    eng = CNNStreamEngine(graph, None, plan, ServeConfig(execute=False))
     eng.submit(None)
     eng.run()
     with pytest.raises(ServingError, match="execute=False"):
@@ -309,11 +313,12 @@ def test_flush_after_ticks_bounds_straggler_latency():
     mb, n, arrival = 4, 6, F(1, 8)  # one frame every 8 ticks
 
     def run(flush):
-        eng = CNNStreamEngine(graph, None, plan, microbatch=mb,
-                              execute=False)
+        eng = CNNStreamEngine(graph, None, plan, ServeConfig(
+            microbatch=mb, execute=False, arrival=arrival,
+            flush_after_ticks=flush))
         for _ in range(n):
             eng.submit(None)
-        return eng.run(arrival_rate=arrival, flush_after_ticks=flush)
+        return eng.run()
 
     held = run(None)
     bounded = run(F(2))
@@ -334,11 +339,11 @@ def test_flush_none_is_event_identical_to_legacy_run():
     _, cfg, graph, plan = _setup("resnet18", n_stages=3)
 
     def run(**kw):
-        eng = CNNStreamEngine(graph, None, plan, microbatch=4,
-                              execute=False)
+        eng = CNNStreamEngine(graph, None, plan, ServeConfig(
+            microbatch=4, execute=False, arrival=F(1, 3), **kw))
         for _ in range(12):
             eng.submit(None)
-        return eng.run(arrival_rate=F(1, 3), **kw)
+        return eng.run()
 
     a, b = run(), run(flush_after_ticks=None)
     assert a.makespan_ticks == b.makespan_ticks
@@ -348,17 +353,20 @@ def test_flush_none_is_event_identical_to_legacy_run():
 
 def test_flush_zero_serves_singleton_batches():
     _, cfg, graph, plan = _setup("mobilenet_v2", n_stages=2)
-    eng = CNNStreamEngine(graph, None, plan, microbatch=4, execute=False)
+    eng = CNNStreamEngine(graph, None, plan, ServeConfig(
+        microbatch=4, execute=False, arrival=F(1, 4),
+        flush_after_ticks=F(0)))
     for _ in range(5):
         eng.submit(None)
-    rep = eng.run(arrival_rate=F(1, 4), flush_after_ticks=F(0))
+    rep = eng.run()
     assert rep.stages[0].batches_served == 5  # nothing ever waits
     assert rep.completed == 5
 
 
 def test_flush_rejects_negative():
     _, cfg, graph, plan = _setup("mobilenet_v2", n_stages=2)
-    eng = CNNStreamEngine(graph, None, plan, execute=False)
+    eng = CNNStreamEngine(graph, None, plan, ServeConfig(
+        execute=False, flush_after_ticks=F(-1)))
     eng.submit(None)
     with pytest.raises(ServingError, match="flush_after_ticks"):
-        eng.run(flush_after_ticks=F(-1))
+        eng.run()

@@ -1,8 +1,6 @@
-"""ServeConfig (serving/config.py): one surface, two calling styles.
+"""ServeConfig (serving/config.py): the one serving surface.
 
-Pins the satellite contract: the deprecated kwargs build exactly the
-config they claim to (event-for-event identical runs), the config is
-frozen, mixing the styles is an error, and ``flush_after_ticks`` now
+Pins the contract: the config is frozen, and ``flush_after_ticks``
 threads through every front door (engine, ``CNNApi.serve``,
 ``FleetScheduler`` — including per-tenant ``TenantWorkload.config``
 with its own overload policy).
@@ -23,11 +21,7 @@ from repro.fleet import (
 )
 from repro.models.registry import get_cnn_api
 from repro.serving import ServeConfig, ShedPolicy
-from repro.serving.cnn_stream import (
-    CNNStreamEngine,
-    ServingError,
-    best_rate_frames,
-)
+from repro.serving.cnn_stream import ServingError, best_rate_frames
 from repro.serving.scenarios import adversarial
 
 
@@ -38,58 +32,6 @@ def _setup(family="resnet18", n_stages=2, rate=F(3)):
     return api, cfg, graph, plan_graph(graph, rate, n_stages=n_stages)
 
 
-def _report_key(rep):
-    """Everything event-order-dependent a run produces."""
-    return (
-        rep.makespan_ticks,
-        rep.latency_ticks,
-        rep.service_latency_ticks,
-        rep.queue_events,
-        rep.request_queue_peak,
-        [(s.busy_cycles, s.stall_cycles, s.batches_served) for s in rep.stages],
-    )
-
-
-def test_kwargs_shim_equals_config_event_for_event():
-    """The deprecated engine kwargs + run() overrides produce the exact
-    run the equivalent ServeConfig does."""
-    _, _, graph, plan = _setup()
-    with pytest.warns(DeprecationWarning):
-        legacy = CNNStreamEngine(graph, None, plan, microbatch=3,
-                                 execute=False)
-    for _ in range(17):
-        legacy.submit(None)
-    legacy_rep = legacy.run(arrival_rate=F(2), flush_after_ticks=F(3))
-
-    cfg = ServeConfig(microbatch=3, execute=False, arrival=F(2),
-                      flush_after_ticks=F(3))
-    modern = CNNStreamEngine(graph, None, plan, cfg)
-    for _ in range(17):
-        modern.submit(None)
-    modern_rep = modern.run()
-
-    assert _report_key(legacy_rep) == _report_key(modern_rep)
-    # the shim builds exactly the config the init kwargs name (run()
-    # overrides stay per-run, they do not mutate the engine config)
-    assert legacy.config == ServeConfig(microbatch=3, execute=False)
-
-
-def test_run_kwargs_override_config():
-    """Per-run kwargs beat the engine config (the PR 6 calling style)."""
-    _, _, graph, plan = _setup()
-    cfg = ServeConfig(execute=False, arrival=F(1, 2))
-    a = CNNStreamEngine(graph, None, plan, cfg)
-    b = CNNStreamEngine(graph, None, plan, cfg)
-    for eng in (a, b):
-        for _ in range(8):
-            eng.submit(None)
-    rep_override = a.run(arrival_rate=F(2))
-    rep_config = b.run()
-    assert rep_override.arrival_rate == F(2)
-    assert rep_config.arrival_rate == F(1, 2)
-    assert rep_override.makespan_ticks < rep_config.makespan_ticks
-
-
 def test_config_is_frozen_and_with_copies():
     cfg = ServeConfig(microbatch=2)
     with pytest.raises(dataclasses.FrozenInstanceError):
@@ -97,12 +39,6 @@ def test_config_is_frozen_and_with_copies():
     cfg2 = cfg.with_(microbatch=4, arrival=F(3))
     assert cfg2.microbatch == 4 and cfg2.arrival == F(3)
     assert cfg.microbatch == 2  # original untouched
-
-
-def test_mixing_config_and_kwargs_is_an_error():
-    _, _, graph, plan = _setup()
-    with pytest.raises(ServingError):
-        CNNStreamEngine(graph, None, plan, ServeConfig(), microbatch=2)
 
 
 def test_api_serve_threads_config_and_flush():
@@ -144,13 +80,6 @@ def test_fleet_scheduler_takes_config(pool):
     # unified schema: per-tenant summaries + canonical rows
     rows = dict(rep.to_rows())
     assert "alpha/served" in rows and "beta/latency" in rows
-
-
-def test_fleet_legacy_kwargs_warn_and_mixing_raises(pool):
-    with pytest.warns(DeprecationWarning):
-        FleetScheduler(pool, execute=False)
-    with pytest.raises(ServingError):
-        FleetScheduler(pool, config=ServeConfig(), execute=False)
 
 
 def test_fleet_per_tenant_policy(pool):
