@@ -117,12 +117,28 @@ def fit_bm(m: int, want: int, sublanes: int = 8) -> int:
     return max(cands) if cands else m
 
 
+def sublane_tile(dtype_bytes: int, spec: TPUSpec) -> int:
+    """Rows of one (sublane, 128-lane) VMEM tile: 8 of 4-byte values;
+    packed dtypes hold 16 or 32."""
+    return spec.sublanes * max(1, 4 // dtype_bytes)
+
+
+def mxu_width(wo: int, dtype_bytes: int, spec: TPUSpec) -> int:
+    """Output columns a whole-frame conv streams per row through the MXU:
+    ``wo`` rounded up to the sublane tile.  Merging the rows of a
+    ``[rows, W, C]`` tap window into MXU rows is then tile-aligned; at a
+    width that is not a tile multiple (ResNet's 7, 14, 28) Mosaic
+    relayouts every window instead (docs/kernels.md)."""
+    sub = sublane_tile(dtype_bytes, spec)
+    return -(-wo // sub) * sub
+
+
 def padded_bytes(shape: Sequence[int], dtype_bytes: int, spec: TPUSpec) -> int:
     """VMEM bytes an array of ``shape`` occupies: the minor dim rounds up
-    to the 128 lanes, the second-minor to the sublane tile (8 rows of
-    4-byte values; packed dtypes hold 16 or 32)."""
+    to the 128 lanes, the second-minor to the sublane tile
+    (``sublane_tile``)."""
     *lead, rows, cols = (1, *shape) if len(shape) == 1 else tuple(shape)
-    sub = spec.sublanes * max(1, 4 // dtype_bytes)
+    sub = sublane_tile(dtype_bytes, spec)
     n = -(-rows // sub) * sub * -(-cols // spec.lanes) * spec.lanes
     for d in lead:
         n *= d
@@ -157,6 +173,11 @@ class ConvGeometry:
     stride never reaches the kernel's loads.  Only the phases some tap
     reads are kept (a 1x1 stride-2 conv keeps one of four): skipped
     windows are never materialized.
+
+    ``wo_mxu`` >= ``out_hw[1]`` is the width of every tap window
+    (``mxu_width``): the right padding adds zero columns until each
+    window of that width lies inside its phase.  Outputs past
+    ``out_hw[1]`` are computed and discarded.
     """
 
     out_hw: Tuple[int, int]
@@ -165,6 +186,7 @@ class ConvGeometry:
     stride: int
     phases: Tuple[Tuple[int, int], ...]
     phase_hw: Tuple[int, int]
+    wo_mxu: int
 
     def tap(self, dy: int, dx: int) -> Tuple[int, int, int]:
         """(phase index, row offset, column offset) of tap (dy, dx)."""
@@ -173,13 +195,20 @@ class ConvGeometry:
 
 
 def conv_geometry(
-    in_hw: Tuple[int, int], kernel: Tuple[int, int], stride: int
+    in_hw: Tuple[int, int],
+    kernel: Tuple[int, int],
+    stride: int,
+    wo_mxu: Optional[int] = None,
 ) -> ConvGeometry:
+    """``wo_mxu`` (the true output width without it) widens every tap
+    window, and the phases with it, by ``wo_mxu - Wo`` columns."""
     (h, w), (kh, kw), s = in_hw, kernel, stride
     ho, (pt, pb) = same_pads(h, kh, s)
     wo, (pl, pr) = same_pads(w, kw, s)
+    wo_mxu = wo if wo_mxu is None else wo_mxu
+    assert wo_mxu >= wo, f"window width {wo_mxu} < output width {wo}"
     hq = -(-(h + pt + pb) // s)
-    wq = -(-(w + pl + pr) // s)
+    wq = -(-(w + pl + pr) // s) + wo_mxu - wo
     phases = sorted({(dy % s, dx % s) for dy in range(kh) for dx in range(kw)})
     return ConvGeometry(
         out_hw=(ho, wo),
@@ -187,6 +216,7 @@ def conv_geometry(
         stride=s,
         phases=tuple(phases),
         phase_hw=(hq, wq),
+        wo_mxu=wo_mxu,
     )
 
 
@@ -204,15 +234,16 @@ def conv_frame_vmem_bytes(
     """Working set of one whole-frame conv grid step (kpu_conv, or
     dw_conv with ``depthwise``) holding ``frames`` frames: the
     phase-split input, weight and output blocks double-buffered, the f32
-    accumulator and one f32 tap product, and one loaded tap window.
-    Every term but the weight block scales with ``frames``."""
+    accumulator and one f32 tap product, and one loaded tap window, the
+    last three ``geo.wo_mxu`` columns wide.  Every term but the weight
+    block scales with ``frames``."""
     (hq, wq), (ho, wo), (kh, kw) = geo.phase_hw, geo.out_hw, kernel
     x = padded_bytes((len(geo.phases), hq, wq, bci), dtype_bytes, spec)
     w_shape = (kh, kw, bci) if depthwise else (kh, kw, bci, bco)
     w = padded_bytes(w_shape, dtype_bytes, spec)
     o = padded_bytes((ho, wo, bco), dtype_bytes, spec)
-    acc = padded_bytes((ho, wo, bco), 4, spec)
-    win = padded_bytes((ho, wo, bci), dtype_bytes, spec)
+    acc = padded_bytes((ho, geo.wo_mxu, bco), 4, spec)
+    win = padded_bytes((ho, geo.wo_mxu, bci), dtype_bytes, spec)
     return 2 * (frames * (x + o) + w) + frames * (2 * acc + win)
 
 

@@ -19,6 +19,11 @@ is the schedule:
     weight tap: ``nb`` > 1 where the data rate has fallen (the small
     late-layer frames), so each weight block is fetched once per ``nb``
     frames;
+  * sublane-aligned rows: each row streams ``wo_mxu`` columns, Wo
+    rounded up to the sublane tile (``core.tpu_tiles.mxu_width``), so
+    the window's rows merge into MXU rows without a relayout; the
+    phases carry that many more zero columns on the right, and the
+    outputs past Wo are dropped at the flush;
   * stride pruning (§II-E): the padded frame arrives split into its
     stride phases (``kernels.common.phase_split``), so every tap reads
     one contiguous window of one phase — only surviving-phase windows
@@ -33,6 +38,7 @@ the pad-select signals of the FPGA become plain zero padding here.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,8 +52,8 @@ def _kpu_kernel(x_ref, w_ref, o_ref, acc_ref, *, taps: tuple, grid_ci: int):
     """Grid: (n/nb, co_blocks, ci_blocks).  Blocks:
     x: [nb, P, Hq, Wq, bci] (nb padded frames split into P stride
     phases), w: [kh, kw, bci, bco], o: [nb, Ho, Wo, bco], acc:
-    [nb*Ho, Wo, bco].  ``taps`` gives per (dy, dx) the (phase, row, col)
-    its window starts at."""
+    [nb*Ho, Wo_mxu, bco].  ``taps`` gives per (dy, dx) the (phase, row,
+    col) its window starts at."""
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -55,15 +61,18 @@ def _kpu_kernel(x_ref, w_ref, o_ref, acc_ref, *, taps: tuple, grid_ci: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     nb, ho, wo, bco = o_ref.shape
+    wo_mxu = acc_ref.shape[1]
     bci = x_ref.shape[-1]
     # weight-stationary tap loop (static unroll = the C configurations);
     # the nb frames' windows merge into the leading (row) axis for free,
-    # so one pass streams all nb * Ho * Wo positions (Mosaic collapses
-    # (rows, Wo) for the MXU: a relayout unless Wo is a multiple of 8)
+    # so one pass streams all nb * Ho * Wo_mxu positions.  Mosaic
+    # collapses (rows, Wo_mxu) for the MXU, which is tile-aligned because
+    # Wo_mxu is a sublane-tile multiple: at the true Wo (7, 14, 28) it
+    # would relayout every window.
     for (dy, dx), (p, oy, ox) in taps:
-        win = x_ref[:, p, oy : oy + ho, ox : ox + wo, :]  # [nb, Ho, Wo, bci]
+        win = x_ref[:, p, oy : oy + ho, ox : ox + wo_mxu, :]
         acc_ref[...] += jax.lax.dot_general(
-            win.reshape(nb * ho, wo, bci),
+            win.reshape(nb * ho, wo_mxu, bci),
             w_ref[dy, dx],  # [bci, bco]
             dimension_numbers=(((2,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -71,7 +80,9 @@ def _kpu_kernel(x_ref, w_ref, o_ref, acc_ref, *, taps: tuple, grid_ci: int):
 
     @pl.when(ci == grid_ci - 1)
     def _flush():
-        o_ref[...] = acc_ref[...].reshape(nb, ho, wo, bco).astype(o_ref.dtype)
+        # only the true Wo columns leave the kernel
+        acc = acc_ref[:, :wo, :] if wo_mxu != wo else acc_ref[...]
+        o_ref[...] = acc.reshape(nb, ho, wo, bco).astype(o_ref.dtype)
 
 
 def kpu_conv_p(
@@ -83,11 +94,15 @@ def kpu_conv_p(
     bci: int,
     bco: int,
     frames: int = 1,
+    wo_mxu: Optional[int] = None,
     out_dtype=None,
     node=None,
 ) -> jax.Array:
     """``frames`` (nb) is the frames each grid step holds: its weight
-    blocks are fetched once per nb frames."""
+    blocks are fetched once per nb frames.  ``wo_mxu`` (Wo without it) is
+    the columns each tap window streams, of which the first Wo are
+    output: the phases must be wide enough for every tap's window
+    (``core.tpu_tiles.conv_geometry``)."""
     n, n_ph, hq, wq, d_in = x_phases.shape
     kh, kw, d_in2, d_out = w.shape
     assert d_in == d_in2
@@ -96,6 +111,10 @@ def kpu_conv_p(
     )
     assert n % frames == 0, f"frames={frames} must divide the batch {n}"
     ho, wo = out_hw
+    wo_mxu = wo_mxu or wo
+    assert all(ox + wo_mxu <= wq for _, (_, _, ox) in taps), (
+        f"a {wo_mxu}-column tap window overruns the {wq}-column phases"
+    )
     nb = frames
     grid = (n // nb, d_out // bco, d_in // bci)
     out_dtype = out_dtype or x_phases.dtype
@@ -113,5 +132,5 @@ def kpu_conv_p(
             (nb, ho, wo, bco), lambda nn, co, ci: (nn, 0, 0, co)
         ),
         out_shape=jax.ShapeDtypeStruct((n, ho, wo, d_out), out_dtype),
-        scratch_shapes=[pltpu.VMEM((nb * ho, wo, bco), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((nb * ho, wo_mxu, bco), jnp.float32)],
     )(x_phases, w)

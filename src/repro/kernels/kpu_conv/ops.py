@@ -12,7 +12,8 @@ Two ways to pick the (bci, bco) channel tiles:
 
 Two routes run a conv: the whole-frame KPU kernel on the phase-split
 padded input, holding as many frames per grid step as the pixel tile
-``bm`` covers (``block_frames``), or — when that frame cannot fit VMEM
+``bm`` covers (``block_frames``), its tap windows widened to the
+sublane tile (``frame_geometry``), or — when that frame cannot fit VMEM
 (the lane-sparse 3-channel stems; ``TileChoice.im2col``, or the same
 budget check on the uniform path) — im2col patches through the FCU
 matmul kernel.
@@ -34,12 +35,22 @@ from repro.core.tpu_tiles import (
     conv_frame_vmem_bytes,
     conv_geometry,
     fit_bm,
+    mxu_width,
     select_tile,
     vmem_budget,
 )
 from repro.kernels.common import conv_taps, phase_split
 from repro.kernels.fcu_matmul.fcu_matmul import fcu_matmul_p
 from .kpu_conv import kpu_conv_p
+
+
+def frame_geometry(in_hw, kernel, stride: int, dtype_bytes: int) -> ConvGeometry:
+    """The whole-frame route's geometry: ``conv_geometry`` with every tap
+    window ``mxu_width`` columns wide (the output width itself where that
+    is a sublane-tile multiple)."""
+    wo = conv_geometry(in_hw, kernel, stride).out_hw[1]
+    wo_mxu = mxu_width(wo, dtype_bytes, target_spec())
+    return conv_geometry(in_hw, kernel, stride, wo_mxu)
 
 
 def _im2col(x: jax.Array, geo: ConvGeometry, kernel) -> jax.Array:
@@ -82,7 +93,7 @@ def kpu_conv(
     node in the kernel's name."""
     n, h, wdt, d_in = x.shape
     kh, kw, _, d_out = w.shape
-    geo = conv_geometry((h, wdt), (kh, kw), stride)
+    geo = frame_geometry((h, wdt), (kh, kw), stride, x.dtype.itemsize)
     ho, wo = geo.out_hw
     if bci is None or bco is None:
         t = select_tile(
@@ -97,6 +108,7 @@ def kpu_conv(
         )
         im2col = frame > vmem_budget(spec)
     if im2col:
+        geo = conv_geometry((h, wdt), (kh, kw), stride)
         m, k = n * ho * wo, kh * kw * d_in
         y = fcu_matmul_p(
             _im2col(x, geo, (kh, kw)),
@@ -115,6 +127,7 @@ def kpu_conv(
         bci=bci,
         bco=bco,
         frames=block_frames(n, geo, (kh, kw), bci, bco, bm, x.dtype.itemsize),
+        wo_mxu=geo.wo_mxu,
         node=node,
     )
 
@@ -163,7 +176,8 @@ def conv_impl(
     the uniform search.  ``record(bk=..., bn=..., d_in=..., d_out=...)``
     is called with the executed tile at trace time — plus ``bm`` and
     ``m`` on the im2col route, whose pixel tile the plan pins like the
-    FCU kinds', and ``frames`` (per grid step) on the whole-frame route.
+    FCU kinds', and ``frames`` (per grid step) and ``wo_mxu`` (columns
+    each tap window streams) on the whole-frame route.
     ``node`` names the graph node in the kernel's name.
     """
     def impl(x, w, stride):
@@ -173,7 +187,7 @@ def conv_impl(
                 record(bk=None, bn=None, d_in=x.shape[-1], d_out=w.shape[-1])
             return y
         n, h, wdt, _ = x.shape
-        geo = conv_geometry((h, wdt), w.shape[:2], stride)
+        geo = frame_geometry((h, wdt), w.shape[:2], stride, x.dtype.itemsize)
         if tile.im2col:
             m = n * geo.out_hw[0] * geo.out_hw[1]
             bm = fit_bm(m, tile.bm)
@@ -183,7 +197,8 @@ def conv_impl(
             extra = {
                 "frames": block_frames(
                     n, geo, w.shape[:2], tile.bk, tile.bn, bm, x.dtype.itemsize
-                )
+                ),
+                "wo_mxu": geo.wo_mxu,
             }
         y = kpu_conv(
             x,

@@ -52,9 +52,10 @@ import numpy as np
 
 from repro.core.dse import NON_ARITH_KINDS
 from repro.core.graph import JOIN_KINDS, ImplPlan, LayerGraph
+from repro.core.hw_specs import target_spec
 from repro.core.rate import LayerSpec
 from repro.core.stage_partition import resolve_link_dtype
-from repro.core.tpu_tiles import KERNEL_KINDS, conv_block_frames
+from repro.core.tpu_tiles import KERNEL_KINDS, conv_block_frames, mxu_width
 from repro.nn.quant import dequantize_link, fake_quant_link, quantize_link
 
 Impl = Callable[..., jax.Array]
@@ -371,6 +372,7 @@ def _check_planned_tile(
     spec: LayerSpec,
     node_plan: Optional[ImplPlan],
     got: Optional[Dict[str, int]],
+    dtype_bytes: int,
 ) -> None:
     """Assert one node's *executed* tile equals its ``ImplPlan`` tile.
 
@@ -385,7 +387,9 @@ def _check_planned_tile(
     the micro-batcher promised that shape, so a mismatch is a serving
     bug, not a legal re-fit; and a whole-frame conv must hold the frames
     per grid step that the planned bm covers on that batch
-    (``conv_block_frames``): the plan's multi-pixel P, executed.
+    (``conv_block_frames``): the plan's multi-pixel P, executed.  A
+    whole-frame conv must also stream the row width ``mxu_width`` gives
+    its output width at the input's ``dtype_bytes`` (``wo_mxu``).
     """
     if node_plan is None:
         raise GraphExecutionError(f"{spec.name}: node missing from the kernel plan")
@@ -440,6 +444,14 @@ def _check_planned_tile(
             raise GraphExecutionError(
                 f"{spec.name}: executed {got.get('frames')} frames per grid "
                 f"step != {want} that the batch-pinned plan bm={t.bm} covers"
+            )
+    if spec.kind == "conv" and not t.im2col:
+        want = mxu_width(spec.out_hw[1], dtype_bytes, target_spec())
+        if got.get("wo_mxu") != want:
+            raise GraphExecutionError(
+                f"{spec.name}: executed rows of {got.get('wo_mxu')} columns "
+                f"!= wo_mxu={want}, Wo={spec.out_hw[1]} aligned to the "
+                f"sublane tile"
             )
 
 
@@ -628,7 +640,10 @@ def _run_nodes(
             if name in overridden and executed.get(name) is None:
                 pass  # user-supplied impl; no record => no tile claim
             else:
-                _check_planned_tile(spec, plan.get(name), executed.get(name))
+                _check_planned_tile(
+                    spec, plan.get(name), executed.get(name),
+                    operands[0].dtype.itemsize,
+                )
         values[name] = y
 
 

@@ -5,9 +5,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.tpu_tiles import conv_geometry
+from repro.core.tpu_tiles import TileChoice, conv_geometry
 from repro.kernels.common import conv_taps, phase_split
-from repro.kernels.kpu_conv import kpu_conv, kpu_conv_ref
+from repro.kernels.kpu_conv import conv_impl, kpu_conv, kpu_conv_ref
 from repro.kernels.kpu_conv.kpu_conv import kpu_conv_p
 from repro.kernels.kpu_conv.ops import block_frames
 
@@ -126,3 +126,35 @@ def test_kpu_frames_per_step_match_one_frame(hw, stride, nb):
     wrapped = kpu_conv(x, w, stride=stride, bci=128, bco=128,
                        bm=nb * ho * wo, im2col=False)
     np.testing.assert_array_equal(np.asarray(wrapped), np.asarray(got))
+
+
+# output width -> the row width the MXU streams at float32: the width
+# rounded up to the 8-row sublane tile (8 itself is the aligned control)
+WO_MXU = {7: 8, 8: 8, 14: 16, 28: 32}
+
+
+@pytest.mark.parametrize(
+    "k,stride", [(3, 1), (3, 2), (1, 2)], ids=["3x3-s1", "3x3-s2", "1x1-s2"]
+)
+@pytest.mark.parametrize("wo", sorted(WO_MXU))
+def test_kpu_aligned_rows_keep_the_true_output_width(wo, k, stride):
+    """An output width that is not a sublane multiple streams through the
+    MXU at the aligned width; the result keeps the true width, matches
+    the oracle, and is the same bits at every frames-per-step nb."""
+    n, cin, cout = 8, 16, 16
+    k1, k2 = jax.random.split(jax.random.key(7))
+    x = _rand(k1, (n, wo * stride, wo * stride, cin))
+    w = _rand(k2, (k, k, cin, cout))
+    want = kpu_conv_ref(x, w, stride=stride)
+    runs = {}
+    for nb in (1, 2, 8):
+        tile = TileChoice(bm=nb * wo * wo, bk=cin, bn=cout, grid_m=1,
+                          grid_k=1, grid_n=1, vmem_bytes=0, mxu_aligned=False)
+        rec = {}
+        y = conv_impl(tile=tile, record=lambda **t: rec.update(t))(x, w, stride)
+        assert y.shape == (n, wo, wo, cout)
+        assert (rec["frames"], rec["wo_mxu"]) == (nb, WO_MXU[wo])
+        np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-4)
+        runs[nb] = np.asarray(y)
+    for nb in (2, 8):
+        np.testing.assert_array_equal(runs[nb], runs[1])

@@ -90,7 +90,8 @@ CASES = [
     ("resnet18", "l3b1_conv1", jnp.float32),  # 3x3/2, 28 -> 14
     ("resnet18", "l3b2_conv2", jnp.float32),  # 3x3/1 at 14x14x256
     ("resnet18", "l4b1_conv1", jnp.float32),  # 3x3/2, 14 -> 7
-    ("resnet18", "l4b2_conv2", jnp.float32),  # 3x3/1 at 7x7x512
+    ("resnet18", "l4b2_conv1", jnp.float32),  # 3x3/1 at 7x7x512
+    ("resnet18", "l4b2_conv2", jnp.float32),
     ("resnet18", "fc", jnp.float32),  # 512 -> 1000 head
     ("mobilenet_v2", "conv1", jnp.float32),  # 3x3/2 stem: im2col
     ("mobilenet_v2", "b1_dw", jnp.float32),  # dw 3x3/1 at 112x112x32
@@ -117,6 +118,20 @@ FRAMES = {
     "l3b1_conv1": 2,
     "l3b2_conv2": 2,
     "l4b1_conv1": 8,
+    "l4b2_conv1": 8,
+    "l4b2_conv2": 8,
+}
+
+# columns each tap window streams through the MXU: the output width
+# rounded up to the 8 float32 sublanes (56 at layer 1 is aligned already)
+WO_MXU = {
+    "l1b1_conv1": 56,
+    "l2b1_conv1": 32,
+    "l2b1_down": 32,
+    "l3b1_conv1": 16,
+    "l3b2_conv2": 16,
+    "l4b1_conv1": 8,
+    "l4b2_conv1": 8,
     "l4b2_conv2": 8,
 }
 
@@ -163,3 +178,5 @@ def test_served_kernel_compiles_for_v5e(
         ho, wo = spec.out_hw
         assert executed[node]["frames"] == FRAMES[node]
         assert FRAMES[node] == max(1, node_plan.tile.bm // (ho * wo))
+    if family == "resnet18" and node in WO_MXU:
+        assert executed[node]["wo_mxu"] == WO_MXU[node]

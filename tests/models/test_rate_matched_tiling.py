@@ -21,6 +21,9 @@ import pytest
 
 from repro.core import plan_graph
 from repro.core.dse import NON_ARITH_KINDS
+from repro.core.tpu_tiles import conv_geometry
+from repro.kernels.common import conv_taps, phase_split
+from repro.kernels.kpu_conv.kpu_conv import kpu_conv_p
 from repro.models import cnn
 from repro.models.registry import get_cnn_api
 
@@ -144,6 +147,35 @@ def test_frames_per_step_other_than_the_batch_pinned_plan_is_detected():
     with pytest.raises(cnn.GraphExecutionError, match=f"{victim}.*frames"):
         cnn.apply_graph(params, x, graph, impls=impls, plan=kp,
                         executed=executed)
+
+
+def test_row_width_other_than_the_sublane_rule_is_detected():
+    """A whole-frame conv must stream its output width rounded up to the
+    sublane tile: a kernel run at the true, unaligned width (and saying
+    so in its record) must trip the per-node check."""
+    api, cfg = _setup("resnet18")
+    graph = api.graph(cfg)
+    kp = api.plan(cfg, RATE)
+    params = api.init(cfg, jax.random.key(0))
+    x = jax.random.normal(jax.random.key(1), (1, 32, 32, 3))
+    victim = "l2b2_conv1"  # 4x4 output: rows of 4 stream as 8
+    executed = {}
+    cnn.apply_graph(params, x, graph, plan=kp, executed=executed)
+    assert executed[victim]["wo_mxu"] == 8
+    t = kp[victim].tile
+
+    def true_width(x, w, stride):
+        geo = conv_geometry(x.shape[1:3], w.shape[:2], stride)
+        executed[victim] = dict(bk=t.bk, bn=t.bn, d_in=x.shape[-1],
+                                d_out=w.shape[-1], frames=1,
+                                wo_mxu=geo.wo_mxu)
+        return kpu_conv_p(phase_split(x, geo), w,
+                          taps=conv_taps(geo, w.shape[:2]),
+                          out_hw=geo.out_hw, bci=t.bk, bco=t.bn)
+
+    with pytest.raises(cnn.GraphExecutionError, match=f"{victim}.*wo_mxu=8"):
+        cnn.apply_graph(params, x, graph, plan=kp,
+                        overrides={victim: true_width}, executed=executed)
 
 
 @pytest.mark.parametrize("family", FAMILIES)
