@@ -24,6 +24,11 @@ Semantics of an implementation (paper §II-B, Fig. 3):
   layer consumes  rate_capacity = P * j/h  features per clock (Eq. 6) and
   emits  P * (d_out*j)/(d_in*h)  (Eq. 5).  Continuous flow requires
   capacity >= demand r; utilization is their ratio.
+
+  A grouped conv (``LayerSpec.groups`` = G) is G such layers of
+  d_in/G -> d_out/G side by side: j | d_in/G, h | d_out/G, C =
+  h*(d_in/G)/j, and the layer absorbs P * G*j/h — the dense layer's
+  capacity from 1/G of its multipliers.
 """
 from __future__ import annotations
 
@@ -142,8 +147,11 @@ def surviving_phases(p: int, stride: int) -> int:
 
 def _h_domain(layer: LayerSpec) -> int:
     # §II-B: for depthwise, the channel multiplier replaces d_out as h's
-    # upper structure (each unit's outputs come from one input channel).
-    return layer.channel_multiplier if layer.kind == "dwconv" else layer.d_out
+    # upper structure (each unit's outputs come from one input channel);
+    # a grouped conv's unit multiplexes outputs of its own group only.
+    if layer.kind == "dwconv":
+        return layer.channel_multiplier
+    return layer.d_out // layer.groups
 
 
 def _units_per_phase(layer: LayerSpec, h: int) -> int:
@@ -209,12 +217,17 @@ def select_ours(
             capacity=Fraction(d_in * p_raw),
         )
 
+    # A grouped conv is G convs of d_in/G -> d_out/G side by side, each
+    # fed r/G of the features: a unit aggregates j <= d_in/G inputs of
+    # one group (a group's contraction is never split across groups),
+    # and the layer absorbs G * j/h features a clock.
+    groups, g_in = layer.groups, layer.group_in
     hd = _h_domain(layer)
-    hj = hj_set(d_in, hd, r_phase)
+    hj = hj_set(g_in, hd, r_phase / groups)
     if not hj:
         raise ValueError(
             f"{layer.name}: no viable (j,h) for per-phase rate {r_phase} "
-            f"(d_in={d_in}, h_domain={hd})"
+            f"(d_in={d_in}, groups={groups}, h_domain={hd})"
         )
     br = best_rate(hj)
     candidates = [(j, h) for (j, h) in hj if Fraction(j, h) == br]
@@ -231,12 +244,12 @@ def select_ours(
             h=h,
             p=p,
             p_raw=p_raw,
-            configs=max(1, (h * d_in) // j),
+            configs=max(1, (h * g_in) // j),
             units=units,
             mults=mults,
             scheme="ours",
             demand=r,
-            capacity=Fraction(j, h) * p_raw,
+            capacity=Fraction(j, h) * groups * p_raw,
         )
 
     if objective in ("resources", "pareto"):
@@ -308,18 +321,21 @@ def select_ref11(layer: LayerSpec, r: Fraction) -> LayerImpl:
         )
 
     if layer.kind in ("conv", "dwconv"):
-        c = min(math.ceil(d_in / r_phase), d_in * d_out)
-        cm = layer.channel_multiplier if layer.kind == "dwconv" else d_out
-        pairs = d_in * cm
-        units_per_phase = math.ceil(pairs / c)
+        # a grouped conv: the same derivation per group, G groups of KPUs
+        groups, g_in = layer.groups, layer.group_in
+        cm = _h_domain(layer)
+        c = min(math.ceil(d_in / r_phase), g_in * (d_out // groups))
+        pairs = g_in * cm  # (channel, kernel) pairs of one group
+        units_per_phase = groups * math.ceil(pairs / c)
         units = units_per_phase * p
         mults = units * layer.k_taps
         # Padding waste: the last KPU covers pairs - (units-1)*C < C pairs.
         covered = units_per_phase * c
+        pairs *= groups
         pad = Fraction(covered - pairs, covered) if covered > pairs else Fraction(0)
         # Effective (j,h) bookkeeping for reporting only.
-        j = min(d_in, units_per_phase)
-        h = max(1, cm // max(1, units_per_phase // max(1, min(d_in, units_per_phase))))
+        j = min(g_in, units_per_phase)
+        h = max(1, cm // max(1, units_per_phase // max(1, min(g_in, units_per_phase))))
         capacity = Fraction(d_in, c) * p  # one pixel per C clocks per phase
         return LayerImpl(
             layer=layer,

@@ -23,6 +23,7 @@ from typing import List, Sequence, Tuple
 LayerKind = str
 # 'conv' | 'dwconv' | 'pointwise' | 'dense' | 'pool' | 'add' | 'gap' | 'concat'
 #   | 'scale' | 'split' | 'merge'
+# A 'conv' may be grouped (``LayerSpec.groups``); depthwise stays 'dwconv'.
 # 'add', 'concat' and 'scale' are JOIN kinds: in a LayerGraph they may have
 # several producers (residual sums, inception-style concatenations,
 # squeeze-and-excitation gates).  For 'add', d_in is the per-operand
@@ -58,6 +59,26 @@ class LayerSpec:
     # generated from the *same* description as the DSE graph — topology
     # and inference cannot drift.
     activation: str = "none"
+    # grouped conv ('conv' only): input and output channels split into
+    # ``groups`` equal groups, each output channel reading the d_in /
+    # groups channels of its own group.  Left out of the repr, so a
+    # dense layer prints as it always has.
+    groups: int = dataclasses.field(default=1, repr=False)
+
+    def __post_init__(self):
+        if self.groups != 1 and (
+            self.kind != "conv" or self.d_in % self.groups or self.d_out % self.groups
+        ):
+            raise ValueError(
+                f"{self.name}: {self.groups} groups need a 'conv' whose d_in "
+                f"and d_out they divide (kind {self.kind!r}, {self.d_in} -> "
+                f"{self.d_out})"
+            )
+
+    @property
+    def group_in(self) -> int:
+        """Input channels each output channel reads: d_in / groups."""
+        return self.d_in // self.groups
 
     @property
     def k_taps(self) -> int:
@@ -74,7 +95,7 @@ class LayerSpec:
     def macs_per_pixel(self) -> int:
         """Multiply ops per *output* pixel (the workload, not the hardware)."""
         if self.kind == "conv":
-            return self.d_in * self.d_out * self.k_taps
+            return self.group_in * self.d_out * self.k_taps
         if self.kind == "dwconv":
             return self.d_in * self.channel_multiplier * self.k_taps
         if self.kind in ("pointwise", "dense"):
@@ -88,7 +109,7 @@ class LayerSpec:
     @property
     def weight_count(self) -> int:
         if self.kind == "conv":
-            return self.d_in * self.d_out * self.k_taps + self.d_out
+            return self.group_in * self.d_out * self.k_taps + self.d_out
         if self.kind == "dwconv":
             return self.d_in * self.channel_multiplier * self.k_taps + self.d_out
         if self.kind in ("pointwise", "dense"):

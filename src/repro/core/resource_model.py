@@ -218,11 +218,10 @@ def estimate_layer(impl: LayerImpl, spec: FPGASpec = XCVU37P) -> ResourceEstimat
             # buffer is banked at the *consumption* width (j channels/clk
             # per phase) — data-rate-aware buffering: low rates get thin,
             # deep, bits-efficient memories.
-            width = 8 * max(1, impl.j * impl.p_raw)
-            depth = max(
-                1,
-                math.ceil(rows * lay.in_hw[1] * lay.d_in / max(1, impl.j * impl.p_raw)),
-            )
+            # (a grouped conv's G groups each take j a clock)
+            lanes = impl.j * lay.groups * impl.p_raw
+            width = 8 * max(1, lanes)
+            depth = max(1, math.ceil(rows * lay.in_hw[1] * lay.d_in / max(1, lanes)))
             b, u = _map_buffer(width, depth)
         else:
             # [11] transposed KPU: weighted partial sums buffered per group

@@ -23,7 +23,8 @@ Extra constraints that exist on TPU but not on the FPGA:
     its stride phases (``conv_geometry``), per grid step.  A conv whose
     frame cannot fit the budget (the lane-sparse 3-channel stems) is
     planned as im2col patches through the FCU matmul instead
-    (``TileChoice.im2col``).
+    (``TileChoice.im2col``).  A grouped conv's grid step holds whole
+    groups (``groups_per_block``) and never runs as im2col.
   * The 'scale' join (``kernels/se_scale``) is one multiply per feature,
     bound by memory: it streams blocks of whole rows by a channel tile,
     the largest that fit ``SCALE_BLOCK_BYTES``.
@@ -230,21 +231,53 @@ def conv_frame_vmem_bytes(
     spec: TPUSpec,
     depthwise: bool = False,
     frames: int = 1,
+    group_in: Optional[int] = None,
 ) -> int:
     """Working set of one whole-frame conv grid step (kpu_conv, or
     dw_conv with ``depthwise``) holding ``frames`` frames: the
     phase-split input, weight and output blocks double-buffered, the f32
     accumulator and one f32 tap product, and one loaded tap window, the
     last three ``geo.wo_mxu`` columns wide.  Every term but the weight
-    block scales with ``frames``."""
+    block scales with ``frames``.  A grouped conv's weight block reads
+    ``group_in`` input channels (its group width), not ``bci``."""
     (hq, wq), (ho, wo), (kh, kw) = geo.phase_hw, geo.out_hw, kernel
     x = padded_bytes((len(geo.phases), hq, wq, bci), dtype_bytes, spec)
-    w_shape = (kh, kw, bci) if depthwise else (kh, kw, bci, bco)
+    w_in = bci if group_in is None else group_in
+    w_shape = (kh, kw, bci) if depthwise else (kh, kw, w_in, bco)
     w = padded_bytes(w_shape, dtype_bytes, spec)
     o = padded_bytes((ho, wo, bco), dtype_bytes, spec)
     acc = padded_bytes((ho, geo.wo_mxu, bco), 4, spec)
     win = padded_bytes((ho, geo.wo_mxu, bci), dtype_bytes, spec)
     return 2 * (frames * (x + o) + w) + frames * (2 * acc + win)
+
+
+def group_blocks(groups: int, group_in: int, group_out: int, lanes: int) -> List[int]:
+    """Groups a grouped conv's grid step may hold (``gpb``): the
+    divisors of ``groups`` whose input and output channel blocks are
+    legal lane blocks (a multiple of ``lanes``, or every channel)."""
+    return [
+        g
+        for g in divisors(groups)
+        if g == groups or (g * group_in % lanes == 0 and g * group_out % lanes == 0)
+    ]
+
+
+def groups_per_block(
+    groups: int,
+    group_in: int,
+    group_out: int,
+    floor_out: int,
+    fits: Callable[[int], bool],
+    lanes: int,
+) -> int:
+    """Whole groups per grid step of a grouped conv: the fewest legal
+    ones (``group_blocks``) whose output block reaches ``floor_out``
+    channels, narrowed to fewer while the step's working set does not
+    fit (``fits``); the fewest legal when none fits."""
+    legal = group_blocks(groups, group_in, group_out, lanes)
+    want = next((g for g in legal if g * group_out >= floor_out), groups)
+    fitting = [g for g in legal if g <= want and fits(g)]
+    return max(fitting) if fitting else legal[0]
 
 
 def conv_block_frames(
@@ -424,6 +457,8 @@ def select_tile_for_impl(
         is planned as im2col through the FCU (``im2col=True``, ``bk``
         grown to the whole ``d_in`` so the contraction k_taps * d_in is
         one legal block).
+      * a grouped conv (``groups`` > 1) — ``_grouped_conv_tile``: whole
+        groups a grid step, narrowed to fit VMEM, never im2col.
       * dwconv — the channel tile ``bk`` = smallest legal divisor of
         ``d_in`` >= j (h = 1 per §II-B: the channel multiplier replaces
         d_out); ``bn`` is reported as 1.
@@ -506,6 +541,9 @@ def select_tile_for_impl(
             mxu_aligned=_align_ok(bc, lane),
         )
 
+    if lay.kind == "conv" and lay.groups > 1:
+        return _grouped_conv_tile(impl, m, batch, dtype_bytes, spec, budget)
+
     bk = plan_dim_tile(lay.d_in, min(impl.j, lay.d_in), lane)
     bn = plan_dim_tile(lay.d_out, max(1, lay.d_out // impl.h), lane)
     frame_bytes = None
@@ -524,14 +562,7 @@ def select_tile_for_impl(
     def ws(bm):
         return matmul_vmem_bytes(bm, k_block, bn, dtype_bytes=dtype_bytes, spec=spec)
 
-    if batch is not None:
-        bm = pinned_bm(
-            m, k_block, bn, dtype_bytes=dtype_bytes, budget=budget, spec=spec
-        )
-    else:
-        bm = min(m, 512)
-        while bm > spec.sublanes and ws(bm) > budget:
-            bm //= 2
+    bm = _pixel_tile(m, k_block, bn, batch, dtype_bytes, budget, spec)
     h_tile = max(1, lay.d_out // bn)
     jh_holds_eq9 = Fraction(impl.j, max(1, impl.h)) >= r_phase
     if jh_holds_eq9 and Fraction(bk, h_tile) < r_phase:
@@ -549,4 +580,71 @@ def select_tile_for_impl(
         vmem_bytes=ws(bm) if frame_bytes is None or im2col else frame_bytes,
         mxu_aligned=_align_ok(bk, lane) and _align_ok(bn, lane),
         im2col=im2col,
+    )
+
+
+def _pixel_tile(
+    m: int,
+    k: int,
+    bn: int,
+    batch: Optional[int],
+    dtype_bytes: int,
+    budget: int,
+    spec: TPUSpec,
+) -> int:
+    """A conv's pixel tile ``bm``: pinned to the batch (``pinned_bm``)
+    where the plan has one, else the largest power-of-two halving of 512
+    whose ``[bm, k] x [k, bn]`` working set fits the budget."""
+    if batch is not None:
+        return pinned_bm(m, k, bn, dtype_bytes=dtype_bytes, budget=budget, spec=spec)
+    bm = min(m, 512)
+    while bm > spec.sublanes and matmul_vmem_bytes(
+        bm, k, bn, dtype_bytes=dtype_bytes, spec=spec
+    ) > budget:
+        bm //= 2
+    return bm
+
+
+def _grouped_conv_tile(
+    impl: LayerImpl,
+    m: int,
+    batch: Optional[int],
+    dtype_bytes: int,
+    spec: TPUSpec,
+    budget: int,
+) -> TileChoice:
+    """A grouped conv's whole-frame tile: each grid step holds ``gpb``
+    whole groups (``groups_per_block``), ``bk = gpb * d_in/G`` input and
+    ``bn = gpb * d_out/G`` output channels, its contraction one step
+    (``grid_k`` 1).  ``bn`` reaches the DSE's ``d_out / h`` floor where
+    the frame fits VMEM, and narrows to fewer groups where it does not;
+    a grouped conv never runs as im2col.  The tile's capacity ``d_in *
+    bn / d_out`` >= ``G * j / h`` (j <= d_in/G, bn >= d_out/h): Eq. 9
+    survives."""
+    lay = impl.layer
+    groups, g_in = lay.groups, lay.group_in
+    g_out = lay.d_out // groups
+    geo = conv_geometry(lay.in_hw, lay.kernel, lay.stride[0])
+
+    def frame_bytes(gpb):
+        return conv_frame_vmem_bytes(
+            geo, lay.kernel, gpb * g_in, gpb * g_out,
+            dtype_bytes=dtype_bytes, spec=spec, group_in=g_in,
+        )
+
+    gpb = groups_per_block(
+        groups, g_in, g_out, max(1, lay.d_out // impl.h),
+        lambda g: frame_bytes(g) <= budget, spec.lanes,
+    )
+    bk, bn = gpb * g_in, gpb * g_out
+    bm = _pixel_tile(m, bk, bn, batch, dtype_bytes, budget, spec)
+    return TileChoice(
+        bm=bm,
+        bk=bk,
+        bn=bn,
+        grid_m=math.ceil(m / bm),
+        grid_k=1,
+        grid_n=groups // gpb,
+        vmem_bytes=frame_bytes(gpb),
+        mxu_aligned=_align_ok(bk, spec.lanes) and _align_ok(bn, spec.lanes),
     )

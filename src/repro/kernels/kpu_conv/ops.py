@@ -16,7 +16,9 @@ padded input, holding as many frames per grid step as the pixel tile
 sublane tile (``frame_geometry``), or — when that frame cannot fit VMEM
 (the lane-sparse 3-channel stems; ``TileChoice.im2col``, or the same
 budget check on the uniform path) — im2col patches through the FCU
-matmul kernel.
+matmul kernel.  A grouped conv (a weight ``[kh, kw, d_in/G, d_out]``)
+always takes the whole-frame kernel's grouped route, ``bco`` holding
+whole groups.
 """
 from __future__ import annotations
 
@@ -35,13 +37,14 @@ from repro.core.tpu_tiles import (
     conv_frame_vmem_bytes,
     conv_geometry,
     fit_bm,
+    groups_per_block,
     mxu_width,
     select_tile,
     vmem_budget,
 )
 from repro.kernels.common import conv_taps, phase_split
 from repro.kernels.fcu_matmul.fcu_matmul import fcu_matmul_p
-from .kpu_conv import kpu_conv_p
+from .kpu_conv import kpu_conv_p, kpu_group_conv_p
 
 
 def frame_geometry(in_hw, kernel, stride: int, dtype_bytes: int) -> ConvGeometry:
@@ -92,9 +95,12 @@ def kpu_conv(
     (``block_frames``; one without it).  ``node`` names the graph
     node in the kernel's name."""
     n, h, wdt, d_in = x.shape
-    kh, kw, _, d_out = w.shape
+    kh, kw, gi, d_out = w.shape
     geo = frame_geometry((h, wdt), (kh, kw), stride, x.dtype.itemsize)
     ho, wo = geo.out_hw
+    if gi != d_in:
+        assert not im2col, "a grouped conv has no im2col route"
+        return group_conv(x, w, stride=stride, bco=bco, bm=bm, node=node)[0]
     if bci is None or bco is None:
         t = select_tile(
             ho * wo, d_in, d_out, rate=rate, dtype_bytes=x.dtype.itemsize
@@ -132,6 +138,56 @@ def kpu_conv(
     )
 
 
+def group_conv(
+    x: jax.Array,            # [N, H, W, d_in]
+    w: jax.Array,            # [kh, kw, d_in / groups, d_out]
+    *,
+    stride: int = 1,
+    bco: Optional[int] = None,
+    bm: Optional[int] = None,
+    node: Optional[str] = None,
+) -> tuple:
+    """``kpu_conv``'s grouped route: ``bco`` output channels (whole
+    groups; ``group_tile`` without it) a grid step, never im2col.
+    Returns the output and ``gpb``, the groups each grid step of the
+    built Pallas call holds."""
+    n, h, wdt, d_in = x.shape
+    kh, kw, gi, d_out = w.shape
+    geo = frame_geometry((h, wdt), (kh, kw), stride, x.dtype.itemsize)
+    groups = d_in // gi
+    if bco is None:
+        bco = group_tile(n, geo, (kh, kw), groups, gi, d_out, x.dtype.itemsize)
+    bci = bco // (d_out // groups) * gi  # the same groups' inputs
+    return kpu_group_conv_p(
+        phase_split(x, geo),
+        w,
+        taps=conv_taps(geo, (kh, kw)),
+        out_hw=geo.out_hw,
+        bco=bco,
+        frames=block_frames(
+            n, geo, (kh, kw), bci, bco, bm, x.dtype.itemsize, group_in=gi
+        ),
+        wo_mxu=geo.wo_mxu,
+        node=node,
+    )
+
+
+def group_tile(n, geo, kernel, groups, gi, d_out, dtype_bytes) -> int:
+    """Output channels a grid step of the grouped route holds without a
+    plan: the fewest whole groups that fill a lane tile and fit VMEM
+    (``core.tpu_tiles.groups_per_block``)."""
+    spec = target_spec()
+    go = d_out // groups
+
+    def fits(gpb):
+        return conv_frame_vmem_bytes(
+            geo, kernel, gpb * gi, gpb * go, dtype_bytes=dtype_bytes,
+            spec=spec, group_in=gi,
+        ) <= vmem_budget(spec)
+
+    return go * groups_per_block(groups, gi, go, spec.lanes, fits, spec.lanes)
+
+
 def block_frames(
     n: int,
     geo: ConvGeometry,
@@ -140,9 +196,11 @@ def block_frames(
     bco: int,
     bm: Optional[int],
     dtype_bytes: int,
+    group_in: Optional[int] = None,
 ) -> int:
     """Frames per grid step of the whole-frame route: as many of the
-    ``n`` as the pixel tile ``bm`` covers, within the VMEM budget."""
+    ``n`` as the pixel tile ``bm`` covers, within the VMEM budget
+    (``group_in``: a grouped conv's group width)."""
     spec = target_spec()
     budget = vmem_budget(spec)
     ho, wo = geo.out_hw
@@ -156,6 +214,7 @@ def block_frames(
             dtype_bytes=dtype_bytes,
             spec=spec,
             frames=frames,
+            group_in=group_in,
         ) <= budget
 
     return conv_block_frames(n, ho * wo, bm, fits)
@@ -178,7 +237,10 @@ def conv_impl(
     ``m`` on the im2col route, whose pixel tile the plan pins like the
     FCU kinds', and ``frames`` (per grid step) and ``wo_mxu`` (columns
     each tap window streams) on the whole-frame route.
-    ``node`` names the graph node in the kernel's name.
+    ``node`` names the graph node in the kernel's name.  A grouped
+    conv (the weight reads fewer channels than ``x`` holds) also
+    reports ``groups`` and ``gpb``, the whole groups each grid step of
+    the Pallas call it built holds (``group_conv``).
     """
     def impl(x, w, stride):
         if tile is None:
@@ -186,7 +248,8 @@ def conv_impl(
             if record is not None:
                 record(bk=None, bn=None, d_in=x.shape[-1], d_out=w.shape[-1])
             return y
-        n, h, wdt, _ = x.shape
+        n, h, wdt, d_in = x.shape
+        gi = w.shape[2]
         geo = frame_geometry((h, wdt), w.shape[:2], stride, x.dtype.itemsize)
         if tile.im2col:
             m = n * geo.out_hw[0] * geo.out_hw[1]
@@ -196,20 +259,25 @@ def conv_impl(
             bm = tile.bm
             extra = {
                 "frames": block_frames(
-                    n, geo, w.shape[:2], tile.bk, tile.bn, bm, x.dtype.itemsize
+                    n, geo, w.shape[:2], tile.bk, tile.bn, bm, x.dtype.itemsize,
+                    group_in=gi if gi != d_in else None,
                 ),
                 "wo_mxu": geo.wo_mxu,
             }
-        y = kpu_conv(
-            x,
-            w,
-            stride=stride,
-            bci=tile.bk,
-            bco=tile.bn,
-            bm=bm,
-            im2col=tile.im2col,
-            node=node,
-        )
+        if gi != d_in:
+            y, gpb = group_conv(x, w, stride=stride, bco=tile.bn, bm=bm, node=node)
+            extra.update(groups=d_in // gi, gpb=gpb)
+        else:
+            y = kpu_conv(
+                x,
+                w,
+                stride=stride,
+                bci=tile.bk,
+                bco=tile.bn,
+                bm=bm,
+                im2col=tile.im2col,
+                node=node,
+            )
         if record is not None:
             record(
                 bk=tile.bk, bn=tile.bn, d_in=x.shape[-1], d_out=w.shape[-1], **extra

@@ -1,7 +1,7 @@
 """Unified CNN inference machinery: execute a ``LayerGraph`` in JAX.
 
 Every CNN family in the repo (MobileNetV1/V2, ResNet-18/34,
-EfficientNet-B0) describes
+EfficientNet-B0, RegNetY-4.0GF) describes
 itself **once**, as the ``LayerSpec`` DAG consumed by the data-rate DSE
 (core.graph).  This module is the other half of that contract: a generic
 interpreter that runs the *same* graph as a JAX network —
@@ -94,12 +94,15 @@ _ACTIVATIONS: Dict[str, Callable[[jax.Array], jax.Array]] = {
 
 
 def _conv(x: jax.Array, w: jax.Array, stride: int) -> jax.Array:
+    """Dense, or grouped where the weight reads fewer channels than
+    ``x`` holds (HWIO ``[kh, kw, d_in/G, d_out]``)."""
     return jax.lax.conv_general_dilated(
         x,
         w,
         window_strides=(stride, stride),
         padding="SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=x.shape[-1] // w.shape[2],
     )
 
 
@@ -212,7 +215,7 @@ def _tile_recorder(executed: Dict[str, Dict[str, int]], name: str):
 
 def _weight_shape(spec: LayerSpec) -> tuple:
     if spec.kind == "conv":
-        return (*spec.kernel, spec.d_in, spec.d_out)
+        return (*spec.kernel, spec.group_in, spec.d_out)
     if spec.kind == "dwconv":
         # HWIO for grouped conv: I = 1 (per-group), O = C * multiplier
         return (*spec.kernel, 1, spec.d_in * spec.channel_multiplier)
@@ -223,7 +226,7 @@ def _weight_shape(spec: LayerSpec) -> tuple:
 
 def _fan_in(spec: LayerSpec) -> int:
     if spec.kind == "conv":
-        return spec.d_in * spec.k_taps
+        return spec.group_in * spec.k_taps
     if spec.kind == "dwconv":
         return spec.k_taps
     return spec.d_in
@@ -389,7 +392,10 @@ def _check_planned_tile(
     per grid step that the planned bm covers on that batch
     (``conv_block_frames``): the plan's multi-pixel P, executed.  A
     whole-frame conv must also stream the row width ``mxu_width`` gives
-    its output width at the input's ``dtype_bytes`` (``wo_mxu``).
+    its output width at the input's ``dtype_bytes`` (``wo_mxu``).  A
+    grouped conv must run the grouped route with the plan's groups per
+    grid step: it reports ``groups`` (the spec's) and ``gpb`` (``bn``
+    over the group's outputs).
     """
     if node_plan is None:
         raise GraphExecutionError(f"{spec.name}: node missing from the kernel plan")
@@ -452,6 +458,14 @@ def _check_planned_tile(
                 f"{spec.name}: executed rows of {got.get('wo_mxu')} columns "
                 f"!= wo_mxu={want}, Wo={spec.out_hw[1]} aligned to the "
                 f"sublane tile"
+            )
+    if spec.kind == "conv" and spec.groups > 1:
+        want = (spec.groups, t.bn * spec.groups // spec.d_out)
+        if (got.get("groups"), got.get("gpb")) != want:
+            raise GraphExecutionError(
+                f"{spec.name}: executed {got.get('groups')} groups, "
+                f"{got.get('gpb')} a grid step != the plan's {want[0]} "
+                f"groups, {want[1]} a step (the grouped route)"
             )
 
 

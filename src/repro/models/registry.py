@@ -1,6 +1,7 @@
 """The CNN registry: one lookup and one apply machinery for every family.
 
-  api = get_cnn_api("resnet18")   # or mobilenet_v1/v2, resnet34, efficientnet_b0
+  api = get_cnn_api("resnet18")   # or mobilenet_v1/v2, resnet34,
+                                  # efficientnet_b0, regnety_4gf
   cfg = api.make_config(input_hw=(32, 32), num_classes=10)
   params = api.init(cfg, rng)
   logits = api.apply(params, x, cfg)     # conv_impls= swaps in Pallas
@@ -21,7 +22,7 @@ import dataclasses
 import functools
 from typing import Any, Callable, Dict, Tuple
 
-from repro.models import cnn, efficientnet, mobilenet, resnet
+from repro.models import cnn, efficientnet, mobilenet, regnet, resnet
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,6 +205,7 @@ _CNN_FAMILIES: Dict[str, Callable] = {
     "efficientnet_b0": efficientnet.EfficientNetConfig,
     "mobilenet_v1": functools.partial(mobilenet.MobileNetConfig, version=1),
     "mobilenet_v2": functools.partial(mobilenet.MobileNetConfig, version=2),
+    "regnety_4gf": regnet.RegNetConfig,
     "resnet18": functools.partial(resnet.ResNetConfig, depth=18),
     "resnet34": functools.partial(resnet.ResNetConfig, depth=34),
 }

@@ -20,14 +20,15 @@ def ceil_div(a: int, b: int) -> int:
 
 def conv_spec(name: str, kind: str, d_in: int, d_out: int,
               hw: Tuple[int, int], k: int, s: int,
-              cm: int = 1, act: str = "none",
+              cm: int = 1, act: str = "none", groups: int = 1,
               ) -> Tuple[LayerSpec, Tuple[int, int]]:
-    """Square-kernel 'same'-padded conv-family LayerSpec + its out_hw."""
+    """Square-kernel 'same'-padded conv-family LayerSpec + its out_hw
+    (``groups`` > 1: a grouped 'conv')."""
     out_hw = (ceil_div(hw[0], s), ceil_div(hw[1], s))
     return (
         LayerSpec(name=name, kind=kind, d_in=d_in, d_out=d_out,
                   in_hw=hw, out_hw=out_hw, kernel=(k, k), stride=(s, s),
-                  channel_multiplier=cm, activation=act),
+                  channel_multiplier=cm, activation=act, groups=groups),
         out_hw,
     )
 

@@ -1,15 +1,20 @@
 """KPU conv kernel vs XLA conv oracle."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.tpu_tiles import TileChoice, conv_geometry
+from repro.core.tpu_tiles import TileChoice, conv_geometry, group_blocks
 from repro.kernels.common import conv_taps, phase_split
 from repro.kernels.kpu_conv import conv_impl, kpu_conv, kpu_conv_ref
 from repro.kernels.kpu_conv.kpu_conv import kpu_conv_p
 from repro.kernels.kpu_conv.ops import block_frames
+
+# the kernel module (the package exports the function of the same name)
+KPU = importlib.import_module("repro.kernels.kpu_conv.kpu_conv")
 
 
 def _rand(key, shape, dtype=jnp.float32):
@@ -158,3 +163,52 @@ def test_kpu_aligned_rows_keep_the_true_output_width(wo, k, stride):
         runs[nb] = np.asarray(y)
     for nb in (2, 8):
         np.testing.assert_array_equal(runs[nb], runs[1])
+
+
+# (groups, group width, stride, groups a grid step): every gpb the rule
+# can pick for RegNetY's 64-channel groups (``group_blocks``), and the
+# whole block for 4-channel groups, where no fewer fill a lane tile
+GROUPED = [
+    (g, gi, s, gpb)
+    for gi in (64, 4)
+    for g in (2, 3, 8, 17)
+    for s in (1, 2)
+    for gpb in group_blocks(g, gi, gi, 128)
+]
+
+
+@pytest.mark.parametrize(
+    "groups,gi,stride,gpb", GROUPED,
+    ids=[f"G{g}-w{gi}-s{s}-gpb{b}" for g, gi, s, b in GROUPED],
+)
+def test_kpu_grouped_route_matches_lax(groups, gi, stride, gpb, monkeypatch):
+    """The grouped route against XLA's grouped conv: a grouped HWIO
+    weight, whole groups a grid step, both in-kernel passes (the rule's
+    block-diagonal chunks, and one group at a time)."""
+    k1, k2 = jax.random.split(jax.random.key(groups * gi + stride))
+    c, hw = groups * gi, 6
+    x = _rand(k1, (2, hw, hw, c))
+    w = _rand(k2, (3, 3, gi, c))
+    want = jax.lax.conv_general_dilated(
+        x, w, (stride, stride), "SAME",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), feature_group_count=groups,
+        precision=jax.lax.Precision.HIGHEST)
+    geo = conv_geometry((hw, hw), (3, 3), stride)
+    xs, taps = phase_split(x, geo), conv_taps(geo, (3, 3))
+    for one_group in (False, True):
+        with monkeypatch.context() as m:
+            if one_group:  # steer the kernel to one group per MXU pass
+                m.setattr(KPU, "groups_per_dot", lambda gpb, gi: 1)
+            got = kpu_conv_p(xs, w, taps=taps, out_hw=geo.out_hw,
+                             bci=gpb * gi, bco=gpb * gi, frames=2)
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    if gpb == groups:  # the entry point without a plan picks its own block
+        np.testing.assert_allclose(kpu_conv(x, w, stride=stride), want,
+                                   rtol=1e-4, atol=1e-4)
+    # the planned entry point records the grouped route it ran
+    tile = TileChoice(bm=2 * 9, bk=gpb * gi, bn=gpb * gi, grid_m=1, grid_k=1,
+                      grid_n=groups // gpb, vmem_bytes=0, mxu_aligned=False)
+    rec = {}
+    y = conv_impl(tile=tile, record=lambda **t: rec.update(t))(x, w, stride)
+    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-4)
+    assert (rec["groups"], rec["gpb"]) == (groups, gpb)

@@ -79,6 +79,7 @@ GRAPHS = {
     "resnet18": lambda: _family_graph("resnet18"),
     "mobilenet_v2": lambda: _family_graph("mobilenet_v2"),
     "efficientnet_b0": lambda: _family_graph("efficientnet_b0"),
+    "regnety_4gf": lambda: _family_graph("regnety_4gf"),
     "wide": _wide_conv_graph,
 }
 
@@ -106,8 +107,22 @@ CASES = [
     ("efficientnet_b0", "b1_scale", jnp.float32),  # se_scale at 112x112x32
     ("efficientnet_b0", "b6_scale", jnp.float32),  # se_scale at 28x28x240
     ("efficientnet_b0", "b16_scale", jnp.float32),  # se_scale at 7x7x1152
+    ("regnety_4gf", "s1b1_b", jnp.float32),  # grouped 3x3/2, 112 -> 56, G=2
+    ("regnety_4gf", "s1b2_b", jnp.float32),  # grouped 3x3/1 at 56x56x128
+    ("regnety_4gf", "s2b2_b", jnp.float32),  # G=3, 28x28x192
+    ("regnety_4gf", "s3b1_b", jnp.float32),  # G=8, 3x3/2, 28 -> 14
+    ("regnety_4gf", "s3b2_b", jnp.float32),  # G=8, 14x14x512
+    ("regnety_4gf", "s4b1_b", jnp.float32),  # G=17, 3x3/2, 14 -> 7
+    ("regnety_4gf", "s4b2_b", jnp.float32),  # G=17, 7x7x1088
+    ("regnety_4gf", "s1b1_proj", jnp.float32),  # 1x1/2 projection, 112 -> 56
+    ("regnety_4gf", "s1b1_scale", jnp.float32),  # se_scale at 56x56x128
     ("wide", "c3x3_112", jnp.float32),
 ]
+
+# groups per grid step of RegNetY's grouped convs: whole lane tiles of
+# 64-channel groups, or every group where none divides (G = 3, 17)
+GPB = {"s1b1_b": 2, "s1b2_b": 2, "s2b2_b": 3, "s3b1_b": 2, "s3b2_b": 2,
+       "s4b1_b": 17, "s4b2_b": 17}
 
 
 # frames per grid step of the whole-frame convs: the planned bm (448-512
@@ -180,3 +195,7 @@ def test_served_kernel_compiles_for_v5e(
         assert FRAMES[node] == max(1, node_plan.tile.bm // (ho * wo))
     if family == "resnet18" and node in WO_MXU:
         assert executed[node]["wo_mxu"] == WO_MXU[node]
+    if family == "regnety_4gf" and node in GPB:
+        assert (executed[node]["groups"], executed[node]["gpb"]) == (
+            spec.groups, GPB[node])
+        assert not node_plan.tile.im2col

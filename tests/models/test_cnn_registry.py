@@ -1,4 +1,4 @@
-"""The unified CNN registry: one lookup + one apply machinery, 5 families."""
+"""The unified CNN registry: one lookup + one apply machinery, 6 families."""
 import os
 import subprocess
 import sys
@@ -13,8 +13,8 @@ from repro.core.flops import graph_macs
 from repro.models import cnn
 from repro.models.registry import cnn_families, get_cnn_api
 
-FAMILIES = ("efficientnet_b0", "mobilenet_v1", "mobilenet_v2", "resnet18",
-            "resnet34")
+FAMILIES = ("efficientnet_b0", "mobilenet_v1", "mobilenet_v2", "regnety_4gf",
+            "resnet18", "resnet34")
 
 
 def test_registry_lists_all_families():

@@ -21,7 +21,8 @@ import pytest
 
 from repro.core import plan_graph
 from repro.core.dse import NON_ARITH_KINDS
-from repro.core.tpu_tiles import conv_geometry
+from repro.core.hw_specs import TPU_V5E
+from repro.core.tpu_tiles import conv_block_frames, conv_geometry, mxu_width
 from repro.kernels.common import conv_taps, phase_split
 from repro.kernels.kpu_conv.kpu_conv import kpu_conv_p
 from repro.models import cnn
@@ -216,3 +217,37 @@ def test_ref11_plans_lower_without_feasibility_claim():
         assert spec.d_in % node.tile.bk == 0
         if spec.kind != "dwconv":
             assert spec.d_out % node.tile.bn == 0
+
+
+def _grouped_record(spec, node_plan, batch):
+    """What the grouped route reports for ``spec`` under its plan."""
+    t = node_plan.tile
+    return dict(
+        bk=t.bk, bn=t.bn, d_in=spec.d_in, d_out=spec.d_out,
+        frames=conv_block_frames(batch, spec.out_hw[0] * spec.out_hw[1], t.bm),
+        wo_mxu=mxu_width(spec.out_hw[1], 4, TPU_V5E),
+        groups=spec.groups, gpb=t.bn * spec.groups // spec.d_out,
+    )
+
+
+@pytest.mark.parametrize(
+    "tamper",
+    [{"gpb": 1}, {"gpb": 8}, {"groups": 4}, {"groups": None, "gpb": None}],
+    ids=["fewer-groups-a-step", "more-groups-a-step", "other-groups",
+         "dense-route"],
+)
+def test_grouped_route_other_than_planned_is_detected(tamper):
+    """A grouped conv must report the grouped route with the plan's
+    groups and groups per grid step: a record with other ``groups`` or
+    ``gpb`` (or none, as the dense route gives) trips the per-node
+    check; the honest record passes."""
+    api = get_cnn_api("regnety_4gf")
+    graph = api.graph(api.make_config(input_hw=(32, 32), num_classes=10))
+    kp = plan_graph(graph, RATE).kernel_plan(batch=2)
+    victim = "s3b2_b"  # 8 groups of 64 channels, 2 a step
+    spec = graph.spec(victim)
+    honest = _grouped_record(spec, kp[victim], 2)
+    assert (honest["groups"], honest["gpb"]) == (8, 2)
+    cnn._check_planned_tile(spec, kp[victim], honest, 4)
+    with pytest.raises(cnn.GraphExecutionError, match=f"{victim}.*groups"):
+        cnn._check_planned_tile(spec, kp[victim], {**honest, **tamper}, 4)
